@@ -94,7 +94,7 @@ def main(quick: bool = False):
         *a, cent, bw, nbits=nbits, k=k_top, impl="ref"),
         qb, packed_b, cids_b, valid_b, cmask_b)
     model = hbm_model(C, Ld, d, nbits, Lq)
-    kp = min(-(-min(k_top, Ct) // 8) * 8, Ct)
+    kp = -(-min(k_top, Ct) // 128) * 128     # whole lane rows
     rerank_model = {
         # split: the full (B, C) fp32 score tensor round-trips HBM
         # twice (raw + masked copy) before selection reads it back

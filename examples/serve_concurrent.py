@@ -51,9 +51,6 @@ def build_stack(splade_backend="host", splade_max_df=None,
                          splade_backend=splade_backend,
                          splade_max_df=splade_max_df,
                          rerank_backend=rerank_backend))
-    if retr.rerank_backend != rerank_backend:
-        print(f"rerank backend {rerank_backend!r} unavailable — "
-              f"using {retr.rerank_backend!r}")
     return corpus, retr
 
 

@@ -91,8 +91,9 @@ stage_kernels() {
 
 stage_smoke() {
     # pipelined smoke: full serving stack with the stage-graph executor
-    # (pipeline_depth=2) over interpret-mode Pallas kernels
-    python -m repro.launch.serve --pipeline-depth 2 --splade-backend pallas \
+    # (pipeline_depth=2) over the device stage-1 scorer (the Pallas
+    # backend needs a TPU: chip_smoke.py covers it there)
+    python -m repro.launch.serve --pipeline-depth 2 --splade-backend jax \
         --max-batch 8 --qps 100 --n 32
 
     # scatter-gather smoke: 2-shard group through the sharded plans

@@ -28,7 +28,6 @@ from repro.core.plaid import (
 )
 from repro.index.splade_device import SpladeDeviceCache
 from repro.index.splade_index import SpladeIndex
-from repro.kernels.fused_rerank import ops as fused_ops
 from repro.serving.context import BatchOutcome, freeze
 from repro.serving.pipeline import (
     DEVICE,
@@ -98,20 +97,18 @@ class MultiStageRetriever:
         if backend not in SPLADE_BACKENDS:
             raise ValueError(f"splade backend {backend!r} not in "
                              f"{SPLADE_BACKENDS}")
+        if backend != "host":
+            self._splade_impl(backend)          # raises without a TPU
         self.splade_backend = backend
 
     def set_rerank_backend(self, backend: str):
         """Stage-4 tail selection: ``fused`` collapses exact scoring,
         masking, (hybrid) α-fusion and top-k selection into ONE device
         dispatch (the ``fused_rerank`` kernel / fused-XLA tail);
-        ``split`` keeps the legacy multi-dispatch tail. Results are
-        bitwise-identical — ``fused`` silently degrades to ``split``
-        when the Pallas toolchain is absent."""
+        ``split`` keeps the legacy multi-dispatch tail."""
         if backend not in RERANK_BACKENDS:
             raise ValueError(f"rerank backend {backend!r} not in "
                              f"{RERANK_BACKENDS}")
-        if backend == "fused" and not fused_ops.HAVE_PALLAS:
-            backend = "split"
         self.rerank_backend = backend
 
     def splade_device_cache(self) -> SpladeDeviceCache:
@@ -126,11 +123,18 @@ class MultiStageRetriever:
             return self._splade_device
 
     def _splade_impl(self, backend: str) -> str:
-        # the Pallas kernel body runs in interpret mode off-TPU so the
-        # selector stays honest (same code path, Mosaic-free execution)
+        """Kernel ``impl`` for a device stage-1 backend: ``jax`` is the
+        segment-sum reference on any platform, ``pallas`` the Mosaic
+        kernel, which exists only on a TPU."""
         if backend == "jax":
             return "ref"
-        return "pallas" if jax.default_backend() == "tpu" else "interpret"
+        platform = jax.default_backend()
+        if platform != "tpu":
+            raise RuntimeError(
+                f"splade backend 'pallas' runs the Mosaic kernel and "
+                f"needs a TPU, but JAX's backend here is {platform!r}; "
+                f"use 'jax' or 'host' on this host")
+        return "pallas"
 
     def reset_stage_stats(self):
         """Clear the per-stage instrumentation (in place: executors and
@@ -845,7 +849,8 @@ class MultiStageRetriever:
         from repro.index.builder import ColBERTIndex
         new_index = ColBERTIndex(col_dir, mode=idx.store.mode)
         new_searcher = PLAIDSearcher(new_index, self.searcher.params,
-                                     device_resident=False)
+                                     device_resident=False,
+                                     device=self.searcher.device)
         new_splade = SpladeIndex.load(spl_dir)
         with live.gate.write():
             self.splade = new_splade

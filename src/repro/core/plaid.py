@@ -49,6 +49,7 @@ class PlaidParams:
 def stage1_centroid_probe(q_emb, centroids, nprobe: int):
     """q_emb (Lq, d), centroids (K, d) → (scores_c (Lq, K), top cids)."""
     s = jnp.einsum("qd,kd->qk", q_emb, centroids,
+                   precision=jax.lax.Precision.HIGHEST,
                    preferred_element_type=jnp.float32)
     _, cids = jax.lax.top_k(s, nprobe)
     return s, cids.astype(jnp.int32)
@@ -177,6 +178,7 @@ def stage1_centroid_probe_batch(q_emb, q_valid, centroids, nprobe: int):
     """q_emb (B, Lq, d), q_valid (B, Lq), centroids (K, d) →
     (scores_c (B, Lq, K), cids (B, Lq, nprobe))."""
     s = jnp.einsum("bqd,kd->bqk", q_emb, centroids,
+                   precision=jax.lax.Precision.HIGHEST,
                    preferred_element_type=jnp.float32)
     _, cids = jax.lax.top_k(s, nprobe)
     # padded query tokens must not widen the candidate set: replicate the
@@ -205,20 +207,28 @@ def stage3_approx_score_batch(scores_c, cand_codes, cand_valid, q_valid):
 
 class PLAIDSearcher:
     def __init__(self, index: ColBERTIndex, params: PlaidParams = PlaidParams(),
-                 device_resident: bool = False, ivf_pad: Optional[int] = None):
+                 device_resident: bool = False, ivf_pad: Optional[int] = None,
+                 device=None):
+        """``device`` (optional jax.Device) pins the searcher's device
+        arrays; its dispatches then run there, since host inputs are
+        uncommitted and follow them. A shard group gives shard i mesh
+        device i (``launch.mesh.shard_device_map``)."""
         self.index = index
         self.params = params
-        self.centroids = jnp.asarray(index.centroids)
-        self.bucket_weights = jnp.asarray(index.bucket_weights)
-        self.ivf_padded = jnp.asarray(index.ivf.as_padded(ivf_pad))
+        self.device = device
+        put = (jnp.asarray if device is None
+               else functools.partial(jax.device_put, device=device))
+        self.centroids = put(index.centroids)
+        self.bucket_weights = put(index.bucket_weights)
+        self.ivf_padded = put(index.ivf.as_padded(ivf_pad))
         self.device_resident = device_resident
         if device_resident:
             # whole pool in device memory (the in-memory ColBERTv2 baseline
             # or the TPU serve path with the pool sharded over 'model')
-            self.dev_codes = jnp.asarray(np.asarray(index.store.codes))
-            self.dev_residuals = jnp.asarray(np.asarray(index.store.residuals))
-            self.dev_offsets = jnp.asarray(index.doc_offsets)
-            self.dev_doclens = jnp.asarray(index.doclens)
+            self.dev_codes = put(np.asarray(index.store.codes))
+            self.dev_residuals = put(np.asarray(index.store.residuals))
+            self.dev_offsets = put(index.doc_offsets)
+            self.dev_doclens = put(index.doclens)
 
     # -- full PLAID (stages 1-4) ------------------------------------------
     def search(self, q_emb: np.ndarray, k: Optional[int] = None):

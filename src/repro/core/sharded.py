@@ -57,6 +57,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -444,9 +445,8 @@ class ShardedRetriever(MultiStageRetriever):
         fused tail bitwise."""
         for sh in self.shards:
             sh.set_rerank_backend(backend)
-        # group-level plans are split-shaped; record the shards' actual
-        # (possibly Pallas-degraded) resolution for the cache key
-        self.rerank_backend = self.shards[0].rerank_backend
+        # group-level plans are split-shaped; the knob still keys caches
+        self.rerank_backend = backend
 
     def splade_device_cache(self):
         """Materialise every shard's padded-postings device cache (each
@@ -617,7 +617,8 @@ class ShardedRetriever(MultiStageRetriever):
             live_mod.compact_splade_dir(last.splade, live, n_take, spl_dir)
             new_searcher = PLAIDSearcher(
                 ColBERTIndex(col_dir, mode=idx.store.mode),
-                last.searcher.params, device_resident=False)
+                last.searcher.params, device_resident=False,
+                device=last.searcher.device)
             new_retr = MultiStageRetriever(
                 SpladeIndex.load(spl_dir), new_searcher,
                 device=getattr(last, "device", None), params=self.params)
@@ -687,9 +688,11 @@ class ShardedRetriever(MultiStageRetriever):
                 # codes gather and approx dispatch run at the shard's
                 # ~cap/S occupancy, not the full global cap
                 sr = shards[i].searcher
+                cids = cb.state["cids"]
+                if sr.device is not None:
+                    cids = jax.device_put(cids, sr.device)
                 cand = stage2_candidates_batch(
-                    sr.ivf_padded, cb.state["cids"],
-                    sr.params.candidate_cap)
+                    sr.ivf_padded, cids, sr.params.candidate_cap)
                 cand_np = np.asarray(cand)
                 n_real = (cand_np >= 0).sum(axis=1)
                 W = min(_next_pow2(max(int(n_real.max()), 8)),
@@ -716,7 +719,7 @@ class ShardedRetriever(MultiStageRetriever):
                 a = stage3_approx_score_batch(
                     cb.state["scores_c"], jnp.asarray(s["codes"]),
                     jnp.asarray(s["cvalid"]), cb.state["q_valid"])
-                a = jnp.where(s["cand"] >= 0, a, -jnp.inf)
+                a = jnp.where(s["cand_np"] >= 0, a, -jnp.inf)
                 s["approx_np"] = np.asarray(a)
                 return s
 
@@ -873,8 +876,8 @@ def build_sharded_retriever(shard_dirs, boundaries, *, mode: str = "mmap",
     """Load a shard group written by ``split_index_tree`` into a
     :class:`ShardedRetriever`. ``shard_dirs``: per-shard directories
     each holding ``colbert/`` + ``splade/``; ``devices`` optionally
-    pins shard i's device-resident state (SPLADE device cache) to
-    ``devices[i]`` — see ``launch.mesh.shard_device_map``."""
+    pins shard i's device state (PLAID centroids and IVF, SPLADE device
+    cache) to ``devices[i]`` — see ``launch.mesh.shard_device_map``."""
     from repro.core.plaid import PLAIDSearcher, PlaidParams
     from repro.index.builder import ColBERTIndex
     from repro.index.splade_index import SpladeIndex
@@ -885,12 +888,11 @@ def build_sharded_retriever(shard_dirs, boundaries, *, mode: str = "mmap",
         d = pathlib.Path(d)
         index = ColBERTIndex(d / "colbert", mode=mode)
         sidx = SpladeIndex.load(d / "splade", mmap=(mode == "mmap"))
-        searcher = PLAIDSearcher(index, plaid_params)
+        device = None if devices is None else devices[i]
+        searcher = PLAIDSearcher(index, plaid_params, device=device)
         kw = {} if multistage_params is None \
             else {"params": multistage_params}
-        retr = MultiStageRetriever(
-            sidx, searcher,
-            device=None if devices is None else devices[i], **kw)
+        retr = MultiStageRetriever(sidx, searcher, device=device, **kw)
         shards.append(retr)
     return ShardedRetriever(shards, boundaries)
 

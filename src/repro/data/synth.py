@@ -48,6 +48,9 @@ class SynthCfg:
     seed: int = 0
 
 
+_SLAB_DOCS = 2048
+
+
 def _unit(x, axis=-1):
     n = np.linalg.norm(x, axis=axis, keepdims=True)
     return x / np.maximum(n, 1e-9)
@@ -74,10 +77,16 @@ def make_corpus(cfg: SynthCfg):
     # cosine-meaningful regardless of dim.
     doc_vec = _unit(topics[doc_topic] + cfg.doc_sig * _unit(
         rng.normal(size=(cfg.n_docs, cfg.dim))))
-    tok = _unit(rng.normal(size=(cfg.n_docs, cfg.doc_maxlen, cfg.dim)))
-    doc_embs = _unit(doc_vec[:, None, :] + cfg.tok_noise * tok)
     mask = np.arange(cfg.doc_maxlen)[None] < doc_lens[:, None]
-    doc_embs = (doc_embs * mask[..., None]).astype(np.float32)
+    doc_embs = np.empty((cfg.n_docs, cfg.doc_maxlen, cfg.dim), np.float32)
+    # token scatter in slabs of docs: the same draws, in the same order,
+    # as one (n_docs, doc_maxlen, dim) call, without its float64
+    # temporaries (tens of GB at 128-d, 180-token documents)
+    for lo in range(0, cfg.n_docs, _SLAB_DOCS):
+        hi = min(lo + _SLAB_DOCS, cfg.n_docs)
+        tok = _unit(rng.normal(size=(hi - lo, cfg.doc_maxlen, cfg.dim)))
+        emb = _unit(doc_vec[lo:hi, None, :] + cfg.tok_noise * tok)
+        doc_embs[lo:hi] = emb * mask[lo:hi, :, None]
 
     # sparse vectors: Zipfian draw from the doc's topic terms
     ranks = np.arange(1, cfg.terms_per_topic + 1)
@@ -124,7 +133,7 @@ def make_corpus(cfg: SynthCfg):
 
     qrels = [{int(p)} for p in q_rel]
     return {
-        "doc_embs": doc_embs.astype(np.float32),
+        "doc_embs": doc_embs,
         "doc_lens": doc_lens.astype(np.int32),
         "doc_term_ids": doc_term_ids,
         "doc_term_weights": doc_term_w,

@@ -19,6 +19,9 @@ from repro.index import ivf as ivf_mod
 from repro.index import kmeans, residual
 
 
+_ENCODE_SLAB = 1 << 20        # tokens per residual-encoding dispatch
+
+
 def build_colbert_index(out_dir, doc_embs: np.ndarray, doc_lens: np.ndarray,
                         *, nbits: int = 4, n_centroids: int | None = None,
                         kmeans_iters: int = 8, sample_cap: int = 65536,
@@ -76,9 +79,15 @@ def build_colbert_index(out_dir, doc_embs: np.ndarray, doc_lens: np.ndarray,
                                    np.asarray(kmeans.assign(
                                        jnp.asarray(sample),
                                        jnp.asarray(centroids))[0]), nbits)
-    packed = np.asarray(residual.encode_residuals(
-        jnp.asarray(flat), jnp.asarray(cids), codec.centroids,
+    # encode in slabs: one call over millions of tokens needs more
+    # device memory than a chip has (the per-token bucket search keeps
+    # several (N, dim) temporaries); rows are independent, so slabs
+    # produce the same codes
+    packed = np.concatenate([np.asarray(residual.encode_residuals(
+        jnp.asarray(flat[lo:lo + _ENCODE_SLAB]),
+        jnp.asarray(cids[lo:lo + _ENCODE_SLAB]), codec.centroids,
         codec.bucket_cutoffs, nbits))
+        for lo in range(0, max(n_tokens, 1), _ENCODE_SLAB)])
 
     # persist
     PagedStore.write(out, cids, packed, dim=dim, nbits=nbits)

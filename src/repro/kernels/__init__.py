@@ -1,3 +1,13 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels, each with a jnp reference oracle (``ref.py``)
+and a public wrapper (``ops.py``)."""
+
+import jax
+
+
+def resolve_impl(impl: str) -> str:
+    """Kernel wrappers' ``impl``: ``auto`` takes the Pallas kernel on a
+    TPU and the jnp reference elsewhere; ``pallas``, ``interpret`` (the
+    kernel body without Mosaic, for tests) and ``ref`` force one."""
+    if impl == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return impl

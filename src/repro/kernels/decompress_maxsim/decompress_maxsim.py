@@ -4,20 +4,33 @@ This is the TPU-native adaptation of the paper's memory-mapping insight.
 On the CPU system, mmap avoids materialising the index in RAM; on TPU
 the equivalent waste is materialising *decompressed fp32 embeddings* in
 HBM between a decompression op and a scoring op. The fusion keeps the
-decompressed tile strictly in VMEM:
+decoded residuals strictly in VMEM.
 
-  HBM traffic per doc token:  packed codes (d·nbits/8 = 64 B at 4-bit)
-                              + centroid id (4 B) + valid (1 B)
-  vs. unfused:                + fp32 embedding write+read (2·512 B)
+The centroid table (2^15..2^17 rows × d f32 = 16..64 MiB) never enters
+the kernel. A decoded token is ``c + r`` (centroid plus residual), so
 
-  ⇒ ~16× less HBM traffic for the scoring stage, turning a memory-bound
-  pipeline into an MXU-bound one (see benchmarks/bench_kernels.py).
+    q·(c + r) = q·c + q·r
 
-Centroid rows are fetched from a VMEM-resident table — valid for tables
-up to ~4 K centroids (2 MiB at d=128); larger tables take the
-``gather='onehot'`` strategy (MXU one-hot matmul over K-tiles, always
-lowerable) or fall back to the unfused path. Both strategies are
-validated against the oracle in interpret mode.
+and the ``q·c`` term is a gather from the per-query centroid-score
+table ``Q·Cᵀ`` (``(Lq, K)``, the table PLAID's stage-1 probe computes).
+The gather runs in XLA before the kernel (``kernel_operands``); the
+kernel decodes only the residual bucket codes, for which a
+``2^nbits``-entry weight table in SMEM suffices.
+
+Layout: candidates sit on the 128 lanes of a vreg, tokens on a leading
+axis. One grid step scores one tile of ``LANES`` candidates of one
+query: for each document token ``t`` it decodes the ``(d, LANES)``
+residual panel, takes one ``(Lq, d)·(d, LANES)`` MXU product, adds the
+gathered ``q·c`` panel and folds the result into a running
+``(Lq, LANES)`` max. Every block's last two dimensions are either the
+whole array dimension or multiples of (8, 128), as Mosaic requires.
+
+Precision: every dot runs at ``Precision.HIGHEST`` (full float32; the
+TPU default for float32 operands is a single bf16 pass), here and in
+the jnp references, so kernel and reference differ only in summation
+order and in rounding ``c + r`` before the product. That bounds the
+difference by a few float32 ulp of the score's magnitude; see
+:func:`score_atol`.
 """
 
 from __future__ import annotations
@@ -26,135 +39,141 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.common.utils import round_up
 
 NEG = -1e30
+LANES = 128          # candidates per tile: one vreg row of lanes
+HIGHEST = jax.lax.Precision.HIGHEST
+# Ulps of headroom in :func:`score_atol`. Kernel and reference round
+# differently in three places (c + r before the product, q·c and q·r as
+# two sums, the sum over query tokens), each worth at most a couple of
+# ulps of the score's scale at these sizes.
+SCORE_ULPS = 8
 
 
-def _decode_tile(packed, cids, centroids, weights_oh, nbits, gather):
-    """packed (T, d/cpb) u8, cids (T,) i32 → emb (T, d) f32 in-VMEM."""
-    cpb = 8 // nbits
-    mask = (1 << nbits) - 1
-    shifts = (jnp.arange(cpb, dtype=jnp.uint8) * nbits)
-    codes = (packed[..., None] >> shifts) & jnp.uint8(mask)
-    T = packed.shape[0]
-    codes = codes.reshape(T, packed.shape[1] * cpb)          # (T, d)
-
-    # bucket LUT via one-hot (16-wide — trivial on the VPU/MXU)
-    n_buckets = 1 << nbits
-    oh = (codes[..., None] == jnp.arange(n_buckets, dtype=jnp.uint8)
-          ).astype(jnp.float32)                              # (T, d, 2^b)
-    res = jax.lax.dot_general(
-        oh.reshape(-1, n_buckets), weights_oh,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).reshape(T, -1)   # (T, d)
-
-    K = centroids.shape[0]
-    if gather == "take":
-        base = jnp.take(centroids, cids, axis=0)             # (T, d)
-    else:  # onehot gather on the MXU — always lowerable
-        coh = (cids[:, None] == jnp.arange(K, dtype=jnp.int32)
-               ).astype(jnp.float32)                         # (T, K)
-        base = jax.lax.dot_general(coh, centroids,
-                                   (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-    return base + res
+def score_atol(ref_scores) -> float:
+    """Absolute tolerance between a kernel's MaxSim scores and the
+    float32 reference: ``SCORE_ULPS`` float32 ulps of the largest
+    reference magnitude. Both sides compute every dot in full float32
+    (``Precision.HIGHEST``) and differ only in rounding order."""
+    finite = np.abs(np.asarray(ref_scores, np.float32))
+    finite = finite[np.isfinite(finite)]
+    scale = np.float32(finite.max()) if finite.size else np.float32(1)
+    return float(SCORE_ULPS * np.spacing(max(scale, np.float32(1))))
 
 
-def _score_tile(q, packed, cids, valid, qv, centroids, weights, nbits,
-                gather):
-    """Shared kernel body: decode one (BC, Ld) tile in-VMEM and score it.
-    q (Lq, d); packed (BC, Ld, d/cpb); cids/valid (BC, Ld); qv (Lq,);
-    centroids (K, d); weights (2^nbits,) → (BC,) f32."""
-    bc, ld = cids.shape
-    emb = _decode_tile(packed.reshape(bc * ld, -1), cids.reshape(-1),
-                       centroids, weights, nbits, gather)     # (BC·Ld, d)
+def kernel_operands(q, packed, cids, valid, q_valid, centroids, nbits: int):
+    """XLA-side preparation shared by the MaxSim kernels.
 
-    s = jax.lax.dot_general(q, emb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s.reshape(q.shape[0], bc, ld)
-    s = jnp.where(valid[None] != 0, s, NEG)
-    per_q = jnp.max(s, axis=-1)
-    per_q = jnp.where(per_q <= NEG / 2, 0.0, per_q)
-    per_q = per_q * (qv[:, None] != 0).astype(per_q.dtype)
-    return jnp.sum(per_q, axis=0)
+    q (B, Lq, d); packed (B, C, Ld, pd) u8; cids/valid (B, C, Ld);
+    q_valid (B, Lq) → (q_perm (B, Lq, d) f32,
+    packed_t (B, T, Ld, pd, LANES) u8, qc (B, T, Ld, Lq, LANES) f32)
+    with T = ceil(C / LANES).
 
-
-def _kernel(q_ref, packed_ref, cids_ref, valid_ref, qvalid_ref,
-            centroids_ref, weights_ref, out_ref, *, nbits, gather):
-    out_ref[...] = _score_tile(q_ref[...], packed_ref[...], cids_ref[...],
-                               valid_ref[...], qvalid_ref[...],
-                               centroids_ref[...], weights_ref[...],
-                               nbits, gather)
-
-
-def _batch_kernel(q_ref, packed_ref, cids_ref, valid_ref, qvalid_ref,
-                  centroids_ref, weights_ref, out_ref, *, nbits, gather):
-    # leading grid axis walks the query batch; centroid/bucket tables
-    # stay batch-invariant VMEM residents
-    out_ref[0, :] = _score_tile(q_ref[0], packed_ref[0], cids_ref[0],
-                                valid_ref[0], qvalid_ref[0],
-                                centroids_ref[...], weights_ref[...],
-                                nbits, gather)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("nbits", "block_c", "gather", "interpret"))
-def decompress_maxsim_pallas(q, packed, cids, valid, q_valid, centroids,
-                             bucket_weights, *, nbits: int, block_c: int = 16,
-                             gather: str = "take", interpret: bool = False):
-    C, Ld, pd = packed.shape
-    Lq, d = q.shape
-    K = centroids.shape[0]
-    assert C % block_c == 0
-    grid = (C // block_c,)
-    kernel = functools.partial(_kernel, nbits=nbits, gather=gather)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((Lq, d), lambda i: (0, 0)),
-            pl.BlockSpec((block_c, Ld, pd), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_c, Ld), lambda i: (i, 0)),
-            pl.BlockSpec((block_c, Ld), lambda i: (i, 0)),
-            pl.BlockSpec((Lq,), lambda i: (0,)),
-            pl.BlockSpec((K, d), lambda i: (0, 0)),      # whole table
-            pl.BlockSpec((1 << nbits,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block_c,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((C,), jnp.float32),
-        interpret=interpret,
-    )(q, packed, cids, valid, q_valid, centroids, bucket_weights)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("nbits", "block_c", "gather", "interpret"))
-def decompress_maxsim_pallas_batch(q, packed, cids, valid, q_valid,
-                                   centroids, bucket_weights, *, nbits: int,
-                                   block_c: int = 16, gather: str = "take",
-                                   interpret: bool = False):
-    """Batched fused scoring: q (B, Lq, d); packed (B, C, Ld, pd);
-    cids/valid (B, C, Ld); q_valid (B, Lq) → (B, C). The whole batch is
-    one kernel launch — stage 4 scores B queries in one dispatch."""
+    * Invalid query tokens are zeroed in ``q`` and in the table, so they
+      contribute ``max(0) = 0`` exactly as the reference's mask does.
+    * Invalid document tokens (and padded candidates) get ``q·c = NEG``,
+      which no residual term can lift back above ``NEG / 2``.
+    * Byte ``j`` of a packed row holds the codes of dims
+      ``j·cpb .. j·cpb + cpb - 1``; the kernel decodes shift group ``s``
+      of every byte into rows ``s·pd + j``, so ``q``'s columns are
+      permuted to match instead of interleaving codes in-kernel.
+    """
     B, C, Ld, pd = packed.shape
     Lq, d = q.shape[1:]
-    K = centroids.shape[0]
-    assert C % block_c == 0
-    grid = (B, C // block_c)
-    kernel = functools.partial(_batch_kernel, nbits=nbits, gather=gather)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, Lq, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_c, Ld, pd), lambda b, i: (b, i, 0, 0)),
-            pl.BlockSpec((1, block_c, Ld), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_c, Ld), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Lq), lambda b, i: (b, 0)),
-            pl.BlockSpec((K, d), lambda b, i: (0, 0)),   # whole table
-            pl.BlockSpec((1 << nbits,), lambda b, i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, block_c), lambda b, i: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((B, C), jnp.float32),
+    cpb = 8 // nbits
+    Cp = round_up(max(C, 1), LANES)
+    T = Cp // LANES
+    qv = q_valid.astype(jnp.float32)
+    q = q.astype(jnp.float32) * qv[..., None]
+    table = jnp.einsum("bqd,kd->bqk", q, centroids.astype(jnp.float32),
+                       precision=HIGHEST,
+                       preferred_element_type=jnp.float32)   # (B, Lq, K)
+    pad = Cp - C
+    cids = jnp.pad(cids.astype(jnp.int32), ((0, 0), (0, pad), (0, 0)))
+    valid = jnp.pad(valid.astype(bool), ((0, 0), (0, pad), (0, 0)))
+    packed = jnp.pad(packed, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    # (B, Cp, Ld) → (B, T, Ld, LANES): candidate on the lane axis
+    cids_t = cids.reshape(B, T, LANES, Ld).transpose(0, 1, 3, 2)
+    valid_t = valid.reshape(B, T, LANES, Ld).transpose(0, 1, 3, 2)
+    b_ix = jnp.arange(B)[:, None, None, None, None]
+    q_ix = jnp.arange(Lq)[None, None, None, :, None]
+    qc = table[b_ix, q_ix, cids_t[:, :, :, None, :]]   # (B, T, Ld, Lq, L)
+    qc = jnp.where(valid_t[:, :, :, None, :], qc, NEG)
+    packed_t = packed.reshape(B, T, LANES, Ld, pd).transpose(0, 1, 3, 4, 2)
+    q_perm = q.reshape(B, Lq, pd, cpb).transpose(0, 1, 3, 2).reshape(
+        B, Lq, d)
+    return q_perm, packed_t, qc
+
+
+def tile_scores(q_ref, packed_ref, qc_ref, w_ref, nbits: int):
+    """Shared kernel body: MaxSim scores of one candidate tile.
+
+    q_ref (1, Lq, d); packed_ref (1, 1, Ld, pd, LANES) u8;
+    qc_ref (1, 1, Ld, Lq, LANES); w_ref (2^nbits,) SMEM → (1, LANES)."""
+    q = q_ref[0]
+    Lq = q.shape[0]
+    Ld = packed_ref.shape[2]
+    cpb = 8 // nbits
+    mask = (1 << nbits) - 1
+
+    def token(t, m):
+        x = packed_ref[0, 0, t].astype(jnp.int32)          # (pd, LANES)
+        groups = []
+        for s in range(cpb):
+            code = (x >> (s * nbits)) & mask
+            r = jnp.zeros(code.shape, jnp.float32)
+            for v in range(1 << nbits):
+                r = jnp.where(code == v, w_ref[v], r)
+            groups.append(r)
+        r = jnp.concatenate(groups, axis=0)                 # (d, LANES)
+        sim = jax.lax.dot(q, r, precision=HIGHEST,
+                          preferred_element_type=jnp.float32)
+        return jnp.maximum(m, sim + qc_ref[0, 0, t])
+
+    m = jax.lax.fori_loop(0, Ld, token,
+                          jnp.full((Lq, LANES), NEG, jnp.float32))
+    m = jnp.where(m <= NEG / 2, 0.0, m)
+    return jnp.sum(m, axis=0, keepdims=True)
+
+
+def operand_specs(Lq: int, d: int, Ld: int, pd: int):
+    """BlockSpecs of (q_perm, packed_t, qc, bucket weights) for a
+    ``(B, T)`` grid."""
+    return [
+        pl.BlockSpec((1, Lq, d), lambda b, i: (b, 0, 0)),
+        pl.BlockSpec((1, 1, Ld, pd, LANES), lambda b, i: (b, i, 0, 0, 0)),
+        pl.BlockSpec((1, 1, Ld, Lq, LANES), lambda b, i: (b, i, 0, 0, 0)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+    ]
+
+
+def _batch_kernel(q_ref, packed_ref, qc_ref, w_ref, out_ref, *, nbits):
+    out_ref[0] = tile_scores(q_ref, packed_ref, qc_ref, w_ref, nbits)
+
+
+@functools.partial(jax.jit, static_argnames=("nbits", "interpret"))
+def decompress_maxsim_pallas_batch(q, packed, cids, valid, q_valid,
+                                   centroids, bucket_weights, *, nbits: int,
+                                   interpret: bool = False):
+    """Batched fused scoring: q (B, Lq, d); packed (B, C, Ld, pd) u8;
+    cids/valid (B, C, Ld); q_valid (B, Lq) → (B, C) f32. The whole batch
+    is one kernel launch — stage 4 scores B queries in one dispatch."""
+    B, C, Ld, pd = packed.shape
+    Lq, d = q.shape[1:]
+    q_perm, packed_t, qc = kernel_operands(q, packed, cids, valid, q_valid,
+                                           centroids, nbits)
+    T = packed_t.shape[1]
+    out = pl.pallas_call(
+        functools.partial(_batch_kernel, nbits=nbits),
+        grid=(B, T),
+        in_specs=operand_specs(Lq, d, Ld, pd),
+        out_specs=pl.BlockSpec((1, 1, LANES), lambda b, i: (b, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, T * LANES), jnp.float32),
         interpret=interpret,
-    )(q, packed, cids, valid, q_valid, centroids, bucket_weights)
+    )(q_perm, packed_t, qc, bucket_weights.astype(jnp.float32))
+    return out[:, 0, :C]

@@ -1,4 +1,5 @@
-"""Public wrapper for the fused decompress+MaxSim kernel."""
+"""Public wrapper for the fused decompress+MaxSim kernel (``impl``:
+see :func:`repro.kernels.resolve_impl`)."""
 
 from __future__ import annotations
 
@@ -7,80 +8,50 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.common.utils import round_up
+from repro.kernels import resolve_impl
 from repro.kernels.decompress_maxsim.decompress_maxsim import (
-    decompress_maxsim_pallas,
     decompress_maxsim_pallas_batch,
 )
 from repro.kernels.decompress_maxsim.ref import (decompress_maxsim_batch_ref,
                                                  decompress_maxsim_ref)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("nbits", "impl", "block_c", "gather"))
+@functools.partial(jax.jit, static_argnames=("nbits", "impl"))
 def decompress_maxsim_scores(q, packed, cids, doc_valid, centroids,
                              bucket_weights, *, nbits: int,
-                             q_valid=None, impl: str = "auto",
-                             block_c: int = 16, gather: str = "take"):
+                             q_valid=None, impl: str = "auto"):
     """Fused scoring over compressed candidates.
 
     q: (Lq, d); packed: (C, Ld, d·nbits/8) uint8; cids: (C, Ld) int32;
     doc_valid: (C, Ld) bool → (C,) f32 scores.
     """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
     if q_valid is None:
         q_valid = jnp.ones((q.shape[0],), bool)
-    if impl == "ref":
+    if resolve_impl(impl) == "ref":
         return decompress_maxsim_ref(q, packed, cids, doc_valid, centroids,
                                      bucket_weights, nbits, q_valid)
-
-    C = packed.shape[0]
-    Cp = round_up(max(C, 1), block_c)
-    if Cp != C:
-        packed = jnp.pad(packed, ((0, Cp - C), (0, 0), (0, 0)))
-        cids = jnp.pad(cids, ((0, Cp - C), (0, 0)))
-        doc_valid = jnp.pad(doc_valid, ((0, Cp - C), (0, 0)))
-    out = decompress_maxsim_pallas(
-        q.astype(jnp.float32), packed, cids.astype(jnp.int32),
-        doc_valid.astype(jnp.int8), q_valid.astype(jnp.int8),
-        centroids.astype(jnp.float32), bucket_weights.astype(jnp.float32),
-        nbits=nbits, block_c=block_c, gather=gather,
-        interpret=(impl == "interpret"))
-    return out[:C]
+    return decompress_maxsim_scores_batch(
+        q[None], packed[None], cids[None], doc_valid[None], centroids,
+        bucket_weights, nbits=nbits, q_valid=q_valid[None], impl=impl)[0]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("nbits", "impl", "block_c", "gather"))
+@functools.partial(jax.jit, static_argnames=("nbits", "impl"))
 def decompress_maxsim_scores_batch(q, packed, cids, doc_valid, centroids,
                                    bucket_weights, *, nbits: int,
-                                   q_valid=None, impl: str = "auto",
-                                   block_c: int = 16, gather: str = "take"):
+                                   q_valid=None, impl: str = "auto"):
     """Cross-query batched fused scoring (the stage-4 batch dispatch).
 
     q: (B, Lq, d); packed: (B, C, Ld, d·nbits/8) uint8; cids: (B, C, Ld)
     int32; doc_valid: (B, C, Ld) bool; q_valid: optional (B, Lq) bool
     (False on padded query tokens) → (B, C) f32 scores.
     """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl(impl)
     if q_valid is None:
         q_valid = jnp.ones(q.shape[:2], bool)
     if impl == "ref":
         return decompress_maxsim_batch_ref(q, packed, cids, doc_valid,
                                            centroids, bucket_weights, nbits,
                                            q_valid)
-
-    B, C = packed.shape[:2]
-    Cp = round_up(max(C, 1), block_c)
-    if Cp != C:
-        packed = jnp.pad(packed, ((0, 0), (0, Cp - C), (0, 0), (0, 0)))
-        cids = jnp.pad(cids, ((0, 0), (0, Cp - C), (0, 0)))
-        doc_valid = jnp.pad(doc_valid, ((0, 0), (0, Cp - C), (0, 0)))
-    out = decompress_maxsim_pallas_batch(
-        q.astype(jnp.float32), packed, cids.astype(jnp.int32),
-        doc_valid.astype(jnp.int8), q_valid.astype(jnp.int8),
-        centroids.astype(jnp.float32), bucket_weights.astype(jnp.float32),
-        nbits=nbits, block_c=block_c, gather=gather,
-        interpret=(impl == "interpret"))
-    return out[:, :C]
+    return decompress_maxsim_pallas_batch(
+        q, packed, cids, doc_valid, q_valid, centroids, bucket_weights,
+        nbits=nbits, interpret=(impl == "interpret"))

@@ -2,7 +2,6 @@
 in one tiled dispatch (FLASH-MAXSIM-style; see fused_rerank.py)."""
 
 from repro.kernels.fused_rerank.ops import (  # noqa: F401
-    HAVE_PALLAS,
     fused_rerank_topk,
     fused_rerank_topk_batch,
 )

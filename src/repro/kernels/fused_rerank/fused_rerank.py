@@ -3,30 +3,28 @@ top-k over candidate tiles — the FLASH-MAXSIM-style rerank tail.
 
 The split stage-4 tail runs three dispatches (decompress+MaxSim scores,
 score masking, top-k selection) and materialises the full ``(B, C)``
-score tensor — and, unfused, the ``(B, C, Lq, Ld)`` similarity tensor —
-in HBM between them. This kernel streams packed residual codes through
-VMEM one candidate tile at a time, decompresses against the
-VMEM-resident centroid table in-register (``_decode_tile``), scores the
-tile (``_score_tile``, shared with ``decompress_maxsim`` so the fused
-and split paths compute *identical* per-candidate arithmetic), and
-folds the tile into a running per-query top-k held in the output block
-across grid steps. Nothing wider than one ``(block_c,)`` score slice
-ever exists:
-
-  HBM traffic per query:  packed codes + ids + valid   (the tile stream)
-                          + 2·k·4 B result             (scores + indices)
-  vs. split:              + C·4 B scores write+read + top-k pass
+score tensor in HBM between them. This kernel scores one tile of
+``LANES`` candidates per grid step with the same body as
+``decompress_maxsim`` (``tile_scores``: the ``q·c`` table gather plus
+an in-VMEM residual decode, so the centroid table never enters the
+kernel) and folds the tile into a running per-query top-k held in the
+output block across grid steps. Nothing wider than one ``(1, LANES)``
+score row ever exists.
 
 The running top-k merge is *sortless*: each grid step ranks the
-``k_pad + block_c`` merged entries by pairwise comparison counts
+``kp + LANES`` merged entries by pairwise comparison counts
 (rank_j = #{m : (s_m, -i_m) ≻ (s_j, -i_j)}) and gathers entry ``j``
 into output slot ``rank_j`` with a masked sum — O(n²) compares on the
-VPU with n ≈ 144, no sort lowering required, and the (score desc,
-index asc) tie order is exactly ``lax.top_k``'s, so the fused result is
-bitwise the split path's. Candidate tiles arrive in ascending index
-order and the running entries always carry lower indices than the
-incoming tile, which is what makes the incremental merge reproduce the
-global stable order.
+VPU with n = kp + 128, no sort lowering required, and the (score desc,
+index asc) tie order is exactly ``lax.top_k``'s. Candidate tiles arrive
+in ascending index order and the running entries always carry lower
+indices than the incoming tile, which is what makes the incremental
+merge reproduce the global stable order.
+
+Precision: the scores are those of ``decompress_maxsim`` (full float32
+dots); they agree with the reference within
+``decompress_maxsim.score_atol`` and the selected indices agree
+wherever neighbouring scores differ by more than that.
 """
 
 from __future__ import annotations
@@ -37,142 +35,89 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.decompress_maxsim.decompress_maxsim import _score_tile
+from repro.kernels.decompress_maxsim.decompress_maxsim import (
+    LANES,
+    kernel_operands,
+    operand_specs,
+    tile_scores,
+)
 
 
 def _merge_topk(prev_s, prev_i, tile_s, tile_i, kp: int):
-    """Rank-selection merge of the running (kp,) state with a scored
-    tile: top-``kp`` of the concatenation by (score desc, index asc).
-    All indices are distinct, so ranks are a permutation and the masked
-    sums gather exactly one entry per output slot (-inf survives the
-    where-sum; no -inf·0 NaNs)."""
-    ms = jnp.concatenate([prev_s, tile_s])
-    mi = jnp.concatenate([prev_i, tile_i])
-    beats = (ms[None, :] > ms[:, None]) | (
-        (ms[None, :] == ms[:, None]) & (mi[None, :] < mi[:, None]))
-    rank = jnp.sum(beats.astype(jnp.int32), axis=1)          # (n,)
-    sel = rank[None, :] == jnp.arange(kp, dtype=jnp.int32)[:, None]
-    out_s = jnp.sum(jnp.where(sel, ms[None, :], 0.0), axis=1)
-    out_i = jnp.sum(jnp.where(sel, mi[None, :], 0), axis=1)
+    """Rank-selection merge of the running (1, kp) state with a scored
+    (1, LANES) tile: top-``kp`` of the concatenation by (score desc,
+    index asc). All indices are distinct, so ranks are a permutation
+    and the masked sums gather exactly one entry per output slot (-inf
+    survives the where-sum; no -inf·0 NaNs)."""
+    ms = jnp.concatenate([prev_s, tile_s], axis=1)            # (1, n)
+    mi = jnp.concatenate([prev_i, tile_i], axis=1)
+    n = ms.shape[1]
+    row_s = jnp.broadcast_to(ms, (n, n))                      # [j, m] = s_m
+    row_i = jnp.broadcast_to(mi, (n, n))
+    col_s, col_i = row_s.T, row_i.T                           # [j, m] = s_j
+    beats = (row_s > col_s) | ((row_s == col_s) & (row_i < col_i))
+    rank = jnp.sum(beats.astype(jnp.float32), axis=1, keepdims=True)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, kp), 1)
+    sel = rank == slot.astype(jnp.float32)                    # (n, kp)
+    out_s = jnp.sum(jnp.where(sel, col_s[:, :kp], 0.0), axis=0,
+                    keepdims=True)
+    out_i = jnp.sum(jnp.where(sel, col_i[:, :kp], 0), axis=0,
+                    keepdims=True)
     return out_s, out_i
 
 
-def _tile_state(step, s, prev_s, prev_i, cmask, kp: int, block_c: int,
-                total_c: int):
-    """One grid step of the running top-k: mask the tile's scores, give
-    each entry its global candidate index, and merge with the carried
-    state. ``step == 0`` replaces the (uninitialised) carried state with
-    sentinels that lose every comparison: -inf scores with indices past
-    ``total_c``, so real candidates — even masked ones, which tie at
-    -inf but carry lower indices — always displace them."""
-    s = jnp.where(cmask != 0, s, -jnp.inf)
-    tile_i = step * block_c + jnp.arange(block_c, dtype=jnp.int32)
-    first = step == 0
-    prev_s = jnp.where(first, -jnp.inf, prev_s)
-    prev_i = jnp.where(first,
-                       total_c + jnp.arange(kp, dtype=jnp.int32), prev_i)
-    return _merge_topk(prev_s, prev_i, s, tile_i, kp)
-
-
-def _kernel(q_ref, packed_ref, cids_ref, valid_ref, cmask_ref, qvalid_ref,
-            centroids_ref, weights_ref, out_s_ref, out_i_ref, *,
-            nbits, gather, kp, block_c):
-    i = pl.program_id(0)
-    s = _score_tile(q_ref[...], packed_ref[...], cids_ref[...],
-                    valid_ref[...], qvalid_ref[...], centroids_ref[...],
-                    weights_ref[...], nbits, gather)
-    out_s_ref[...], out_i_ref[...] = _tile_state(
-        i, s, out_s_ref[...], out_i_ref[...], cmask_ref[...], kp,
-        block_c, pl.num_programs(0) * block_c)
-
-
-def _batch_kernel(q_ref, packed_ref, cids_ref, valid_ref, cmask_ref,
-                  qvalid_ref, centroids_ref, weights_ref, out_s_ref,
-                  out_i_ref, *, nbits, gather, kp, block_c):
-    # grid (B, C//block_c): for a fixed batch row the candidate tiles
-    # run consecutively, so the (1, kp) output block stays VMEM-resident
+def _batch_kernel(q_ref, packed_ref, qc_ref, w_ref, cmask_ref, out_s_ref,
+                  out_i_ref, *, nbits, kp):
+    # grid (B, T): for a fixed batch row the candidate tiles run
+    # consecutively, so the (1, 1, kp) output blocks stay VMEM-resident
     # as the running top-k state across the whole row
     i = pl.program_id(1)
-    s = _score_tile(q_ref[0], packed_ref[0], cids_ref[0], valid_ref[0],
-                    qvalid_ref[0], centroids_ref[...], weights_ref[...],
-                    nbits, gather)
-    out_s_ref[0, :], out_i_ref[0, :] = _tile_state(
-        i, s, out_s_ref[0, :], out_i_ref[0, :], cmask_ref[0], kp,
-        block_c, pl.num_programs(1) * block_c)
+
+    @pl.when(i == 0)
+    def _():
+        # sentinels lose every comparison: -inf scores with indices past
+        # every candidate, so real candidates — even masked ones, which
+        # tie at -inf but carry lower indices — always displace them
+        total = pl.num_programs(1) * LANES
+        out_s_ref[0] = jnp.full((1, kp), -jnp.inf, jnp.float32)
+        out_i_ref[0] = total + jax.lax.broadcasted_iota(jnp.int32,
+                                                        (1, kp), 1)
+
+    s = tile_scores(q_ref, packed_ref, qc_ref, w_ref, nbits)
+    s = jnp.where(cmask_ref[0] != 0, s, -jnp.inf)
+    tile_i = i * LANES + jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    out_s_ref[0], out_i_ref[0] = _merge_topk(out_s_ref[0], out_i_ref[0], s,
+                                             tile_i, kp)
 
 
-@functools.partial(jax.jit, static_argnames=("nbits", "kp", "block_c",
-                                             "gather", "interpret"))
-def fused_rerank_pallas(q, packed, cids, valid, cmask, q_valid, centroids,
-                        bucket_weights, *, nbits: int, kp: int,
-                        block_c: int = 16, gather: str = "take",
-                        interpret: bool = False):
-    """Single-query fused tail: q (Lq, d); packed (C, Ld, pd) u8;
-    cids/valid (C, Ld); cmask (C,) i8 → (scores (kp,), idx (kp,) i32),
-    the top-``kp`` of the masked MaxSim scores in (desc, index-asc)
-    order. Requires ``C % block_c == 0`` and ``kp <= C``."""
-    C, Ld, pd = packed.shape
-    Lq, d = q.shape
-    K = centroids.shape[0]
-    assert C % block_c == 0 and 0 < kp <= C
-    grid = (C // block_c,)
-    kernel = functools.partial(_kernel, nbits=nbits, gather=gather,
-                               kp=kp, block_c=block_c)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((Lq, d), lambda i: (0, 0)),
-            pl.BlockSpec((block_c, Ld, pd), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_c, Ld), lambda i: (i, 0)),
-            pl.BlockSpec((block_c, Ld), lambda i: (i, 0)),
-            pl.BlockSpec((block_c,), lambda i: (i,)),
-            pl.BlockSpec((Lq,), lambda i: (0,)),
-            pl.BlockSpec((K, d), lambda i: (0, 0)),      # whole table
-            pl.BlockSpec((1 << nbits,), lambda i: (0,)),
-        ],
-        out_specs=(pl.BlockSpec((kp,), lambda i: (0,)),
-                   pl.BlockSpec((kp,), lambda i: (0,))),
-        out_shape=(jax.ShapeDtypeStruct((kp,), jnp.float32),
-                   jax.ShapeDtypeStruct((kp,), jnp.int32)),
-        interpret=interpret,
-    )(q, packed, cids, valid, cmask, q_valid, centroids, bucket_weights)
-
-
-@functools.partial(jax.jit, static_argnames=("nbits", "kp", "block_c",
-                                             "gather", "interpret"))
+@functools.partial(jax.jit, static_argnames=("nbits", "kp", "interpret"))
 def fused_rerank_pallas_batch(q, packed, cids, valid, cmask, q_valid,
                               centroids, bucket_weights, *, nbits: int,
-                              kp: int, block_c: int = 16,
-                              gather: str = "take",
-                              interpret: bool = False):
-    """Batched fused tail: q (B, Lq, d); packed (B, C, Ld, pd);
-    cids/valid (B, C, Ld); cmask (B, C) i8; q_valid (B, Lq) →
-    (scores (B, kp), idx (B, kp)). One kernel launch reranks the whole
-    micro-batch — the single device dispatch of the fused stage."""
+                              kp: int, interpret: bool = False):
+    """Batched fused tail: q (B, Lq, d); packed (B, C, Ld, pd) u8;
+    cids/valid (B, C, Ld); cmask (B, C) bool; q_valid (B, Lq) →
+    (scores (B, kp), idx (B, kp)), the top-``kp`` of the masked MaxSim
+    scores in (desc, index-asc) order. ``kp`` is a multiple of
+    ``LANES``; slots past the candidate count hold (-inf, index ≥ C).
+    One kernel launch reranks the whole micro-batch — the single device
+    dispatch of the fused stage."""
     B, C, Ld, pd = packed.shape
     Lq, d = q.shape[1:]
-    K = centroids.shape[0]
-    assert C % block_c == 0 and 0 < kp <= C
-    grid = (B, C // block_c)
-    kernel = functools.partial(_batch_kernel, nbits=nbits, gather=gather,
-                               kp=kp, block_c=block_c)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, Lq, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_c, Ld, pd), lambda b, i: (b, i, 0, 0)),
-            pl.BlockSpec((1, block_c, Ld), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_c, Ld), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_c), lambda b, i: (b, i)),
-            pl.BlockSpec((1, Lq), lambda b, i: (b, 0)),
-            pl.BlockSpec((K, d), lambda b, i: (0, 0)),   # whole table
-            pl.BlockSpec((1 << nbits,), lambda b, i: (0,)),
-        ],
-        out_specs=(pl.BlockSpec((1, kp), lambda b, i: (b, 0)),
-                   pl.BlockSpec((1, kp), lambda b, i: (b, 0))),
-        out_shape=(jax.ShapeDtypeStruct((B, kp), jnp.float32),
-                   jax.ShapeDtypeStruct((B, kp), jnp.int32)),
+    assert kp % LANES == 0, kp
+    q_perm, packed_t, qc = kernel_operands(q, packed, cids, valid, q_valid,
+                                           centroids, nbits)
+    T = packed_t.shape[1]
+    cm = jnp.pad(cmask.astype(jnp.int32), ((0, 0), (0, T * LANES - C)))
+    out_spec = pl.BlockSpec((1, 1, kp), lambda b, i: (b, 0, 0))
+    vals, idx = pl.pallas_call(
+        functools.partial(_batch_kernel, nbits=nbits, kp=kp),
+        grid=(B, T),
+        in_specs=operand_specs(Lq, d, Ld, pd) + [
+            pl.BlockSpec((1, 1, LANES), lambda b, i: (b, 0, i))],
+        out_specs=(out_spec, out_spec),
+        out_shape=(jax.ShapeDtypeStruct((B, 1, kp), jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1, kp), jnp.int32)),
         interpret=interpret,
-    )(q, packed, cids, valid, cmask, q_valid, centroids, bucket_weights)
+    )(q_perm, packed_t, qc, bucket_weights.astype(jnp.float32),
+      cm[:, None, :])
+    return vals[:, 0], idx[:, 0]

@@ -3,9 +3,7 @@
 ``impl`` selection follows the repo convention: ``auto`` takes the
 Pallas kernel on TPU and the fused-XLA reference elsewhere (same fused
 semantics, one dispatch either way); ``interpret`` runs the kernel body
-Mosaic-free for CI parity. The module degrades gracefully when the
-Pallas toolchain is absent (``HAVE_PALLAS``): ``auto`` then always
-resolves to the reference and serving falls back to the split tail.
+Mosaic-free for tests.
 """
 
 from __future__ import annotations
@@ -16,31 +14,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.utils import round_up
+from repro.kernels import resolve_impl
+from repro.kernels.decompress_maxsim.decompress_maxsim import LANES
+from repro.kernels.fused_rerank.fused_rerank import fused_rerank_pallas_batch
 from repro.kernels.fused_rerank.ref import (
     _pad_topk,
     fused_rerank_batch_ref,
     fused_rerank_ref,
 )
-
-try:
-    from repro.kernels.fused_rerank.fused_rerank import (
-        fused_rerank_pallas,
-        fused_rerank_pallas_batch,
-    )
-    HAVE_PALLAS = True
-except Exception:                                    # pragma: no cover
-    fused_rerank_pallas = fused_rerank_pallas_batch = None
-    HAVE_PALLAS = False
-
-
-def _resolve_impl(impl: str) -> str:
-    if impl == "auto":
-        return ("pallas" if HAVE_PALLAS
-                and jax.default_backend() == "tpu" else "ref")
-    if impl in ("pallas", "interpret") and not HAVE_PALLAS:
-        raise RuntimeError("Pallas unavailable: fused_rerank impl "
-                           f"{impl!r} cannot run (use impl='ref')")
-    return impl
 
 
 def _empty_topk(lead, k: int):
@@ -49,62 +30,41 @@ def _empty_topk(lead, k: int):
             jnp.full(shape, -1, jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("nbits", "k", "impl",
-                                             "block_c", "gather"))
+@functools.partial(jax.jit, static_argnames=("nbits", "k", "impl"))
 def fused_rerank_topk(q, packed, cids, doc_valid, cand_mask, centroids,
                       bucket_weights, *, nbits: int, k: int, q_valid=None,
-                      impl: str = "auto", block_c: int = 16,
-                      gather: str = "take"):
+                      impl: str = "auto"):
     """Fused rerank tail over one query's compressed candidates.
 
     q: (Lq, d); packed: (C, Ld, d·nbits/8) uint8; cids: (C, Ld) int32;
     doc_valid: (C, Ld) bool; cand_mask: (C,) bool → (scores (k,) f32
-    desc, idx (k,) i32) — exactly ``lax.top_k`` of the -inf-masked
-    MaxSim scores, ``(-inf, -1)``-padded when ``k > C``.
+    desc, idx (k,) i32) — ``lax.top_k`` of the -inf-masked MaxSim
+    scores, ``(-inf, -1)``-padded when ``k > C``.
     """
-    impl = _resolve_impl(impl)
-    C = packed.shape[0]
-    kk = min(k, C)
-    if kk == 0:
-        return _empty_topk((), k)
     if q_valid is None:
         q_valid = jnp.ones((q.shape[0],), bool)
-    if impl == "ref":
+    if resolve_impl(impl) == "ref":
         return fused_rerank_ref(q, packed, cids, doc_valid, cand_mask,
                                 centroids, bucket_weights, nbits, k,
                                 q_valid)
-
-    Cp = round_up(C, block_c)
-    if Cp != C:
-        packed = jnp.pad(packed, ((0, Cp - C), (0, 0), (0, 0)))
-        cids = jnp.pad(cids, ((0, Cp - C), (0, 0)))
-        doc_valid = jnp.pad(doc_valid, ((0, Cp - C), (0, 0)))
-        cand_mask = jnp.pad(cand_mask, ((0, Cp - C),))
-    # running-state width padded for lane alignment; the top-kk prefix
-    # of a top-kp selection is the top-kk selection, so slicing is exact
-    kp = min(round_up(kk, 8), Cp)
-    vals, idx = fused_rerank_pallas(
-        q.astype(jnp.float32), packed, cids.astype(jnp.int32),
-        doc_valid.astype(jnp.int8), cand_mask.astype(jnp.int8),
-        q_valid.astype(jnp.int8), centroids.astype(jnp.float32),
-        bucket_weights.astype(jnp.float32), nbits=nbits, kp=kp,
-        block_c=block_c, gather=gather, interpret=(impl == "interpret"))
-    return _pad_topk(vals[:kk], idx[:kk], k)
+    vals, idx = fused_rerank_topk_batch(
+        q[None], packed[None], cids[None], doc_valid[None], cand_mask[None],
+        centroids, bucket_weights, nbits=nbits, k=k, q_valid=q_valid[None],
+        impl=impl)
+    return vals[0], idx[0]
 
 
-@functools.partial(jax.jit, static_argnames=("nbits", "k", "impl",
-                                             "block_c", "gather"))
+@functools.partial(jax.jit, static_argnames=("nbits", "k", "impl"))
 def fused_rerank_topk_batch(q, packed, cids, doc_valid, cand_mask,
                             centroids, bucket_weights, *, nbits: int,
-                            k: int, q_valid=None, impl: str = "auto",
-                            block_c: int = 16, gather: str = "take"):
+                            k: int, q_valid=None, impl: str = "auto"):
     """Cross-query batched fused tail — the stage-4 single dispatch.
 
     q: (B, Lq, d); packed: (B, C, Ld, d·nbits/8) uint8; cids/doc_valid:
     (B, C, Ld); cand_mask: (B, C) bool; q_valid: optional (B, Lq) bool
     → (scores (B, k) f32 desc, idx (B, k) i32 into the candidate axis).
     """
-    impl = _resolve_impl(impl)
+    impl = resolve_impl(impl)
     B, C = packed.shape[:2]
     kk = min(k, C)
     if kk == 0:
@@ -115,18 +75,10 @@ def fused_rerank_topk_batch(q, packed, cids, doc_valid, cand_mask,
         return fused_rerank_batch_ref(q, packed, cids, doc_valid,
                                       cand_mask, centroids,
                                       bucket_weights, nbits, k, q_valid)
-
-    Cp = round_up(C, block_c)
-    if Cp != C:
-        packed = jnp.pad(packed, ((0, 0), (0, Cp - C), (0, 0), (0, 0)))
-        cids = jnp.pad(cids, ((0, 0), (0, Cp - C), (0, 0)))
-        doc_valid = jnp.pad(doc_valid, ((0, 0), (0, Cp - C), (0, 0)))
-        cand_mask = jnp.pad(cand_mask, ((0, 0), (0, Cp - C)))
-    kp = min(round_up(kk, 8), Cp)
+    # running-state width padded to whole lane rows; the top-kk prefix
+    # of a top-kp selection is the top-kk selection, so slicing is exact
     vals, idx = fused_rerank_pallas_batch(
-        q.astype(jnp.float32), packed, cids.astype(jnp.int32),
-        doc_valid.astype(jnp.int8), cand_mask.astype(jnp.int8),
-        q_valid.astype(jnp.int8), centroids.astype(jnp.float32),
-        bucket_weights.astype(jnp.float32), nbits=nbits, kp=kp,
-        block_c=block_c, gather=gather, interpret=(impl == "interpret"))
+        q, packed, cids, doc_valid, cand_mask, q_valid, centroids,
+        bucket_weights, nbits=nbits, kp=round_up(kk, LANES),
+        interpret=(impl == "interpret"))
     return _pad_topk(vals[:, :kk], idx[:, :kk], k)

@@ -12,7 +12,8 @@ def maxsim_scores_ref(q, docs, doc_valid, q_valid=None):
     Fully-invalid docs score 0.
     """
     s = jnp.einsum("qd,cld->cql", q.astype(jnp.float32),
-                   docs.astype(jnp.float32))
+                   docs.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
     s = jnp.where(doc_valid[:, None, :], s, -jnp.inf)
     per_q = jnp.max(s, axis=-1)                       # (C, Lq)
     per_q = jnp.where(jnp.isfinite(per_q), per_q, 0.0)

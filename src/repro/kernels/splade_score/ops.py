@@ -10,27 +10,22 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.utils import round_up
+from repro.kernels import resolve_impl
 from repro.kernels.splade_score.ref import (splade_block_scores_batch_ref,
                                             splade_block_scores_ref)
-from repro.kernels.splade_score.splade_score import (splade_block_pallas,
-                                                     splade_block_pallas_batch)
+from repro.kernels.splade_score.splade_score import splade_block_pallas_batch
 
 
 def _chunked(pids, vals, chunk: int):
-    """Reshape (…, Qt, max_df) postings into chunk-aligned rows, padding
+    """Reshape (…, Qt, max_df) postings into (…, R, chunk) rows, padding
     the entry count up to a multiple of ``chunk`` with −1/0 entries."""
     *lead, Qt, max_df = pids.shape
     E = Qt * max_df
-    Ep = round_up(E, chunk)
-    if Ep == E:
-        return pids, vals
-    pad_rows = (Ep - E) // max_df + 1
-    pad_width = [(0, 0)] * len(lead) + [(0, pad_rows), (0, 0)]
-    pids = jnp.pad(pids, pad_width, constant_values=-1)
-    vals = jnp.pad(vals, pad_width)
-    pids = pids.reshape(*lead, -1)[..., :Ep].reshape(*lead, -1, chunk)
-    vals = vals.reshape(*lead, -1)[..., :Ep].reshape(*lead, -1, chunk)
-    return pids, vals
+    pad = [(0, 0)] * len(lead) + [(0, round_up(E, chunk) - E)]
+    pids = jnp.pad(pids.reshape(*lead, E), pad, constant_values=-1)
+    vals = jnp.pad(vals.reshape(*lead, E), pad)
+    return (pids.reshape(*lead, -1, chunk),
+            vals.reshape(*lead, -1, chunk))
 
 
 @functools.partial(jax.jit,
@@ -39,20 +34,12 @@ def splade_block_scores(post_pids, post_imps, term_weights, *, n_docs: int,
                         impl: str = "auto", block_d: int = 2048,
                         chunk: int = 512):
     """Impact scores for one query over padded postings → (n_docs,) f32."""
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if impl == "ref":
+    if resolve_impl(impl) == "ref":
         return splade_block_scores_ref(post_pids, post_imps, term_weights,
                                        n_docs)
-    valid = (post_pids >= 0) & (term_weights[:, None] > 0)  # match ref mask
-    vals = jnp.where(valid, term_weights[:, None] * post_imps, 0.0)
-    pids = jnp.where(valid, post_pids, -1)
-    pids, vals = _chunked(pids, vals, chunk)
-    out = splade_block_pallas(pids.astype(jnp.int32),
-                              vals.astype(jnp.float32),
-                              n_docs=n_docs, block_d=block_d, chunk=chunk,
-                              interpret=(impl == "interpret"))
-    return out[:n_docs]
+    return splade_block_scores_batch(
+        post_pids[None], post_imps[None], term_weights[None],
+        n_docs=n_docs, impl=impl, block_d=block_d, chunk=chunk)[0]
 
 
 @functools.partial(jax.jit,
@@ -66,8 +53,7 @@ def splade_block_scores_batch(post_pids, post_imps, term_weights, *,
     f32 (de-quantised); term_weights: (B, Qt) f32 (0 disables a term)
     → (B, n_docs) f32. One dispatch for the whole batch.
     """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl(impl)
     if impl == "ref":
         return splade_block_scores_batch_ref(post_pids, post_imps,
                                              term_weights, n_docs)
@@ -78,7 +64,6 @@ def splade_block_scores_batch(post_pids, post_imps, term_weights, *,
     out = splade_block_pallas_batch(pids.astype(jnp.int32),
                                     vals.astype(jnp.float32),
                                     n_docs=n_docs, block_d=block_d,
-                                    chunk=chunk,
                                     interpret=(impl == "interpret"))
     return out[:, :n_docs]
 

@@ -6,20 +6,23 @@ lists — hostile to a vector unit. The TPU-native re-think partitions
 the *score vector* over a grid of doc-id blocks; each grid step scans
 every (query-term, posting) pair once and accumulates the entries whose
 pid falls inside its block. The scatter becomes a dense one-hot matmul
-on the MXU:
+on the MXU. A block of ``block_d = 128·H`` doc ids is laid out as an
+``(H, 128)`` panel and a local pid splits into ``hi = pid // 128``,
+``lo = pid % 128``, so for a chunk of postings
 
-    scores[lo:hi] += wᵀ · onehot(pid − lo)     (E × BD one-hot panel)
+    panel[hi, lo] += Σ_e [hi_e = hi]·v_e · [lo_e = lo]
+                   = (A · Bᵀ)[hi, lo],   A (H, chunk), B (128, chunk)
 
-Posting entries stream through VMEM in chunks so the one-hot panel is
-bounded (chunk × BD fp32 ≤ 4 MiB by default). Work per block is
-O(E · BD) MACs — embarrassingly parallel over blocks, no data-dependent
-control flow, and the block grid is how the score vector shards over
-the 'model' mesh axis in the distributed serve path.
+— an ``(H, chunk)·(chunk, 128)`` product instead of a matrix-vector
+product against a ``(chunk, block_d)`` one-hot. The dot runs at
+``Precision.HIGHEST``: ``B`` is exact in bf16 and the three-way bf16
+split of ``A`` carries every float32 bit of ``w·imp``, so each panel
+entry is a float32 sum of the same terms the segment-sum reference
+adds, in another order (a few ulp apart).
 
-The batched variant adds a leading batch axis to the grid (one kernel
-launch scores the whole micro-batch): each (b, i) step owns query b's
-postings and doc block i, so cross-query batches cost one dispatch
-instead of B.
+The grid is ``(B, n_blocks)``: each (b, i) step owns query b's
+postings and doc block i, so a cross-query micro-batch costs one
+dispatch.
 """
 
 from __future__ import annotations
@@ -30,89 +33,55 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANES = 128
 
-def _score_block(pids, vals, lo, *, block_d: int, chunk: int):
-    """Shared tile body: accumulate postings into one doc-id block.
 
-    pids: (E,) int32 (−1 padded); vals: (E,) f32 (w_t · imp, 0 padded);
-    lo: first pid of this block → (block_d,) f32 partial scores."""
-    E = pids.shape[0]
-    local = pids - lo
-    acc = jnp.zeros((block_d,), jnp.float32)
-    iota = jax.lax.iota(jnp.int32, block_d)
-    for c in range(E // chunk):
-        lc = jax.lax.dynamic_slice(local, (c * chunk,), (chunk,))
-        vc = jax.lax.dynamic_slice(vals, (c * chunk,), (chunk,))
-        oh = (lc[:, None] == iota[None, :]).astype(jnp.float32)  # (chunk, BD)
-        acc = acc + jax.lax.dot_general(
-            vc, oh, (((0,), (0,)), ((), ())),
+def _batch_kernel(pids_ref, vals_ref, out_ref, *, block_d: int):
+    # pids/vals blocks (1, R, chunk): R chunks of one query's postings
+    # (−1 / 0 padded); out block (1, 1, H, LANES) = doc block i
+    H = block_d // LANES
+    R, chunk = pids_ref.shape[1:]
+    lo_base = pl.program_id(1) * block_d
+    hi_iota = jax.lax.broadcasted_iota(jnp.int32, (H, chunk), 0)
+    lo_iota = jax.lax.broadcasted_iota(jnp.int32, (LANES, chunk), 0)
+
+    def row(r, acc):
+        local = pids_ref[0, pl.ds(r, 1), :] - lo_base           # (1, chunk)
+        v = vals_ref[0, pl.ds(r, 1), :]
+        # out-of-block and −1 pids give hi outside [0, H): a zero row of A
+        a = jnp.where((local >> 7) == hi_iota, v, 0.0)            # (H, chunk)
+        b = ((local & (LANES - 1)) == lo_iota).astype(jnp.float32)
+        return acc + jax.lax.dot_general(
+            a, b, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
-    return acc
 
-
-def _kernel(pids_ref, vals_ref, out_ref, *, block_d: int, chunk: int):
-    i = pl.program_id(0)
-    out_ref[...] = _score_block(pids_ref[...].reshape(-1),
-                                vals_ref[...].reshape(-1), i * block_d,
-                                block_d=block_d, chunk=chunk)
-
-
-def _batch_kernel(pids_ref, vals_ref, out_ref, *, block_d: int, chunk: int):
-    # grid (B, n_blocks): axis 0 walks the query batch, axis 1 the doc-id
-    # blocks; blocks carry a size-1 batch dim squeezed before the body
-    i = pl.program_id(1)
-    out_ref[0, :] = _score_block(pids_ref[0].reshape(-1),
-                                 vals_ref[0].reshape(-1), i * block_d,
-                                 block_d=block_d, chunk=chunk)
+    out_ref[0, 0] = jax.lax.fori_loop(0, R, row,
+                                      jnp.zeros((H, LANES), jnp.float32))
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_docs", "block_d", "chunk", "interpret"))
-def splade_block_pallas(post_pids, post_vals, *, n_docs: int,
-                        block_d: int = 2048, chunk: int = 512,
-                        interpret: bool = False):
-    """post_pids: (Qt, max_df) int32; post_vals: (Qt, max_df) f32 (weight
-    pre-multiplied, 0 at padding). Returns (n_docs_padded,) f32 scores;
-    caller slices [:n_docs]."""
-    Qt, max_df = post_pids.shape
-    E = Qt * max_df
-    assert E % chunk == 0, (E, chunk)
-    n_blocks = -(-n_docs // block_d)
-    kernel = functools.partial(_kernel, block_d=block_d, chunk=chunk)
-    return pl.pallas_call(
-        kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((Qt, max_df), lambda i: (0, 0)),   # postings resident
-            pl.BlockSpec((Qt, max_df), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_d,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks * block_d,), jnp.float32),
-        interpret=interpret,
-    )(post_pids, post_vals)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n_docs", "block_d", "chunk", "interpret"))
+                   static_argnames=("n_docs", "block_d", "interpret"))
 def splade_block_pallas_batch(post_pids, post_vals, *, n_docs: int,
-                              block_d: int = 2048, chunk: int = 512,
-                              interpret: bool = False):
-    """Batched stage-1 dispatch: post_pids (B, Qt, max_df) int32;
-    post_vals (B, Qt, max_df) f32 → (B, n_docs_padded) f32; caller
-    slices [:, :n_docs]. One kernel launch for the whole micro-batch."""
-    B, Qt, max_df = post_pids.shape
-    E = Qt * max_df
-    assert E % chunk == 0, (E, chunk)
+                              block_d: int = 2048, interpret: bool = False):
+    """Batched stage-1 dispatch: post_pids (B, R, chunk) int32 (−1 pad);
+    post_vals (B, R, chunk) f32 (weight pre-multiplied, 0 at padding) →
+    (B, n_docs_padded) f32; caller slices [:, :n_docs]. ``block_d`` is a
+    multiple of 128. One kernel launch for the whole micro-batch."""
+    B, R, chunk = post_pids.shape
+    assert block_d % LANES == 0, block_d
+    H = block_d // LANES
     n_blocks = -(-n_docs // block_d)
-    kernel = functools.partial(_batch_kernel, block_d=block_d, chunk=chunk)
-    return pl.pallas_call(
-        kernel,
+    out = pl.pallas_call(
+        functools.partial(_batch_kernel, block_d=block_d),
         grid=(B, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, Qt, max_df), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Qt, max_df), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, R, chunk), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, R, chunk), lambda b, i: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_d), lambda b, i: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((B, n_blocks * block_d), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, H, LANES), lambda b, i: (b, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, n_blocks, H, LANES),
+                                       jnp.float32),
         interpret=interpret,
     )(post_pids, post_vals)
+    return out.reshape(B, n_blocks * block_d)

@@ -11,8 +11,6 @@ from typing import Optional
 
 import jax
 
-from repro.common.compat import make_mesh
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -20,13 +18,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = 1
     for s in shape:
         n *= s
-    return make_mesh(shape, axes, devices=jax.devices()[:n])
+    return jax.make_mesh(shape, axes, devices=jax.devices()[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_local_mesh():
     """1-device mesh with the production axis names — lets the same
     pjit'd code paths run in tests/benchmarks on one CPU device."""
-    return make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def mesh_device_count(mesh) -> int:
@@ -90,11 +90,11 @@ def shard_worker_env(n_workers: int, *, pin_host_threads: bool = False,
                      base: Optional[dict] = None) -> dict:
     """Environment for spawned shard *worker processes*.
 
-    Inherits the parent env and pins ``JAX_PLATFORMS`` to ``cpu``
-    unless the caller already set it: most accelerators are
-    single-owner per host, and N worker processes racing to initialise
-    the same device would fail (the coordinator keeps the accelerator;
-    workers own the mmap/host side).
+    Inherits the parent env and pins ``JAX_PLATFORMS`` to ``cpu``: an
+    accelerator belongs to one process, and a parent that has touched
+    JAX holds it, so a child that tried to open it would fail or hang
+    (the coordinator keeps the accelerator; workers own the mmap/host
+    side).
 
     ``pin_host_threads`` restricts each worker's XLA CPU compute to one
     thread — worth it when ``n_workers`` approaches the core count so
@@ -104,7 +104,7 @@ def shard_worker_env(n_workers: int, *, pin_host_threads: bool = False,
     thread == shards-1, bitwise) requires workers to run the exact XLA
     configuration the coordinator would have used."""
     env = dict(os.environ if base is None else base)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     if pin_host_threads and n_workers > 1 and "XLA_FLAGS" not in env:
         env["XLA_FLAGS"] = ("--xla_cpu_multi_thread_eigen=false "
                             "intra_op_parallelism_threads=1")

@@ -25,6 +25,7 @@ from repro.data.synth import SynthCfg, make_corpus
 from repro.index.builder import ColBERTIndex, build_colbert_index
 from repro.index.sharding import split_index_tree
 from repro.index.splade_index import SpladeIndex, build_splade_index
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import shard_device_map
 from repro.serving.admission import AdmissionController
 from repro.serving.context import CacheHierarchy
@@ -139,8 +140,7 @@ def main():
                          "top-k in ONE device dispatch (the tiled "
                          "fused_rerank kernel on TPU, a fused XLA tail "
                          "elsewhere), split = the legacy multi-dispatch "
-                         "tail. Results are bitwise-identical; fused "
-                         "degrades to split when Pallas is unavailable")
+                         "tail")
     ap.add_argument("--shards", type=int, default=1,
                     help=">=2: partition the index into this many "
                          "contiguous doc-range shards (scatter-gather "
@@ -255,6 +255,7 @@ def main():
     ap.add_argument("--qps", type=float, default=2.0)
     ap.add_argument("--n", type=int, default=60)
     args = ap.parse_args()
+    enable_compile_cache()
 
     depth = (args.pipeline_depth if args.pipeline_depth is not None
              else (2 if args.pipeline else 1))
@@ -297,12 +298,8 @@ def main():
         batch_timeout_ms=args.batch_timeout_ms,
         latency_slo_ms=args.latency_slo_ms, admission=admission)
     server.start()
-    rb = getattr(retr, "rerank_backend", args.rerank_backend)
-    if rb != args.rerank_backend:
-        print(f"rerank backend {args.rerank_backend!r} unavailable "
-              f"(no Pallas toolchain) — falling back to {rb!r}")
     print(f"serving ({args.mode} index, {args.threads} thread(s), "
-          f"stage1={args.splade_backend}, rerank={rb}, "
+          f"stage1={args.splade_backend}, rerank={args.rerank_backend}, "
           f"pipeline_depth={depth}, "
           f"shards={args.shards} [{args.shard_workers} workers]); "
           f"pool={index.store.total_bytes() / 1e6:.1f} MB")

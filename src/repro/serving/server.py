@@ -551,6 +551,9 @@ class _Handler(socketserver.StreamRequestHandler):
 class TCPRetrievalServer(socketserver.ThreadingTCPServer):
     allow_reuse_address = True
     daemon_threads = True
+    # listen backlog: socketserver's default of 5 resets connections when
+    # a burst of clients connects at once
+    request_queue_size = 128
 
     def __init__(self, addr, retrieval_server: RetrievalServer):
         super().__init__(addr, _Handler)

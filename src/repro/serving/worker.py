@@ -292,7 +292,9 @@ def spawn_standalone(shard_dir, shard_index: int = 0, *,
            "--mode", mode, "--port", str(port),
            "--plaid-json", json.dumps(plaid_params or {}),
            "--ms-json", json.dumps(ms_params or {})]
-    env = dict(os.environ)
+    from repro.launch.mesh import shard_worker_env
+
+    env = shard_worker_env(1)
     env["PYTHONPATH"] = _src_pythonpath()
     proc = subprocess.Popen(cmd, env=env, stdin=subprocess.DEVNULL,
                             stdout=subprocess.PIPE, text=True)
@@ -346,7 +348,9 @@ def main(argv=None):
     from repro.core.plaid import PLAIDSearcher, PlaidParams
     from repro.index.builder import ColBERTIndex
     from repro.index.splade_index import SpladeIndex
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     d = pathlib.Path(args.shard_dir)
     index = ColBERTIndex(d / "colbert", mode=args.mode)
     sidx = SpladeIndex.load(d / "splade", mmap=(args.mode == "mmap"))

@@ -41,8 +41,8 @@ def test_maxsim_batch_equals_loop(impl, block_c):
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("impl,block_c", [("ref", 16), ("interpret", 4)])
-def test_decompress_maxsim_batch_equals_loop(impl, block_c):
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+def test_decompress_maxsim_batch_equals_loop(impl):
     B, C, Ld, Lq, d, nbits, K = 3, 20, 12, 8, 32, 4, 16
     k = jax.random.PRNGKey(1)
     q = jax.random.normal(k, (B, Lq, d))
@@ -56,7 +56,7 @@ def test_decompress_maxsim_batch_equals_loop(impl, block_c):
     bw = jnp.linspace(-0.3, 0.3, 2 ** nbits)
     batch = decompress_maxsim_scores_batch(q, packed, cids, dv, cent, bw,
                                            nbits=nbits, q_valid=qv,
-                                           impl=impl, block_c=block_c)
+                                           impl=impl)
     loop = jnp.stack([decompress_maxsim_scores(q[b], packed[b], cids[b],
                                                dv[b], cent, bw, nbits=nbits,
                                                q_valid=qv[b], impl="ref")

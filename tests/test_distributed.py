@@ -17,7 +17,6 @@ def test_pjit_train_matches_single_device():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.training.optimizer import AdamWCfg, adamw_init, adamw_update
-from repro.common.compat import make_mesh
 
 W = jax.random.normal(jax.random.PRNGKey(0), (16, 8))
 def loss_fn(params, batch):
@@ -48,7 +47,8 @@ def trajectory(mesh=None):
     return losses
 
 l1 = trajectory(None)
-mesh = make_mesh((4, 2), ('data', 'model'))
+mesh = jax.make_mesh((4, 2), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 with mesh:
     l2 = trajectory(mesh)
 np.testing.assert_allclose(l1, l2, rtol=1e-5, atol=1e-6)
@@ -64,16 +64,17 @@ def test_elastic_restore_across_meshes():
 import jax, jax.numpy as jnp, numpy as np, tempfile
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.training import checkpoint as C
-from repro.common.compat import make_mesh
 
 tree = {'w': jax.random.normal(jax.random.PRNGKey(0), (8, 16)),
         'b': jnp.arange(16.0)}
-mesh_a = make_mesh((4, 2), ('data', 'model'))
+mesh_a = jax.make_mesh((4, 2), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 sh_a = {'w': NamedSharding(mesh_a, P('data', 'model')), 'b': NamedSharding(mesh_a, P('model'))}
 placed = jax.tree.map(lambda x, s: jax.device_put(x, s), tree, sh_a)
 with tempfile.TemporaryDirectory() as d:
     C.save_checkpoint(d, 3, placed)
-    mesh_b = make_mesh((2, 4), ('data', 'model'))
+    mesh_b = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
     sh_b = {'w': NamedSharding(mesh_b, P('model', 'data')), 'b': NamedSharding(mesh_b, P())}
     step, restored = C.load_checkpoint(d, template=tree, shardings=sh_b)
     assert step == 3
@@ -93,8 +94,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from jax.experimental.shard_map import shard_map
 from repro.training.compression import q8_psum
-from repro.common.compat import make_mesh
-mesh = make_mesh((8,), ('pod',))
+mesh = jax.make_mesh((8,), ('pod',),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 1)
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 4, 256))
 exact = jnp.sum(x, axis=0)
 f = shard_map(lambda v: q8_psum(v[0], 'pod'), mesh=mesh,
@@ -118,8 +119,8 @@ from repro.configs.registry import ARCHS
 from repro.configs.cells import build_cell
 from repro.launch import hlo_analysis
 
-from repro.common.compat import make_mesh
-mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 arch = ARCHS['qwen3-14b']
 with mesh:
     cell = build_cell(arch, 'train_4k', mesh, cfg=arch.smoke_cfg(),
@@ -140,8 +141,8 @@ def test_hlo_analyzer_scan_ground_truth():
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hlo_analysis import analyze
-from repro.common.compat import make_mesh
-mesh = make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 def f(ws, x):
     y, _ = jax.lax.scan(lambda c, w: (jnp.tanh(c @ w), None), x, ws)
     return y
@@ -163,8 +164,8 @@ def test_recsys_sharded_lookup_matches_replicated():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models.recsys import embedding as EB
-from repro.common.compat import make_mesh
-mesh = make_mesh((2, 4), ('data', 'model'))
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 table = jax.random.normal(jax.random.PRNGKey(0), (64, 8))
 ids = jax.random.randint(jax.random.PRNGKey(1), (16, 3), 0, 64)
 with mesh:
@@ -184,9 +185,9 @@ def test_pipeline_parallel_matches_sequential():
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.pipeline_parallel import (bubble_fraction,
                                                  make_pipelined_fn)
-from repro.common.compat import make_mesh
 S, M, mb, d = 4, 8, 2, 16
-mesh = make_mesh((S,), ('pipe',))
+mesh = jax.make_mesh((S,), ('pipe',),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 1)
 ws = jax.random.normal(jax.random.PRNGKey(0), (S, d, d)) * 0.3
 bs = jax.random.normal(jax.random.PRNGKey(1), (S, d)) * 0.1
 params = {'w': ws, 'b': bs}

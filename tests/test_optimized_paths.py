@@ -99,8 +99,8 @@ import dataclasses, jax
 from repro.configs.registry import ARCHS
 from repro.configs import cells_opt as CO
 
-from repro.common.compat import make_mesh
-mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 with mesh:
     arch = ARCHS['colbert-serve']
     cfg = arch.smoke_cfg()

@@ -322,3 +322,52 @@ def test_tcp_error_response_carries_qid():
     finally:
         tcp.shutdown()
         srv.stop()
+
+
+def test_tcp_front_answers_a_connection_burst():
+    """Clients connecting all at once are queued by the listen backlog,
+    not reset."""
+    from concurrent.futures import ThreadPoolExecutor
+    srv = make_server(n_threads=2, service_s=0.0)
+    tcp = TCPRetrievalServer(("127.0.0.1", 0), srv)
+    port = tcp.server_address[1]
+    t = threading.Thread(target=tcp.serve_forever, daemon=True)
+    t.start()
+    try:
+        with ThreadPoolExecutor(64) as pool:
+            outs = list(pool.map(lambda i: tcp_query(
+                "127.0.0.1", port, {"qid": i, "q_emb": [0.0], "k": 3}),
+                range(64)))
+        assert sorted(o["qid"] for o in outs) == list(range(64))
+    finally:
+        tcp.shutdown()
+        tcp.server_close()
+        srv.stop()
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_placement(env_dir, tmp_path, monkeypatch):
+    """The entry points' cache follows JAX_COMPILATION_CACHE_DIR when it
+    is set (and then sets nothing), else a fixed <checkout>/.jax_cache."""
+    import pathlib
+
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    try:
+        got = enable_compile_cache()
+        if env_dir is None:
+            checkout = pathlib.Path(__file__).resolve().parents[1]
+            assert got == str(checkout / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

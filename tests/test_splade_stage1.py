@@ -186,12 +186,30 @@ def retr(built_index, small_corpus, sidx):
                                MultiStageParams(first_k=50, k=20))
 
 
+def _interpret_off_tpu(self, backend):
+    # the program refuses the Mosaic kernel off-TPU; tests run its body
+    # in interpret mode instead
+    return "ref" if backend == "jax" else "interpret"
+
+
+def test_pallas_backend_raises_without_tpu(retr):
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        retr.set_splade_backend("pallas")
+    assert retr.splade_backend == "host"
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        retr.run_splade_batch([np.array([1], np.int32)],
+                              [np.array([1.0], np.float32)], k=5,
+                              backend="pallas")
+
+
 @pytest.mark.parametrize("backend", ["jax", "pallas"])
 @pytest.mark.parametrize("method", ["splade", "rerank", "hybrid"])
 def test_search_batch_device_backend_matches_search(retr, small_corpus,
                                                     backend, method,
                                                     monkeypatch):
     B = 5
+    monkeypatch.setattr(MultiStageRetriever, "_splade_impl",
+                        _interpret_off_tpu)
     args = dict(
         q_embs=[small_corpus["q_embs"][i] for i in range(B)],
         term_ids=[small_corpus["q_term_ids"][i] for i in range(B)],
