@@ -216,12 +216,8 @@ class MultiStageRetriever:
             return None
         rows = [None if k is None else self._caches.stage1.get(k)
                 for k in keys]
-        n_hit = sum(r is not None for r in rows)
-        if n_hit < len(rows):
-            self.pipeline_stats.counter("cache_stage1_misses",
-                                        len(rows) - n_hit)
+        if any(r is None for r in rows):
             return None
-        self.pipeline_stats.counter("cache_stage1_hits", n_hit)
         return (np.stack([r[0] for r in rows]),
                 np.stack([r[1] for r in rows]))
 
@@ -283,8 +279,8 @@ class MultiStageRetriever:
                                    impl=self._splade_impl(backend))
         if _record:
             self.pipeline_stats.record(
-                "splade_stage1", HOST if backend == "host" else DEVICE,
-                time.perf_counter() - t0, queries=len(term_ids))
+                "splade_stage1", time.perf_counter() - t0,
+                queries=len(term_ids))
         return out
 
     def _run_splade_live(self, live, term_ids, term_weights, k: int):
@@ -350,8 +346,8 @@ class MultiStageRetriever:
 
         order = np.argsort(-final, kind="stable")[:k]
         out_pids = np.where(final[order] > -np.inf, pids[order], -1)
-        self.pipeline_stats.record("rest", HOST,
-                                   time.perf_counter() - t0, queries=1)
+        self.pipeline_stats.record("rest", time.perf_counter() - t0,
+                                   queries=1)
         return out_pids, final[order]
 
     # ------------------------------------------------------------------
@@ -360,11 +356,13 @@ class MultiStageRetriever:
     def build_batch(self, method: str, q_embs=None, term_ids=None,
                     term_weights=None, alphas=None, k: Optional[int] = None,
                     n: Optional[int] = None,
-                    ctxs=None) -> CandidateBatch:
+                    ctxs=None, qids=None) -> CandidateBatch:
         """Package per-query inputs into the immutable carrier a
         :class:`StagePlan` consumes. ``ctxs`` (optional per-query
         :class:`~repro.serving.context.RequestContext`) rides along so
-        plan stages can consult per-request cache keys."""
+        plan stages can consult per-request cache keys; ``qids``
+        (optional request ids) so each stage's profiler span names its
+        requests."""
         k = self.params.k if k is None else k
         if n is None:
             n = len(q_embs) if q_embs is not None else len(term_ids)
@@ -372,7 +370,8 @@ class MultiStageRetriever:
         return CandidateBatch(method=method, k=k, q_embs=pick(q_embs),
                               term_ids=pick(term_ids),
                               term_weights=pick(term_weights),
-                              alphas=alphas, ctxs=pick(ctxs))
+                              alphas=alphas, ctxs=pick(ctxs),
+                              qids=pick(qids))
 
     def compile_plan(self, method: str) -> StagePlan:
         """Compile one of the four systems to its typed stage graph.
@@ -426,8 +425,6 @@ class MultiStageRetriever:
                             else self._caches.stage1.get(k_)
                             for k_ in keys]
                     if all(r is not None for r in rows):
-                        self.pipeline_stats.counter("cache_stage1_hits",
-                                                    len(rows))
                         q, q_valid = pad_query_batch(cb.q_embs)
                         B, q, q_valid, final_np = _pad_batch_rows(
                             q, q_valid, np.stack([r[0] for r in rows]))
@@ -437,9 +434,6 @@ class MultiStageRetriever:
                             final_pids=jnp.asarray(final_np),
                             final_np=final_np, n_real=n_real,
                             stage1_cached=True)
-                    self.pipeline_stats.counter(
-                        "cache_stage1_misses",
-                        sum(r is None for r in rows))
                 st = searcher.probe_batch(cb.q_embs)
                 # sync candidates to host here, on the device worker —
                 # the host gather must not block on device work
@@ -555,9 +549,6 @@ class MultiStageRetriever:
             rows = [None if k_ is None else self._caches.stage1.get(k_)
                     for k_ in keys]
             miss = [i for i, r in enumerate(rows) if r is None]
-            self.pipeline_stats.counter("cache_stage1_hits",
-                                        len(rows) - len(miss))
-            self.pipeline_stats.counter("cache_stage1_misses", len(miss))
             if miss:
                 pids_m, scores_m = self.run_splade_batch(
                     [cb.term_ids[i] for i in miss],

@@ -23,6 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.common.utils import next_pow2 as _next_pow2
 from repro.core import hybrid as hybrid_mod
@@ -381,11 +382,12 @@ class PLAIDSearcher:
         into the candidate axis), kk = min(k, C), selection and tie
         order bitwise-identical to :meth:`exact_score_gathered` +
         ``lax.top_k``."""
-        return fused_rerank_topk_batch(
-            q, packed, codes.astype(jnp.int32), valid,
-            jnp.asarray(cand_mask), self.centroids, self.bucket_weights,
-            nbits=self.index.nbits, k=min(k, cand_mask.shape[1]),
-            q_valid=q_valid)
+        with TraceAnnotation("tail:dispatch"):
+            return fused_rerank_topk_batch(
+                q, packed, codes.astype(jnp.int32), valid,
+                jnp.asarray(cand_mask), self.centroids, self.bucket_weights,
+                nbits=self.index.nbits, k=min(k, cand_mask.shape[1]),
+                q_valid=q_valid)
 
     def fused_hybrid_topk_gathered(self, q, q_valid, codes, packed, valid,
                                    cand_mask, s_scores, alphas, k: int,
@@ -393,12 +395,13 @@ class PLAIDSearcher:
         """Hybrid fused tail (see :func:`fused_hybrid_tail`): scoring +
         α-fusion + top-k in one dispatch. Returns lazy (scores (b, kk),
         idx (b, kk)), kk = min(k, first_k)."""
-        return fused_hybrid_tail(
-            q, packed, codes.astype(jnp.int32), valid,
-            jnp.asarray(cand_mask), self.centroids, self.bucket_weights,
-            q_valid, jnp.asarray(s_scores), jnp.asarray(alphas),
-            nbits=self.index.nbits, k=min(k, cand_mask.shape[1]), b=b,
-            normalizer=normalizer)
+        with TraceAnnotation("tail:dispatch"):
+            return fused_hybrid_tail(
+                q, packed, codes.astype(jnp.int32), valid,
+                jnp.asarray(cand_mask), self.centroids, self.bucket_weights,
+                q_valid, jnp.asarray(s_scores), jnp.asarray(alphas),
+                nbits=self.index.nbits, k=min(k, cand_mask.shape[1]), b=b,
+                normalizer=normalizer)
 
     def finalize_topk_fused(self, top_s, top_i, final_np, B: int, k: int):
         """Terminal formatting for the fused tail: map candidate-axis
@@ -488,6 +491,8 @@ class PLAIDSearcher:
         codes = codes_u[pos]
         valid = valid_u[pos] & mask
         packed = None if packed_u is None else packed_u[pos]
+        self.index.store.stats.transfer(
+            *(a for a in (codes, packed, valid) if a is not None))
         return codes, packed, valid
 
     # -- device-resident gather --------------------------------------------

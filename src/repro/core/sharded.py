@@ -1005,8 +1005,6 @@ class _ShardDispatcher:
                     s.replica = replica
             raise
         stats.counter("rpc_dispatches")
-        for s in slots:
-            stats.counter(f"rpc_ops:{s.op}")
         self._account(cli)
 
     def _account(self, cli):
@@ -1057,9 +1055,7 @@ class _ShardDispatcher:
             # its own connection; FIFO discipline consumes it later
             # without desequencing.
             g.pipeline_stats.counter("hedges")
-            out = g._resend_slot(self.i, slot)
-            g.pipeline_stats.counter("hedge_wins")
-            return out
+            return g._resend_slot(self.i, slot)
         except (ShardWorkerDied, DeadlineExceeded) as e:
             if (slot.op in MUTATION_OPS
                     or g._replica_sets[self.i].total == 1):

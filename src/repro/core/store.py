@@ -57,6 +57,7 @@ class AccessStats:
     unique_pages: Optional[set] = None  # residual pages, deduplicated
     residual_gathers: int = 0         # gathers that faulted residual rows
     residual_tokens_read: int = 0     # rows read from the residual file
+    h2d_bytes: int = 0                # gathered bytes bound for the device
 
     def __post_init__(self):
         self._lock = threading.Lock()
@@ -69,6 +70,7 @@ class AccessStats:
             self.unique_pages = set()
             self.residual_gathers = 0
             self.residual_tokens_read = 0
+            self.h2d_bytes = 0
 
     def account(self, token_ids: np.ndarray, packed_dim: int,
                 residuals: bool = True):
@@ -89,6 +91,13 @@ class AccessStats:
             if self.unique_pages is not None:
                 self.unique_pages.update(pages.tolist())
 
+    def transfer(self, *arrays):
+        """Record host arrays a gather hands on for the host→device copy
+        (their ``nbytes``, padded rows included)."""
+        n = sum(a.nbytes for a in arrays)
+        with self._lock:
+            self.h2d_bytes += n
+
     def snapshot(self) -> dict:
         """Atomic, plain-dict copy for cross-thread readers (per-stage
         instrumentation deltas, tests, benchmarks)."""
@@ -98,7 +107,8 @@ class AccessStats:
                     "pages_touched": self.pages_touched,
                     "unique_pages": len(self.unique_pages or ()),
                     "residual_gathers": self.residual_gathers,
-                    "residual_tokens_read": self.residual_tokens_read}
+                    "residual_tokens_read": self.residual_tokens_read,
+                    "h2d_bytes": self.h2d_bytes}
 
 
 class PagedStore:

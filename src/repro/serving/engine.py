@@ -24,6 +24,7 @@ from concurrent.futures import Future
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.multistage import MultiStageRetriever
 from repro.serving.context import (
@@ -40,6 +41,7 @@ from repro.serving.pipeline import (
     PipelineExecutor,
     PipelineStopped,
     gather_futures,
+    span_ids,
 )
 
 
@@ -272,11 +274,6 @@ class ServeEngine:
             if r.ctx is None:
                 r.ctx = self.context_for(r)
 
-    def _counter(self, name: str, delta: int = 1) -> None:
-        ps = getattr(self.retriever, "pipeline_stats", None)
-        if ps is not None and hasattr(ps, "counter"):
-            ps.counter(name, delta)
-
     def cache_lookup(self, req: Request,
                      count_miss: bool = True) -> Optional[Result]:
         """Exact-cache probe; a hit IS the answer (bitwise the cold
@@ -292,7 +289,6 @@ class ServeEngine:
         if hit is None:
             return None
         pids, scores = hit
-        self._counter("cache_exact_hits")
         now = time.perf_counter()
         with self._lock:
             self.served += 1
@@ -314,7 +310,6 @@ class ServeEngine:
             return
         caches.exact.put(ctx.cache_key, freeze(res.pids, res.scores),
                          getattr(self.retriever, "index_generation", 0))
-        self._counter("cache_exact_stores")
 
     @staticmethod
     def _effective_method(req: Request) -> str:
@@ -476,7 +471,8 @@ class ServeEngine:
                 term_ids=[miss[i].term_ids for i in idx],
                 term_weights=[miss[i].term_weights for i in idx],
                 alphas=alphas[idx], k=k_max,
-                ctxs=[miss[i].ctx for i in idx])
+                ctxs=[miss[i].ctx for i in idx],
+                qids=[miss[i].qid for i in idx])
             groups.append((m, idx, cb))
 
         out: Future = Future()
@@ -503,8 +499,10 @@ class ServeEngine:
                 out.set_exception(e)
                 return
             try:
-                assembled = self._assemble(miss, groups, f.result(),
-                                           n, k_max, t_start)
+                with TraceAnnotation("stage:assemble", **span_ids(
+                        [r.qid for r in miss])):
+                    assembled = self._assemble(miss, groups, f.result(),
+                                               n, k_max, t_start)
                 full = hits
                 for j, res in enumerate(assembled):
                     full[miss_idx[j]] = res

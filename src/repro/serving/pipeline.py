@@ -18,10 +18,16 @@ while kernels run. This module restructures the serving hot path around
   host-bound gather overlaps micro-batch N's device-bound dispatch
   (JAX dispatch and numpy mmap reads both release the GIL);
 * :class:`PipelineStats` is the single per-stage instrumentation
-  record — wall time, dispatches, queries, queue wait, EWMA service
-  time, mmap pages/tokens touched (folded in from ``AccessStats``),
-  and the measured host/device *overlap fraction* — surfaced through
-  ``RetrievalServer.health()`` and ``benchmarks/bench_latency.py``.
+  record — wall time, dispatches, queries, EWMA service time, mmap
+  pages touched and bytes bound for the device (folded in from
+  ``AccessStats``), the measured host/device *overlap fraction*, and
+  the process's JAX compiles — surfaced through
+  ``RetrievalServer.health()`` and ``benchmarks/bench_latency.py``;
+* every stage run is also a ``stage:<name>`` profiler span
+  (``jax.profiler.TraceAnnotation``) over the same interval as its
+  recorded wall time, carrying the batch's request ids (``qids``), so
+  a profile of the server names what the host did between the
+  device's operations, per request.
 
 Running a plan synchronously (``StagePlan.run``) and through the
 executor are the *same stage functions in the same order*, so
@@ -38,16 +44,30 @@ import dataclasses
 import queue
 import threading
 import time
+import weakref
 from concurrent.futures import Future
 from types import MappingProxyType
 from typing import Any, Callable, Mapping, Optional
 
+import jax.monitoring
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 HOST = "host"
 DEVICE = "device"
 
 STAGE_KINDS = (HOST, DEVICE)
+
+# the duration event JAX records once per program the backend compiles
+# (a persistent-cache hit records none)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def span_ids(qids) -> dict:
+    """Profiler-span stats that join a batch-level span to its requests:
+    ``qids``, the request ids joined by spaces (the profiler's metadata
+    encoding splits a value at commas). Empty when the ids are unknown."""
+    return {"qids": " ".join(map(str, qids))} if qids else {}
 
 
 class PipelineStopped(RuntimeError):
@@ -89,6 +109,7 @@ class CandidateBatch:
     term_weights: Optional[tuple] = None
     alphas: Optional[np.ndarray] = None     # (B,) hybrid interpolation
     ctxs: Optional[tuple] = None            # per-query RequestContext
+    qids: Optional[tuple] = None            # request ids (profiler spans)
     state: Mapping[str, Any] = _EMPTY_STATE
     shard_states: Optional[tuple] = None    # per-shard state mappings
     pids: Optional[np.ndarray] = None       # (B, k) final, -1 padded
@@ -196,32 +217,38 @@ class StagePlan:
         return tuple(s.name for s in self.stages)
 
     def run_stage(self, stage: Stage, cb: CandidateBatch,
-                  stats: Optional["PipelineStats"] = None,
-                  queue_wait_s: float = 0.0) -> CandidateBatch:
+                  stats: Optional["PipelineStats"] = None
+                  ) -> CandidateBatch:
+        """Run one stage as a ``stage:<name>`` profiler span; with
+        ``stats``, record its wall time (the span's interval), and for
+        host stages the mmap pages and device-bound bytes it gathered
+        (also set as the span's ``h2d_bytes``)."""
         acc = self.access_stats if stage.kind == HOST else None
         before = acc.snapshot() if acc is not None else None
         if stats is not None:
             if stage.closes_async:
                 stats.async_close()
             stats.stage_begin()
-        t0 = time.perf_counter()
-        try:
-            out = self._call_stage(stage, cb)
-        finally:
-            wall = time.perf_counter() - t0
-            if stats is not None:
-                stats.stage_end()
-        if stats is not None:
-            if stage.opens_async:
-                stats.async_open()
-            pages = tokens = 0
+        with TraceAnnotation(f"stage:{stage.name}",
+                             **span_ids(cb.qids)) as span:
+            t0 = time.perf_counter()
+            try:
+                out = self._call_stage(stage, cb)
+            finally:
+                wall = time.perf_counter() - t0
+                if stats is not None:
+                    stats.stage_end()
+            pages = h2d = 0
             if before is not None:
                 after = acc.snapshot()
                 pages = after["pages_touched"] - before["pages_touched"]
-                tokens = after["tokens_read"] - before["tokens_read"]
-            stats.record(stage.name, stage.kind, wall,
-                         queries=cb.n_queries, pages_touched=pages,
-                         tokens_read=tokens, queue_wait_s=queue_wait_s,
+                h2d = after["h2d_bytes"] - before["h2d_bytes"]
+                span.set_metadata(h2d_bytes=h2d)
+        if stats is not None:
+            if stage.opens_async:
+                stats.async_open()
+            stats.record(stage.name, wall, queries=cb.n_queries,
+                         pages_touched=pages, h2d_bytes=h2d,
                          device_dispatches=stage.device_dispatch_count
                          * max(1, stage.fanout))
         return out
@@ -275,13 +302,11 @@ class StagePlan:
 
 @dataclasses.dataclass
 class StageRecord:
-    kind: str = HOST
     wall_s: float = 0.0
     dispatches: int = 0
     queries: int = 0
-    queue_wait_s: float = 0.0
     pages_touched: int = 0
-    tokens_read: int = 0
+    h2d_bytes: int = 0                   # gathered bytes bound for device
     device_dispatches: int = 0           # declared device launches
     ewma_ms: Optional[float] = None      # EWMA of per-dispatch wall time
 
@@ -305,6 +330,11 @@ class PipelineStats:
     the pipeline's win: 0.0 when execution is strictly serial (depth 1
     runs the sync stage immediately after the dispatch), > 0 when
     gathers and device scoring actually ran concurrently.
+
+    Compiles: every instance counts the programs JAX compiles in this
+    process (``jax_compiles``, and their ``jax_compile_ms``) among its
+    counters, from one ``jax.monitoring`` listener per process. A
+    served window after warm-up should count none.
     """
 
     def __init__(self, ewma_alpha: float = 0.25):
@@ -316,7 +346,8 @@ class PipelineStats:
         self._t_mark: Optional[float] = None
         self._busy_any_s = 0.0
         self._overlap_s = 0.0
-        self._counters: dict[str, int] = {}
+        self._counters: dict[str, float] = {}
+        _watch_compiles(self)
 
     def reset(self):
         with self._lock:
@@ -360,50 +391,70 @@ class PipelineStats:
             self._async = max(0, self._async - 1)
 
     # -- records ---------------------------------------------------------
-    def record(self, name: str, kind: str, wall_s: float, *,
+    def record(self, name: str, wall_s: float, *,
                queries: int = 0, dispatches: int = 1,
-               pages_touched: int = 0, tokens_read: int = 0,
-               queue_wait_s: float = 0.0, device_dispatches: int = 0):
+               pages_touched: int = 0, h2d_bytes: int = 0,
+               device_dispatches: int = 0):
         with self._lock:
             rec = self._stages.get(name)
             if rec is None:
-                rec = self._stages[name] = StageRecord(kind=kind)
-            rec.kind = kind
+                rec = self._stages[name] = StageRecord()
             rec.wall_s += wall_s
             rec.dispatches += dispatches
             rec.queries += queries
             rec.pages_touched += pages_touched
-            rec.tokens_read += tokens_read
-            rec.queue_wait_s += queue_wait_s
+            rec.h2d_bytes += h2d_bytes
             rec.device_dispatches += device_dispatches
             ms = wall_s * 1e3
             rec.ewma_ms = (ms if rec.ewma_ms is None
                            else self._ewma_alpha * ms
                            + (1 - self._ewma_alpha) * rec.ewma_ms)
 
-    def counter(self, name: str, delta: int = 1):
+    def counter(self, name: str, delta: float = 1):
         """Bump a named monotonic counter (transport bytes, RPC
         dispatches, coalesced-op counts, …); surfaced in
         :meth:`snapshot` under ``"counters"``."""
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + delta
 
-    @property
-    def overlap_fraction(self) -> float:
-        with self._lock:
-            return (self._overlap_s / self._busy_any_s
-                    if self._busy_any_s > 0 else 0.0)
-
     def snapshot(self) -> dict:
-        """Atomic copy: {"stages": {name: record-dict}, "busy_s": ...,
-        "overlap_s": ..., "overlap_fraction": ..., "counters": ...}."""
+        """Atomic copy: {"stages": {name: record-dict},
+        "overlap_fraction": ..., "counters": ...}."""
         with self._lock:
             stages = {n: r.as_dict() for n, r in self._stages.items()}
             busy, over = self._busy_any_s, self._overlap_s
             counters = dict(self._counters)
-        return {"stages": stages, "busy_s": busy, "overlap_s": over,
+        return {"stages": stages,
                 "overlap_fraction": over / busy if busy > 0 else 0.0,
                 "counters": counters}
+
+
+# the process's one compile listener, fanned out to every live
+# PipelineStats (JAX keeps listeners for the life of the process)
+_compile_lock = threading.Lock()
+_compile_sinks: "weakref.WeakSet[PipelineStats]" = weakref.WeakSet()
+_compile_listening = False
+
+
+def _on_duration(event: str, duration_s: float, **_):
+    if event != COMPILE_EVENT:
+        return
+    with _compile_lock:
+        sinks = list(_compile_sinks)
+    for stats in sinks:
+        stats.counter("jax_compiles")
+        stats.counter("jax_compile_ms", duration_s * 1e3)
+
+
+def _watch_compiles(stats: PipelineStats):
+    """Count the process's compiles into ``stats`` from now on."""
+    global _compile_listening
+    with _compile_lock:
+        if not _compile_listening:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            _compile_listening = True
+        _compile_sinks.add(stats)
 
 
 # ---------------------------------------------------------------------------
@@ -411,13 +462,12 @@ class PipelineStats:
 # ---------------------------------------------------------------------------
 
 class _Job:
-    __slots__ = ("cb", "future", "idx", "t_enq", "async_open")
+    __slots__ = ("cb", "future", "idx", "async_open")
 
-    def __init__(self, cb: CandidateBatch, future: Future, t_enq: float):
+    def __init__(self, cb: CandidateBatch, future: Future):
         self.cb = cb
         self.future = future
         self.idx = 0                       # next stage to run
-        self.t_enq = t_enq
         self.async_open = False            # opened an unclosed async window
 
 
@@ -504,7 +554,7 @@ class PipelineExecutor:
             raise PipelineStopped("executor stopped")
         fut: Future = Future()
         fut.set_running_or_notify_cancel()   # internal: never cancelled
-        job = _Job(cb, fut, time.perf_counter())
+        job = _Job(cb, fut)
         with self._cond:
             self._inflight += 1
         self._mark_queued(job.idx, +1)
@@ -578,19 +628,17 @@ class PipelineExecutor:
 
     # -- shared stage step -----------------------------------------------
     def _advance(self, job: _Job) -> bool:
-        """Run the job's next stage on the calling worker (queued-count,
-        queue-wait, and async-window bookkeeping included). Returns True
+        """Run the job's next stage on the calling worker (queued-count
+        and async-window bookkeeping included). Returns True
         when the job left the pipeline (finished or failed); False when
         it advanced to the next stage — already marked queued, but not
         yet handed to a worker queue."""
         stage = self.plan.stages[job.idx]
         self._mark_queued(job.idx, -1)
-        wait_s = time.perf_counter() - job.t_enq
         if stage.closes_async:
             job.async_open = False         # run_stage closes the window
         try:
-            cb = self.plan.run_stage(stage, job.cb, self.stats,
-                                     queue_wait_s=wait_s)
+            cb = self.plan.run_stage(stage, job.cb, self.stats)
         except Exception as e:
             self._finish(job, exc=e)
             return True
@@ -601,7 +649,6 @@ class PipelineExecutor:
             self._finish(job, cb=cb)
             return True
         job.cb = cb
-        job.t_enq = time.perf_counter()
         self._mark_queued(job.idx, +1)
         return False
 

@@ -43,11 +43,12 @@ from concurrent.futures import Future
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.admission import AdmissionController, RequestShed
 from repro.serving.context import ADMIT_DEGRADED, ADMIT_SHED
 from repro.serving.engine import Request, Result, ServeEngine
-from repro.serving.pipeline import PipelineStopped
+from repro.serving.pipeline import PipelineStopped, span_ids
 
 
 class RetrievalServer:
@@ -109,22 +110,25 @@ class RetrievalServer:
     def _collect_batch(self, first):
         """Coalesce queued requests behind ``first`` until the current
         (possibly adapted) batch cap or ``batch_timeout_ms`` elapses."""
-        batch = [first]
-        # one locked read: _observe_latency resizes batch_cap under
-        # self._lock from whichever thread served the last batch, and a
-        # torn/stale read here could collect against a cap that no
-        # longer exists
-        with self._lock:
-            cap = self.batch_cap
-        deadline = time.perf_counter() + self.batch_timeout_ms / 1e3
-        while len(batch) < cap:
-            remaining = deadline - time.perf_counter()
-            if remaining <= 0:
-                break
-            try:
-                batch.append(self.queue.get(timeout=remaining))
-            except queue.Empty:
-                break
+        with TraceAnnotation("tcp:collect") as span:
+            batch = [first]
+            # one locked read: _observe_latency resizes batch_cap under
+            # self._lock from whichever thread served the last batch,
+            # and a torn/stale read here could collect against a cap
+            # that no longer exists
+            with self._lock:
+                cap = self.batch_cap
+            deadline = time.perf_counter() + self.batch_timeout_ms / 1e3
+            while len(batch) < cap:
+                remaining = deadline - time.perf_counter()
+                if remaining <= 0:
+                    break
+                try:
+                    batch.append(self.queue.get(timeout=remaining))
+                except queue.Empty:
+                    break
+            span.set_metadata(n=len(batch),
+                              **span_ids([req.qid for req, _ in batch]))
         return batch
 
     def _observe_latency(self, results):
@@ -176,15 +180,18 @@ class RetrievalServer:
             # dispatch path, not keep the one captured at start()
             pipelined = getattr(self.engine, "pipelined", False)
             try:
-                if pipelined:
-                    # feed the stage pipeline and move on: the tail
-                    # resolves the futures while this worker collects
-                    # the next micro-batch (gather/score overlap)
-                    self._dispatch_pipelined(batch)
-                elif len(batch) == 1:
-                    self._serve_one(*batch[0])
-                else:
-                    self._serve_batch(batch)
+                with TraceAnnotation("tcp:dispatch", **span_ids(
+                        [req.qid for req, _ in batch])):
+                    if pipelined:
+                        # feed the stage pipeline and move on: the tail
+                        # resolves the futures while this worker
+                        # collects the next micro-batch (gather/score
+                        # overlap)
+                        self._dispatch_pipelined(batch)
+                    elif len(batch) == 1:
+                        self._serve_one(*batch[0])
+                    else:
+                        self._serve_batch(batch)
             finally:
                 for _ in batch:
                     self.queue.task_done()
@@ -251,10 +258,12 @@ class RetrievalServer:
             # bind once: result() re-derives the list on every call, and
             # the latency observer must see exactly the results the
             # clients got
-            results = agg.result()
-            for (_, fut), res in zip(claimed, results):
-                fut.set_result(res)
-            self._observe_latency(results)
+            with TraceAnnotation("tcp:resolve", **span_ids(
+                    [req.qid for req, _ in claimed])):
+                results = agg.result()
+                for (_, fut), res in zip(claimed, results):
+                    fut.set_result(res)
+                self._observe_latency(results)
             return
         if isinstance(exc, PipelineStopped) and not self.running:
             # server shutdown: fail fast instead of re-serving inline.
@@ -403,16 +412,12 @@ class RetrievalServer:
             if d.admission == ADMIT_SHED:
                 with self._lock:
                     self.sheds += 1
-                if stats is not None and hasattr(stats, "counter"):
-                    stats.counter("admission_sheds")
                 fut.set_running_or_notify_cancel()
                 fut.set_exception(RequestShed(d.reason,
                                               d.predicted_full_ms))
                 return fut
             if d.admission == ADMIT_DEGRADED and req.ctx is not None:
                 req.ctx = req.ctx.degraded(d.reason)
-                if stats is not None and hasattr(stats, "counter"):
-                    stats.counter("admission_degraded")
         self.queue.put((req, fut))
         return fut
 
@@ -450,11 +455,12 @@ class RetrievalServer:
                 name: {"ewma_ms": r["ewma_ms"], "wall_s": r["wall_s"],
                        "dispatches": r["dispatches"],
                        "device_dispatches": r["device_dispatches"],
-                       "queue_wait_s": r["queue_wait_s"],
-                       "pages_touched": r["pages_touched"]}
+                       "pages_touched": r["pages_touched"],
+                       "h2d_bytes": r["h2d_bytes"]}
                 for name, r in snap["stages"].items()}
             h["overlap_fraction"] = snap["overlap_fraction"]
-            h["counters"] = dict(snap.get("counters", {}))
+            h["counters"] = {"jax_compiles": 0, "jax_compile_ms": 0.0,
+                             **snap.get("counters", {})}
         live = getattr(retr, "live", None)
         if live is not None:
             h["live"] = (retr.live_stats() if hasattr(retr, "live_stats")
@@ -505,47 +511,59 @@ class _Handler(socketserver.StreamRequestHandler):
 
     def handle(self):
         for line in self.rfile:
-            qid = None
-            try:
-                msg = json.loads(line)
-                qid = msg.get("qid")
-                op = msg.get("op")
-                if op is not None:
-                    out = self._admin(msg, op)
-                    self.wfile.write((json.dumps(out) + "\n").encode())
+            with TraceAnnotation("tcp:request") as span:
+                out = self._answer(line, span)
+                with TraceAnnotation("tcp:json_dumps"):
+                    reply = (json.dumps(out) + "\n").encode()
+                with TraceAnnotation("tcp:write"):
+                    self.wfile.write(reply)
                     self.wfile.flush()
-                    continue
-                req = Request(
-                    qid=msg["qid"], method=msg.get("method", "hybrid"),
-                    q_emb=np.asarray(msg["q_emb"], np.float32)
-                    if "q_emb" in msg else None,
-                    term_ids=np.asarray(msg.get("term_ids", []), np.int32),
-                    term_weights=np.asarray(msg.get("term_weights", []),
-                                            np.float32),
-                    k=msg.get("k", 10))
+
+    def _answer(self, line, span) -> dict:
+        """The reply to one request line: an admin op's result, a
+        query's answer, or an error. Names the request's ``qid`` on its
+        ``tcp:request`` span once the line is parsed."""
+        qid = None
+        try:
+            with TraceAnnotation("tcp:json_loads"):
+                msg = json.loads(line)
+            qid = msg.get("qid")
+            if qid is not None:
+                span.set_metadata(qid=qid)
+            op = msg.get("op")
+            if op is not None:
+                return self._admin(msg, op)
+            req = Request(
+                qid=msg["qid"], method=msg.get("method", "hybrid"),
+                q_emb=np.asarray(msg["q_emb"], np.float32)
+                if "q_emb" in msg else None,
+                term_ids=np.asarray(msg.get("term_ids", []), np.int32),
+                term_weights=np.asarray(msg.get("term_weights", []),
+                                        np.float32),
+                k=msg.get("k", 10))
+            with TraceAnnotation("tcp:await", qid=req.qid):
                 res = self.server.retrieval.submit(req).result(timeout=60)
-                out = {"qid": res.qid, "pids": res.pids.tolist(),
-                       "scores": [float(s) for s in res.scores],
-                       "latency": res.latency}
-                if res.cache_hit:
-                    out["cache_hit"] = True
-                if res.degraded:
-                    # partial or downgraded answer: the reason code says
-                    # whether shards were missing or admission control
-                    # ran the cheap plan
-                    out["degraded"] = True
-                    out["degrade_reason"] = res.degrade_reason
-                    out["missing_shards"] = list(res.missing_shards)
-            except RequestShed as e:
-                out = {"error": str(e), "shed": True, "reason": e.reason}
-                if qid is not None:
-                    out["qid"] = qid
-            except Exception as e:
-                out = {"error": str(e)}
-                if qid is not None:
-                    out["qid"] = qid
-            self.wfile.write((json.dumps(out) + "\n").encode())
-            self.wfile.flush()
+            out = {"qid": res.qid, "pids": res.pids.tolist(),
+                   "scores": [float(s) for s in res.scores],
+                   "latency": res.latency}
+            if res.cache_hit:
+                out["cache_hit"] = True
+            if res.degraded:
+                # partial or downgraded answer: the reason code says
+                # whether shards were missing or admission control
+                # ran the cheap plan
+                out["degraded"] = True
+                out["degrade_reason"] = res.degrade_reason
+                out["missing_shards"] = list(res.missing_shards)
+        except RequestShed as e:
+            out = {"error": str(e), "shed": True, "reason": e.reason}
+            if qid is not None:
+                out["qid"] = qid
+        except Exception as e:
+            out = {"error": str(e)}
+            if qid is not None:
+                out["qid"] = qid
+        return out
 
 
 class TCPRetrievalServer(socketserver.ThreadingTCPServer):

@@ -43,7 +43,6 @@ from repro.serving.loadgen import (
     run_poisson_load,
     zipf_trace,
 )
-from repro.serving.pipeline import DEVICE, HOST
 from repro.serving.server import RetrievalServer
 
 METHODS = ("splade", "rerank", "hybrid", "colbert")
@@ -249,8 +248,6 @@ def test_stage1_cache_splade_warms_hybrid(base_dir, small_corpus,
                                         range(4)))
     for r, g in zip(ref, got):
         _assert_bitwise(r, g)
-    counters = retr.pipeline_stats.snapshot()["counters"]
-    assert counters.get("cache_stage1_hits", 0) >= 4
 
 
 def test_stage1_cache_colbert_candidates(base_dir, small_corpus,
@@ -391,10 +388,8 @@ def test_admission_ladder_unit():
 
 def _poison(retr, stage1_s, tail_s):
     for _ in range(4):                   # drive the EWMA, not one sample
-        retr.pipeline_stats.record("splade_stage1", HOST,
-                                   wall_s=stage1_s)
-        retr.pipeline_stats.record("device_score:maxsim", DEVICE,
-                                   wall_s=tail_s)
+        retr.pipeline_stats.record("splade_stage1", wall_s=stage1_s)
+        retr.pipeline_stats.record("device_score:maxsim", wall_s=tail_s)
 
 
 def test_admission_degrades_hybrid_to_splade(base_dir, small_corpus,
@@ -416,7 +411,6 @@ def test_admission_degrades_hybrid_to_splade(base_dir, small_corpus,
         _assert_bitwise(ref, res)
         h = srv.health()
         assert h["admission"]["degraded_admits"] == 1
-        assert h["counters"].get("admission_degraded", 0) == 1
     finally:
         srv.stop()
 
