@@ -12,10 +12,13 @@ the kernel. A decoded token is ``c + r`` (centroid plus residual), so
     q·(c + r) = q·c + q·r
 
 and the ``q·c`` term is a gather from the per-query centroid-score
-table ``Q·Cᵀ`` (``(Lq, K)``, the table PLAID's stage-1 probe computes).
-The gather runs in XLA before the kernel (``kernel_operands``); the
-kernel decodes only the residual bucket codes, for which a
-``2^nbits``-entry weight table in SMEM suffices.
+table ``Q·Cᵀ`` (the table PLAID's stage-1 probe computes), laid out as
+``(K, Lq)`` so that a document token's centroid id selects one whole
+row of ``Lq`` scores: one gather index per token, not one per gathered
+float (a gather's cost on the chip follows its number of indices). The
+gather runs in XLA before the kernel (``kernel_operands``); the kernel
+decodes only the residual bucket codes, for which a ``2^nbits``-entry
+weight table in SMEM suffices.
 
 Layout: candidates sit on the 128 lanes of a vreg, tokens on a leading
 axis. One grid step scores one tile of ``LANES`` candidates of one
@@ -74,6 +77,12 @@ def kernel_operands(q, packed, cids, valid, q_valid, centroids, nbits: int):
     packed_t (B, T, Ld, pd, LANES) u8, qc (B, T, Ld, Lq, LANES) f32)
     with T = ceil(C / LANES).
 
+    * ``q·c`` is a row gather from the centroid-score table transposed
+      to ``(B, K, Lq)``: each document token's centroid id fetches its
+      ``Lq`` scores as one row, so the gather takes one index per token
+      rather than one per float; the rows are then moved into the
+      kernel's candidate-on-lanes layout. The values are the table's,
+      bit for bit.
     * Invalid query tokens are zeroed in ``q`` and in the table, so they
       contribute ``max(0) = 0`` exactly as the reference's mask does.
     * Invalid document tokens (and padded candidates) get ``q·c = NEG``,
@@ -97,13 +106,12 @@ def kernel_operands(q, packed, cids, valid, q_valid, centroids, nbits: int):
     cids = jnp.pad(cids.astype(jnp.int32), ((0, 0), (0, pad), (0, 0)))
     valid = jnp.pad(valid.astype(bool), ((0, 0), (0, pad), (0, 0)))
     packed = jnp.pad(packed, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    # (B, Cp, Ld) → (B, T, Ld, LANES): candidate on the lane axis
-    cids_t = cids.reshape(B, T, LANES, Ld).transpose(0, 1, 3, 2)
-    valid_t = valid.reshape(B, T, LANES, Ld).transpose(0, 1, 3, 2)
-    b_ix = jnp.arange(B)[:, None, None, None, None]
-    q_ix = jnp.arange(Lq)[None, None, None, :, None]
-    qc = table[b_ix, q_ix, cids_t[:, :, :, None, :]]   # (B, T, Ld, Lq, L)
-    qc = jnp.where(valid_t[:, :, :, None, :], qc, NEG)
+    # one index per document token: whole rows of Lq scores
+    rows = table.transpose(0, 2, 1)                  # (B, K, Lq)
+    qc = rows[jnp.arange(B)[:, None, None], cids]    # (B, Cp, Ld, Lq)
+    qc = jnp.where(valid[..., None], qc, NEG)
+    # (B, Cp, ...) → (B, T, Ld, Lq, LANES): candidate on the lane axis
+    qc = qc.reshape(B, T, LANES, Ld, Lq).transpose(0, 1, 3, 4, 2)
     packed_t = packed.reshape(B, T, LANES, Ld, pd).transpose(0, 1, 3, 4, 2)
     q_perm = q.reshape(B, Lq, pd, cpb).transpose(0, 1, 3, 2).reshape(
         B, Lq, d)

@@ -5,11 +5,12 @@ The split stage-4 tail runs three dispatches (decompress+MaxSim scores,
 score masking, top-k selection) and materialises the full ``(B, C)``
 score tensor in HBM between them. This kernel scores one tile of
 ``LANES`` candidates per grid step with the same body as
-``decompress_maxsim`` (``tile_scores``: the ``q·c`` table gather plus
-an in-VMEM residual decode, so the centroid table never enters the
-kernel) and folds the tile into a running per-query top-k held in the
-output block across grid steps. Nothing wider than one ``(1, LANES)``
-score row ever exists.
+``decompress_maxsim`` (``tile_scores``: the ``q·c`` term, gathered in
+XLA as whole ``Lq``-float rows of the ``(B, K, Lq)`` centroid-score
+table, one index per document token, plus an in-VMEM residual decode,
+so the centroid table never enters the kernel) and folds the tile into
+a running per-query top-k held in the output block across grid steps.
+Nothing wider than one ``(1, LANES)`` score row ever exists.
 
 The running top-k merge is *sortless*: each grid step ranks the
 ``kp + LANES`` merged entries by pairwise comparison counts

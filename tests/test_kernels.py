@@ -9,7 +9,13 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.index.residual import unpack_codes
-from repro.kernels.decompress_maxsim.decompress_maxsim import score_atol
+from repro.kernels.decompress_maxsim.decompress_maxsim import (
+    HIGHEST,
+    LANES,
+    NEG,
+    kernel_operands,
+    score_atol,
+)
 from repro.kernels.decompress_maxsim.ops import decompress_maxsim_scores
 from repro.kernels.maxsim.ops import maxsim_scores
 from repro.kernels.maxsim.ref import maxsim_scores_ref
@@ -142,6 +148,52 @@ def test_fused_equals_decompress_then_maxsim():
                                      nbits=nbits, impl="ref")
     np.testing.assert_allclose(np.asarray(fused), np.asarray(two_step),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("B", [1, 3])
+@pytest.mark.parametrize("nbits", [2, 4])
+def test_kernel_operands_equal_elementwise_formulation(B, nbits):
+    """The kernels' operands, element by element: ``qc[b, t, l, i, j]``
+    is the centroid score ``(q_i · C)[cids[b, t·LANES + j, l]]`` of a
+    valid token and ``NEG`` elsewhere; ``packed_t`` puts candidates on
+    lanes; ``q_perm`` puts dim ``j·cpb + s`` in column ``s·pd + j``.
+    Bitwise: the kernel must see the same numbers however they are
+    gathered."""
+    C, Ld, Lq, d, K = 200, 37, 8, 64, 48
+    rng = np.random.default_rng(B * 10 + nbits)
+    pd, cpb = d * nbits // 8, 8 // nbits
+    q = rng.standard_normal((B, Lq, d)).astype(np.float32)
+    packed = rng.integers(0, 256, (B, C, Ld, pd)).astype(np.uint8)
+    cids = rng.integers(0, K, (B, C, Ld)).astype(np.int32)
+    valid = rng.random((B, C, Ld)) < 0.7
+    q_valid = rng.random((B, Lq)) < 0.75
+    q_valid[:, 0] = True
+    cent = rng.standard_normal((K, d)).astype(np.float32)
+    q_perm, packed_t, qc = (np.asarray(x) for x in kernel_operands(
+        q, packed, cids, valid, q_valid, cent, nbits))
+
+    qz = q * q_valid[..., None]
+    table = np.asarray(jnp.einsum("bqd,kd->bqk", qz, cent,
+                                  precision=HIGHEST,
+                                  preferred_element_type=jnp.float32))
+    T = -(-C // LANES)
+    want_qc = np.full((B, T, Ld, Lq, LANES), NEG, np.float32)
+    want_packed = np.zeros((B, T, Ld, pd, LANES), np.uint8)
+    for b, c, t in np.ndindex(B, C, Ld):
+        if valid[b, c, t]:
+            for i in range(Lq):
+                want_qc[b, c // LANES, t, i, c % LANES] = \
+                    table[b, i, cids[b, c, t]]
+        for j in range(pd):
+            want_packed[b, c // LANES, t, j, c % LANES] = packed[b, c, t, j]
+    want_q = np.zeros((B, Lq, d), np.float32)
+    for s in range(cpb):
+        for j in range(pd):
+            want_q[:, :, s * pd + j] = qz[:, :, j * cpb + s]
+    assert qc.shape == want_qc.shape and qc.dtype == np.float32
+    assert np.array_equal(qc, want_qc)
+    assert np.array_equal(packed_t, want_packed)
+    assert np.array_equal(q_perm, want_q)
 
 
 # ---------------------------------------------------------------------------

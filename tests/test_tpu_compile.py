@@ -11,6 +11,7 @@ import: one process at a time may load the TPU library, and every test
 worker imports this file."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -63,17 +64,31 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _compile_tail(sharding, program, nbits, K):
+    """Compile one served tail program at the module's shapes."""
+    a = _rerank_args(sharding, nbits, K)
+    common = (a["q"], a["packed"], a["cids"], a["valid"], a["cand_mask"],
+              a["centroids"], a["bucket_weights"])
+    if program == "fused_hybrid_tail":
+        lowered = fused_hybrid_tail.lower(
+            *common, a["q_valid"],
+            jax.ShapeDtypeStruct((B, C), jnp.float32, sharding=sharding),
+            jax.ShapeDtypeStruct((B,), jnp.float32, sharding=sharding),
+            nbits=nbits, k=100, b=B, normalizer="znorm", impl="pallas")
+    else:
+        lowered = fused_rerank_topk_batch.lower(
+            *common, q_valid=a["q_valid"], nbits=nbits, k=100,
+            impl="pallas")
+    return lowered.compile()
+
+
 RERANK_CASES = [(4, 1 << 15), (4, 1 << 17), (2, 1 << 15)]
 
 
 @pytest.mark.parametrize("nbits,K", RERANK_CASES)
 def test_fused_rerank_compiles_for_v5e(one_chip, nbits, K):
-    a = _rerank_args(one_chip, nbits, K)
-    compiled = fused_rerank_topk_batch.lower(
-        a["q"], a["packed"], a["cids"], a["valid"], a["cand_mask"],
-        a["centroids"], a["bucket_weights"], q_valid=a["q_valid"],
-        nbits=nbits, k=100, impl="pallas").compile()
-    _assert_kernel(compiled)
+    _assert_kernel(_compile_tail(one_chip, "fused_rerank_topk_batch",
+                                 nbits, K))
 
 
 @pytest.mark.parametrize("nbits,K", RERANK_CASES)
@@ -87,16 +102,24 @@ def test_decompress_maxsim_compiles_for_v5e(one_chip, nbits, K):
 
 
 def test_hybrid_tail_compiles_for_v5e(one_chip):
-    nbits, K = 4, 1 << 15
-    a = _rerank_args(one_chip, nbits, K)
-    compiled = fused_hybrid_tail.lower(
-        a["q"], a["packed"], a["cids"], a["valid"], a["cand_mask"],
-        a["centroids"], a["bucket_weights"], a["q_valid"],
-        jax.ShapeDtypeStruct((B, C), jnp.float32, sharding=one_chip),
-        jax.ShapeDtypeStruct((B,), jnp.float32, sharding=one_chip),
-        nbits=nbits, k=100, b=B, normalizer="znorm",
-        impl="pallas").compile()
-    _assert_kernel(compiled)
+    _assert_kernel(_compile_tail(one_chip, "fused_hybrid_tail", 4, 1 << 15))
+
+
+# a float32 gather whose slices are single elements: one index per float
+SCALAR_GATHER = re.compile(r"= f32\[[^\n]*? gather\([^\n]*?"
+                           r"slice_sizes=\{1(?:,1)*\}")
+
+
+@pytest.mark.parametrize("program", ["fused_hybrid_tail",
+                                     "fused_rerank_topk_batch"])
+def test_tail_gathers_centroid_scores_by_rows(one_chip, program):
+    """The ``q·c`` operand is gathered as whole rows of the centroid-score
+    table, one index per document token: no float32 gather of single
+    elements in either served tail program."""
+    text = _compile_tail(one_chip, program, 2, 1 << 15).as_text()
+    assert " gather(" in text
+    found = SCALAR_GATHER.search(text)
+    assert found is None, found[0]
 
 
 def test_splade_stage1_compiles_for_v5e(one_chip):
