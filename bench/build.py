@@ -33,6 +33,22 @@ import gen  # noqa: E402
 import harness  # noqa: E402
 
 
+def preload(retr, corpus: dict, docs: dict, writes: dict, seed: int):
+    """Apply the traffic's ``preload`` updates to a live retriever
+    directly, as the load generator sends them over TCP in a run, so
+    that the warm-up compiles the upsert encoding at their lengths and
+    the overlay path → the new versions' terms."""
+    keys = gen.write_keys(corpus, writes, writes["preload"], seed)
+    v = gen.make_versions(corpus, docs, keys, seed)
+    current = {}
+    for j, key in enumerate(keys.tolist()):
+        pid = retr.live_upsert(v["embs"][j, :v["lens"][j]],
+                               v["term_ids"][j], v["term_weights"][j])
+        retr.live_delete(current.get(key, key))
+        current[key] = pid
+    return {"term_ids": v["term_ids"], "term_weights": v["term_weights"]}
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", required=True)
@@ -45,6 +61,9 @@ def main(argv=None):
     ap.add_argument("--warm-k", type=int, default=0,
                     help="compile the serving programs for answers of "
                          "this depth once the index is built")
+    ap.add_argument("--traffic", default=None,
+                    help="with --warm-k and a live configuration, the "
+                         "traffic whose preload the warm-up applies")
     args = ap.parse_args(argv)
     cfg = json.loads(pathlib.Path(args.config).read_text())
     harness.use_compile_cache()
@@ -80,7 +99,11 @@ def main(argv=None):
         import run
         t0 = time.perf_counter()
         retr, _ = run.open_retriever(cfg, out)
-        run.warm(retr, cfg, args.warm_k)
+        terms = None
+        if run.go_live(retr, cfg) is not None:
+            terms = preload(retr, corpus, docs, harness.load_json(
+                args.traffic)["writes"], args.seed)
+        run.warm(retr, cfg, args.warm_k, terms)
         times["compile_s"] = time.perf_counter() - t0
     print(json.dumps({"device": dev, "times": times}), flush=True)
 

@@ -3,7 +3,7 @@ program's place and computed one precision step below what the
 configuration states, must come out as not correct.
 
     python bench/control.py --workload <cell> --seeds <n> [<n> ...]
-        [--precision high|highest]
+        [--precision high|highest | --forget-writes]
 
 The configuration states float32 at ``Precision.HIGHEST`` for every dot
 of the served path; the step below is ``high`` (three bf16 passes). For
@@ -16,6 +16,12 @@ decoding, MaxSim and the z-normalised fusion — its dots at
 a run's (``reference.check``), and each number is printed beside the
 cell's limit, one JSON line per seed. The benchmark's runs never run
 this.
+
+``--forget-writes`` is the control of the check under writes, for a
+cell whose traffic has ``writes``: each seed is served as a run serves
+it (``run.run``), and the answers are judged against the corpus as
+built, as though no write had been made. It must come out as not
+correct through ``bad_pids`` or ``rank_gap``, with nothing ``failed``.
 """
 
 from __future__ import annotations
@@ -25,6 +31,7 @@ import functools
 import json
 import pathlib
 import shutil
+import subprocess
 import sys
 
 import numpy as np
@@ -66,15 +73,16 @@ def _topk(x, k):
 
 
 def answers(cfg: dict, index_dir, seed: int, n: int, sample, k: int,
-            precision: str):
-    """Answers of the sampled requests → (pids (n, k), scores (n, k))."""
+            precision: str, rel=None):
+    """Answers of the sampled requests → (pids (n, k), scores (n, k));
+    ``rel`` as ``gen.make_queries`` takes it."""
     import jax
     import jax.numpy as jnp
 
     dot = jax.jit(functools.partial(_dot, precision=precision))
     corpus, s = harness.corpus(cfg), cfg["serving"]
     docs = gen.make_corpus(corpus, seed)
-    q = gen.make_queries(corpus, docs, n, seed)
+    q = gen.make_queries(corpus, docs, n, seed, rel)
     index = reference.Index(index_dir, corpus["dim"], cfg["index"]["nbits"],
                             docs["doc_lens"])
     cents = jnp.asarray(index.centroids, jnp.float32)
@@ -130,6 +138,7 @@ def main(argv=None):
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--precision", default="high",
                     choices=("high", "highest"))
+    ap.add_argument("--forget-writes", action="store_true")
     args = ap.parse_args(argv)
     harness.use_compile_cache()
     import build
@@ -137,6 +146,26 @@ def main(argv=None):
     c = harness.cell(args.workload)
     cfg, traffic = c["config"], c["traffic"]
     seconds = harness.benchmark()["run_seconds"]
+    limits = cfg["check"]["limits"]
+    if args.forget_writes and len(args.seeds) > 1:
+        # a run holds the chip until its process ends: one process a seed
+        for seed in args.seeds:
+            subprocess.run([sys.executable, __file__, "--workload",
+                            args.workload, "--seeds", str(seed),
+                            "--forget-writes"], check=True)
+        return
+    if args.forget_writes:
+        import run
+        for seed in args.seeds:
+            r = run.run(args.workload, seed, seconds, False,
+                        forget_writes=True)
+            print(json.dumps({
+                "workload": args.workload, "seed": seed,
+                "control": "forget_writes", "correct": r["correct"],
+                "numbers": {m: x["value"] for m, x in r["checks"].items()},
+                "limits": limits, "writes": r["info"].get("writes")}),
+                flush=True)
+        return
     n = harness.n_requests(traffic, seconds)
     out = harness.WORK / f"control-{args.workload}"
     for seed in args.seeds:
@@ -146,10 +175,9 @@ def main(argv=None):
         client = {"status": np.zeros(n, np.int8)}
         sample = stats.sample(client, cfg["check"]["sample"], seed)
         client["pids"], client["scores"] = answers(
-            cfg, out, seed, n, sample, traffic["k"], args.precision)
-        numbers = reference.check(cfg, out, seed, client, sample,
-                                  traffic["k"])
-        limits = cfg["check"]["limits"]
+            cfg, out, seed, n, sample, traffic["k"], args.precision,
+            gen.query_rel(harness.corpus(cfg), traffic, n, seed))
+        numbers = reference.check(cfg, traffic, out, seed, client, sample)
         print(json.dumps({
             "workload": args.workload, "seed": seed,
             "precision": args.precision, "numbers": numbers,

@@ -163,7 +163,8 @@ def make_corpus(corpus: dict, seed: int) -> dict:
     return {"doc_lens": lens.astype(np.int32), "doc_topic": doc_topic,
             "doc_term_ids": doc_term_ids, "doc_term_weights": doc_term_w,
             "topic_terms": topic_terms, "type_vec": type_vec,
-            "doc_vec": doc_vec}
+            "doc_vec": doc_vec, "topic_vec": topic_vec,
+            "topic_logp": topic_logp}
 
 
 def make_doc_embs(corpus: dict, docs: dict, seed: int) -> np.ndarray:
@@ -188,16 +189,20 @@ def make_doc_embs(corpus: dict, docs: dict, seed: int) -> np.ndarray:
     return out
 
 
-def make_queries(corpus: dict, docs: dict, n: int, seed: int) -> dict:
+def make_queries(corpus: dict, docs: dict, n: int, seed: int,
+                 rel: np.ndarray | None = None) -> dict:
     """n queries, each with a relevant passage: distinct terms drawn from
     its terms by weight, a share swapped for other terms of its topic,
     and query_maxlen token vectors near those terms and the passage.
-    → q_embs (n, query_maxlen, dim), q_term_ids / q_term_weights
-    (n, query_nnz), q_rel (n,)."""
+    The relevant passages are uniform draws unless ``rel`` names them
+    (``query_rel``). → q_embs (n, query_maxlen, dim), q_term_ids /
+    q_term_weights (n, query_nnz), q_rel (n,)."""
     c = corpus
     rng = np.random.default_rng(_streams(seed)[2])
     qn, lq, tv = c["query_nnz"], c["query_maxlen"], c["topic_vocab"]
-    rel = rng.integers(0, c["n_docs"], n)
+    # drawn either way, so that the draws after it stay where they were
+    drawn = rng.integers(0, c["n_docs"], n)
+    rel = drawn if rel is None else np.asarray(rel)
     w_rel = docs["doc_term_weights"][rel]
     pick = _gumbel_topk(rng, np.log(w_rel), qn)
     terms = np.take_along_axis(docs["doc_term_ids"][rel], pick, axis=1)
@@ -225,3 +230,92 @@ def arrivals(n: int, seconds: float, seed: int) -> np.ndarray:
     gaps = -np.log1p(-(np.arange(n) + 0.5) / n)
     gaps = np.random.default_rng([seed_word(seed), 0xA221]).permutation(gaps)
     return np.cumsum(gaps) * (seconds * (1 - 0.5 / n) / gaps.sum())
+
+
+# Read/write traffic. Each draw below has a seed stream of its own, so
+# that the corpus, the ``unique`` queries and the arrivals are the same
+# with or without it.
+
+def _rng(seed: int, stream: int) -> np.random.Generator:
+    return np.random.default_rng([seed_word(seed), stream])
+
+
+def popularity(n_docs: int, seed: int) -> np.ndarray:
+    """The passages ranked by popularity: one seeded permutation of the
+    pids, most popular first."""
+    return _rng(seed, 0x9091).permutation(n_docs)
+
+
+def zipf_ranks(rng: np.random.Generator, n_items: int, s: float,
+               size: int) -> np.ndarray:
+    """``size`` ranks in [0, n_items), rank r drawn with probability
+    proportional to (r + 1)^-s, as YCSB's Zipfian generator draws."""
+    cdf = np.cumsum(np.arange(1, n_items + 1, dtype=np.float64) ** -s)
+    u = rng.random(size) * cdf[-1]
+    return np.minimum(np.searchsorted(cdf, u, side="right"), n_items - 1)
+
+
+def query_rel(corpus: dict, traffic: dict, n: int, seed: int):
+    """The relevant passages of ``n`` queries: None for ``unique``
+    queries (``make_queries`` draws them uniformly), Zipfian over the
+    popularity ranking at ``zipf_s`` for ``zipf``."""
+    kind = traffic.get("queries", "unique")
+    if kind == "unique":
+        return None
+    if kind != "zipf":
+        raise ValueError(f"unknown queries {kind!r}")
+    ranks = zipf_ranks(_rng(seed, 0x2197), corpus["n_docs"],
+                       traffic["zipf_s"], n)
+    return popularity(corpus["n_docs"], seed)[ranks]
+
+
+def write_keys(corpus: dict, writes: dict, n: int, seed: int) -> np.ndarray:
+    """The keys (original pids) of ``n`` updates, Zipfian over the same
+    popularity ranking at ``key_zipf_s``: the set-up's preload first,
+    then the window's."""
+    ranks = zipf_ranks(_rng(seed, 0x3171), corpus["n_docs"],
+                       writes["key_zipf_s"], n)
+    return popularity(corpus["n_docs"], seed)[ranks]
+
+
+def write_slots(n_arrivals: int, share: float, seed: int) -> np.ndarray:
+    """Which of the window's arrivals are writes: ``round(share · n)`` of
+    them, chosen from the seed, so every seed writes as often."""
+    n_w = int(round(share * n_arrivals))
+    mask = np.zeros(n_arrivals, bool)
+    mask[_rng(seed, 0x3172).permutation(n_arrivals)[:n_w]] = True
+    return mask
+
+
+def make_versions(corpus: dict, docs: dict, keys: np.ndarray,
+                  seed: int) -> dict:
+    """A new version of each key's passage, one per update in order: the
+    key's topic and length, a new passage vector around the topic's, new
+    SPLADE terms and weights from the topic's vocabulary, and new token
+    vectors, drawn as ``make_corpus`` and ``make_doc_embs`` draw them.
+    → lens (m,), embs (m, doc_maxlen, dim) zero past each length,
+    term_ids / term_weights (m, doc_nnz)."""
+    c = corpus
+    rng = _rng(seed, 0x7E25)
+    keys = np.asarray(keys, np.int64)
+    m, dim, nnz = len(keys), c["dim"], c["doc_nnz"]
+    topic = docs["doc_topic"][keys]
+    lens = docs["doc_lens"][keys].astype(np.int32)
+    vec = _unit(docs["topic_vec"][topic] + np.float32(c["doc_sig"]) * _unit(
+        rng.standard_normal((m, dim), dtype=np.float32)))
+    slot = _gumbel_topk(rng, docs["topic_logp"][topic], nnz)
+    term_ids = np.take_along_axis(docs["topic_terms"][topic], slot,
+                                  axis=1).astype(np.int32)
+    term_w = (np.float32(c["weight_floor"]) + rng.gamma(
+        2.0, c["weight_scale"], (m, nnz)).astype(np.float32))
+    embs = np.zeros((m, c["doc_maxlen"], dim), np.float32)
+    valid = np.arange(c["doc_maxlen"])[None, :] < lens[:, None]
+    doc = np.repeat(np.arange(m), lens)
+    typ = term_ids[doc, rng.integers(0, nnz, len(doc))]
+    a, b, s = (np.float32(c[k]) for k in ("tok_type", "tok_doc",
+                                          "tok_noise"))
+    x = a * docs["type_vec"][typ] + b * vec[doc]
+    x += s * rng.standard_normal((len(doc), dim), dtype=np.float32)
+    embs[valid] = _unit(x)
+    return {"lens": lens, "embs": embs, "term_ids": term_ids,
+            "term_weights": term_w.astype(np.float32)}

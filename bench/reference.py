@@ -35,6 +35,20 @@ best. Four numbers come out of a sample of answers:
 * ``rank_gap``: the worst amount, in the same unit, by which the
   reference score of the pid served at rank j falls below the j-th best
   reference score of the candidates.
+
+Under writes (traffic with ``writes``; hybrid only) the corpus a query
+sees depends on when it ran. The load generator sends its writes in
+order on one connection, one at a time, so the writes applied at any
+moment are a prefix of the write log. A query must see every write
+acknowledged before it was sent, and no write sent after its reply
+arrived; each prefix in between is an admissible state, and the answer
+is judged against every outcome of every such state. A deleted pid is
+not admissible in a state that holds its delete, an upserted one only
+in a state that holds its upsert, so an acknowledged upsert that
+belongs in the top k and is not served counts in ``rank_gap``. The
+upserted passages are drawn again from the seed (``gen``) and encoded
+by the reference itself (``Versions``); their SPLADE impacts take the
+base corpus's quantum, as the program pins it.
 """
 
 from __future__ import annotations
@@ -47,8 +61,17 @@ import numpy as np
 
 import gen
 import harness
+import stats
 
 U32 = 2.0 ** -24          # float32 unit roundoff
+# Relative error of a product in the upsert encoder's centroid scores: a
+# float32 einsum at the backend's default precision, which on the TPU
+# rounds each operand to bfloat16 (8 significant bits, so off by less
+# than 2^-7 of its value whether rounded or truncated): at most
+# 2·2^-7 + 2^-14 of the product's size. The float32 sum adds at most
+# dim·2^-24 of the sum of the products' sizes.
+ENCODE_ROUNDING = 2 * 2.0 ** -7 + 2.0 ** -14
+ENCODE_ROWS = 256         # tokens encoded per block
 BOUNDARY_WINDOW = 48      # ranks on each side of a cut searched for swaps
 MAX_ALTERNATIVES = 256
 THREADS = 8               # answers are judged in parallel; NumPy drops the GIL
@@ -75,7 +98,10 @@ class Index:
                              f"the corpus {n_tok}")
         self.centroids = np.load(col / "centroids.npy").astype(np.float64)
         self.weights = np.load(col / "bucket_weights.npy").astype(np.float64)
+        self.cutoffs = np.load(col / "bucket_cutoffs.npy").astype(np.float64)
         self.tok_pid = np.repeat(np.arange(len(doc_lens)), doc_lens)
+        self.n_base = len(doc_lens)
+        self.versions = None      # upserted passages (``Versions``)
 
     def token_rows(self, pids):
         """Token row ids of ``pids`` in order, and each passage's first
@@ -97,12 +123,93 @@ class Index:
         return c + r, np.abs(c) + np.abs(r)
 
 
+class Versions:
+    """Upserted passages, encoded as the program's upsert encodes them
+    (each token's nearest centroid by inner product, then each
+    dimension's residual bucket by the stored cutoffs), in float64 from
+    the stored centroids, cutoffs and bucket weights. Where the encoder's
+    rounding could pick another centroid (its score within the two
+    scores' ``ENCODE_ROUNDING`` bounds of the best) or another bucket
+    (the float32 residual within its rounding of a cutoff), every choice
+    is kept. Each choice is a row: token ``tok``, its decoded vector as
+    ``mid ± half`` per dimension, and ``mag``, the size of centroid plus
+    residual. Rows are grouped by token, tokens by passage; ``slot`` j
+    holds pid ``n_base + j``, ``first[j]`` its first token."""
+
+    def __init__(self, index: Index, embs: np.ndarray, lens: np.ndarray):
+        C, W, cut = index.centroids, index.weights, index.cutoffs
+        dim = C.shape[1]
+        valid = np.arange(embs.shape[1])[None, :] < lens[:, None]
+        e = embs[valid].astype(np.float64)                 # (N, dim)
+        absC = np.abs(C)
+        tok, cid = [], []
+        for a in range(0, len(e), ENCODE_ROWS):
+            x = e[a:a + ENCODE_ROWS]
+            sc = x @ C.T
+            bound = (ENCODE_ROUNDING + dim * U32) * (np.abs(x) @ absC.T)
+            best = np.argmax(sc, axis=1)[:, None]
+            floor = (np.take_along_axis(sc, best, 1)
+                     - np.take_along_axis(bound, best, 1))
+            t, c = np.nonzero(sc + bound >= floor)
+            tok.append(t + a)
+            cid.append(c)
+        tok, cid = np.concatenate(tok), np.concatenate(cid)
+        r = e[tok] - C[cid]
+        b = np.searchsorted(cut, r, side="left")
+        eps = 2 * U32 * (np.abs(e[tok]) + absC[cid])
+        lo_b = np.where((b > 0) & (r - cut[np.maximum(b - 1, 0)] <= eps),
+                        b - 1, b)
+        hi_b = np.where((b < len(cut))
+                        & (cut[np.minimum(b, len(cut) - 1)] - r <= eps),
+                        b + 1, b)
+        w_lo, w_hi = W[lo_b], W[hi_b]
+        self.tok = tok
+        self.mid = C[cid] + 0.5 * (w_lo + w_hi)
+        self.half = 0.5 * np.abs(w_hi - w_lo)
+        self.mag = absC[cid] + np.maximum(np.abs(w_lo), np.abs(w_hi))
+        self.first = np.zeros(len(lens) + 1, np.int64)
+        np.cumsum(lens, out=self.first[1:])
+        self.row_first = np.searchsorted(tok, np.arange(len(e) + 1))
+        self.choices = len(tok) - len(e)      # rows beyond one a token
+
+    def maxsim(self, q: np.ndarray, slots: np.ndarray):
+        """MaxSim of ``q`` (Lq, dim) float64 over the passages in
+        ``slots`` → (scores, bounds): the middle of the range the
+        choices allow, and its half-width plus the float32 bound."""
+        out = np.empty(len(slots))
+        tol = np.empty(len(slots))
+        aq = np.abs(q)
+        for i, j in enumerate(slots.tolist()):
+            t0, t1 = self.first[j], self.first[j + 1]
+            r0, r1 = self.row_first[t0], self.row_first[t1]
+            m = self.mid[r0:r1] @ q.T
+            h = self.half[r0:r1] @ aq.T
+            starts = self.row_first[t0:t1] - r0
+            hi = (m + h).max(0)
+            lo = np.minimum.reduceat(m - h, starts, axis=0).max(0)
+            big = (self.mag[r0:r1] @ aq.T).max(0)
+            out[i] = 0.5 * (hi + lo).sum()
+            tol[i] = (0.5 * (hi - lo).sum()
+                      + 2 * (q.shape[1] + len(q)) * U32 * big.sum())
+        return out, tol
+
+
 def maxsim(index: Index, q: np.ndarray, pids: np.ndarray):
     """Exact MaxSim of query ``q`` (Lq, dim) over ``pids`` → (scores,
     forward-error bounds): a float32 evaluation obeys
     ``2·(dim + Lq)·2^-24·S`` with ``S`` the sum over query tokens of the
-    largest absolute-product sum among the passage's tokens."""
+    largest absolute-product sum among the passage's tokens. Upserted
+    pids are scored by ``Versions.maxsim``."""
     q = q.astype(np.float64)
+    pids = np.asarray(pids)
+    up = pids >= index.n_base
+    if up.any():
+        score, tol = np.empty(len(pids)), np.empty(len(pids))
+        score[up], tol[up] = index.versions.maxsim(
+            q, pids[up] - index.n_base)
+        if (~up).any():
+            score[~up], tol[~up] = maxsim(index, q, pids[~up])
+        return score, tol
     rows, first = index.token_rows(pids)
     emb, mag = index.decode(rows)
     sim = np.maximum.reduceat(emb @ q.T, first, axis=0)
@@ -114,11 +221,14 @@ class Splade:
     """Stage-1 scores over every passage, from the corpus's raw term
     weights quantised to the index's uint8 impacts."""
 
-    def __init__(self, docs: dict):
+    def __init__(self, docs: dict, extra: dict | None = None):
         ids = docs["doc_term_ids"]
         w = docs["doc_term_weights"]
-        self.n_docs, self.nnz = ids.shape
         self.quantum = float(w.max()) / 255.0
+        if extra is not None:      # upserted passages, at the base quantum
+            ids = np.concatenate([ids, extra["term_ids"]])
+            w = np.concatenate([w, extra["term_weights"]])
+        self.n_docs, self.nnz = ids.shape
         x = w.astype(np.float64).ravel() / self.quantum
         self.imp = np.clip(np.rint(x), 1, 255)
         # entries whose quotient sits on a rounding edge may take either
@@ -215,35 +325,51 @@ def _best(results):
     return min(results, key=lambda x: (x[0], max(x[1], x[2])))
 
 
-def check_hybrid(index, splade, q_emb, terms, weights, pids, scores, p):
-    s, ts = splade.scores(terms, weights)
-    order = _ranked(s, p["first_k"] + BOUNDARY_WINDOW)
-    alts = _alternatives(order, s, ts, p["first_k"])
-    pool = np.unique(np.concatenate(alts))
-    c_pool, tc_pool = maxsim(index, q_emb, pool)
-    c_of = dict(zip(pool.tolist(), c_pool))
-    tc_of = dict(zip(pool.tolist(), tc_pool))
+def hybrid_outcomes(index, splade, q_emb, terms, weights, p,
+                    states=(None,)):
+    """Every admissible outcome of one hybrid query, over the write
+    ``states`` (masks of the pids alive in each; None: the corpus as
+    built) and each state's admissible candidate lists → [(candidates,
+    {pid: fused score}, {pid: bound})]."""
+    s0, ts = splade.scores(terms, weights)
+    c_of, tc_of = {}, {}
     a, u = p["alpha"], U32
     out = []
-    for cand in alts:
-        sv = s[cand]
-        cv = np.array([c_of[int(x)] for x in cand])
-        tcv = np.array([tc_of[int(x)] for x in cand])
-        n = len(cand)
-        fused, tol = 0.0, 0.0
-        for x, w, t in ((sv, a, ts[cand]), (cv, 1 - a, tcv)):
-            mean, std = x.mean(), max(x.std(), 1e-9)
-            fused = fused + w * (x - mean) / std
-            # the input's own error through the normaliser, plus the
-            # float32 rounding of a length-n mean and variance
-            tol += w * (2 * t.max() / std
-                        + (n + 8) * u * (np.abs(x).max() + abs(mean)) / std)
-        ref = dict(zip(cand.tolist(), fused))
-        tols = dict.fromkeys(cand.tolist(), tol)
-        adm = set(cand.tolist())
-        out.append(judge(pids, scores, ref, tols, adm, cand,
-                         min(p["k"], n), p["k"]))
-    return _best(out), len(alts)
+    for alive in states:
+        s = s0 if alive is None else np.where(alive, s0, -np.inf)
+        order = _ranked(s, p["first_k"] + BOUNDARY_WINDOW)
+        alts = _alternatives(order, s, ts, p["first_k"])
+        pool = [x for x in np.unique(np.concatenate(alts)).tolist()
+                if x not in c_of]
+        if pool:
+            c_pool, tc_pool = maxsim(index, q_emb, np.array(pool))
+            c_of.update(zip(pool, c_pool))
+            tc_of.update(zip(pool, tc_pool))
+        for cand in alts:
+            sv = s[cand]
+            cv = np.array([c_of[int(x)] for x in cand])
+            tcv = np.array([tc_of[int(x)] for x in cand])
+            n = len(cand)
+            fused, tol = 0.0, 0.0
+            for x, w, t in ((sv, a, ts[cand]), (cv, 1 - a, tcv)):
+                mean, std = x.mean(), max(x.std(), 1e-9)
+                fused = fused + w * (x - mean) / std
+                # the input's own error through the normaliser, plus the
+                # float32 rounding of a length-n mean and variance
+                tol += w * (2 * t.max() / std + (n + 8) * u
+                            * (np.abs(x).max() + abs(mean)) / std)
+            out.append((cand, dict(zip(cand.tolist(), fused)),
+                        dict.fromkeys(cand.tolist(), tol)))
+    return out
+
+
+def check_hybrid(index, splade, q_emb, terms, weights, pids, scores, p,
+                 states=(None,)):
+    out = [judge(pids, scores, ref, tols, set(ref), cand,
+                 min(p["k"], len(cand)), p["k"])
+           for cand, ref, tols in hybrid_outcomes(
+               index, splade, q_emb, terms, weights, p, states)]
+    return _best(out), len(out)
 
 
 def check_plaid(index, q_emb, pids, scores, p):
@@ -293,35 +419,107 @@ def check_plaid(index, q_emb, pids, scores, p):
                  p["k"]), int(len(surv_poss) > len(surv_cert))
 
 
-def check(cfg: dict, index_dir, seed: int, client: dict, sample: np.ndarray,
-          k: int) -> dict:
+def n_slots(client: dict, n_base: int) -> int:
+    """Upserted pids run from ``n_base`` to the largest one assigned."""
+    ok = (client["w_status"] == stats.OK) & (client["w_op"] == 0)
+    return int(client["w_pid"][ok].max(initial=n_base - 1) - n_base + 1)
+
+
+class WriteLog:
+    """The writes a run sent, in order, and the corpus after each prefix
+    of them: ``alive[m]`` marks the pids alive once the first m writes
+    are applied (base pids, then one slot per upserted pid). A write
+    that failed changes nothing."""
+
+    def __init__(self, client: dict, n_base: int):
+        ok = client["w_status"] == stats.OK
+        sent = ~np.isnan(client["w_sent"])
+        self.sent = np.where(sent, client["w_sent"], np.inf)
+        self.ack = np.where(ok, client["w_ack"], np.inf)
+        alive = np.zeros(n_base + n_slots(client, n_base), bool)
+        alive[:n_base] = True
+        self.alive = [alive.copy()]
+        for op, pid, good in zip(client["w_op"].tolist(),
+                                 client["w_pid"].tolist(), ok.tolist()):
+            if good:
+                alive[pid] = op == 0
+            self.alive.append(alive.copy())
+
+    def states(self, sent: float, done: float) -> list:
+        """The corpora a query sent at ``sent`` and answered at ``done``
+        may have seen: every write acknowledged before the send applied,
+        none sent after the reply."""
+        lo = int(np.sum(self.ack < sent))
+        hi = max(lo, int(np.sum(self.sent <= done)))
+        return self.alive[lo:hi + 1]
+
+
+def upserted(cfg: dict, traffic: dict, client: dict, docs: dict,
+             seed: int, n_base: int) -> dict:
+    """The passage behind each upserted pid, drawn again from the seed:
+    {term_ids, term_weights, embs, lens} by slot (pid − n_base)."""
+    corpus = harness.corpus(cfg)
+    m = int(client["w_version"].max()) + 1
+    keys = gen.write_keys(corpus, traffic["writes"], m, seed)
+    v = gen.make_versions(corpus, docs, keys, seed)
+    ups = (client["w_op"] == 0) & (client["w_version"] >= 0)
+    if not np.array_equal(keys[client["w_version"][ups]],
+                          client["w_key"][ups]):
+        raise ValueError("the write log's keys are not the seed's")
+    ok = ups & (client["w_status"] == stats.OK)
+    which = np.zeros(n_slots(client, n_base), np.int64)  # empty: never alive
+    which[client["w_pid"][ok] - n_base] = client["w_version"][ok]
+    return {k: v[k][which] for k in ("term_ids", "term_weights", "embs",
+                                     "lens")}
+
+
+def check(cfg: dict, traffic: dict, index_dir, seed: int, client: dict,
+          sample: np.ndarray, forget_writes: bool = False) -> dict:
     """Judge the sampled answers → {name: value} for ``failed``,
-    ``bad_pids``, ``score_err``, ``rank_gap``, and ``ambiguous`` (sampled
-    answers with more than one admissible candidate list; information,
-    not compared)."""
+    ``bad_pids``, ``score_err``, ``rank_gap``; ``ambiguous`` (sampled
+    answers with more than one admissible outcome) and ``states``
+    (sampled answers with more than one admissible write state) are
+    information, not compared. ``forget_writes`` judges against the
+    corpus as built, as though no write had been made: the control of
+    the check under writes."""
     corpus, serving = harness.corpus(cfg), cfg["serving"]
-    p = dict(serving, k=k)
+    p = dict(serving, k=traffic["k"])
     docs = gen.make_corpus(corpus, seed)
-    queries = gen.make_queries(corpus, docs, len(client["status"]), seed)
+    n = len(client["status"])
+    queries = gen.make_queries(corpus, docs, n, seed,
+                               gen.query_rel(corpus, traffic, n, seed))
     index = Index(index_dir, corpus["dim"], cfg["index"]["nbits"],
                   docs["doc_lens"])
-    splade = Splade(docs) if serving["method"] == "hybrid" else None
-    failed = int(np.sum(client["status"] != 0))
+    log, extra = None, None
+    if "w_status" in client and not forget_writes:
+        if serving["method"] != "hybrid":
+            raise ValueError("writes are judged under hybrid serving only")
+        log = WriteLog(client, index.n_base)
+        extra = upserted(cfg, traffic, client, docs, seed, index.n_base)
+        index.versions = Versions(index, extra["embs"], extra["lens"])
+    splade = (Splade(docs, extra) if serving["method"] == "hybrid"
+              else None)
+    failed = stats.failed(client)
 
     def one(i):
         pids, scores = client["pids"][i], client["scores"][i]
         if splade is not None:
+            states = ((None,) if log is None else log.states(
+                client["sent"][i], client["done"][i]))
             return check_hybrid(
                 index, splade, queries["q_embs"][i],
                 queries["q_term_ids"][i], queries["q_term_weights"][i],
-                pids, scores, p)
-        return check_plaid(index, queries["q_embs"][i], pids, scores, p)
+                pids, scores, p, states), len(states)
+        return check_plaid(index, queries["q_embs"][i], pids, scores,
+                           p), 1
     answered = [i for i in sample.tolist() if client["status"][i] == 0]
     with ThreadPoolExecutor(THREADS) as pool:
-        out = list(pool.map(one, answered))
+        res = list(pool.map(one, answered))
+    out = [r for r, _ in res]
     bad = sum(b for (b, _, _), _ in out)
     err = max((e for (_, e, _), _ in out), default=0.0)
     gap = max((g for (_, _, g), _ in out), default=0.0)
     amb = sum(n_alt > 1 for _, n_alt in out)
     return {"failed": failed, "bad_pids": bad, "score_err": err,
-            "rank_gap": gap, "ambiguous": amb}
+            "rank_gap": gap, "ambiguous": amb,
+            "states": sum(n_states > 1 for _, n_states in res)}

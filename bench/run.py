@@ -20,6 +20,13 @@ Set-up (``setup_s``, from process start to the first due request):
    the cell's method at every micro-batch size it can form, from the
    persistent compilation cache in ``bench/.cache/jax``.
 
+A configuration with ``serving.live`` is served as ``launch/serve.py
+--live --live-compact-every N`` serves it: the live index is attached,
+the load generator sends the traffic's ``preload`` updates over the
+TCP front, the overlay path that then serves every query is warmed at
+every micro-batch size, and the ``AutoCompactor`` starts; it is stopped
+once the window's replies are in.
+
 The window: the client sends open-loop arrivals for ``--seconds`` and
 times each request from its due time to its parsed reply. With
 ``--trace 1`` the profiler records the middle of the window, the
@@ -84,7 +91,8 @@ def build_index(c: dict, seed: int, out, require_tpu: bool) -> dict:
         [sys.executable, str(harness.HERE / "build.py"), "--config",
          str(c["config_file"]), "--seed", str(seed), "--out", str(out),
          "--chips", str(chips)] + (["--require-tpu"] if require_tpu else [])
-        + (["--warm-k", str(c["traffic"]["k"])] if cold else []),
+        + (["--warm-k", str(c["traffic"]["k"]), "--traffic",
+            str(c["traffic_file"])] if cold else []),
         stdout=subprocess.PIPE, text=True, cwd=harness.ROOT)
     if proc.returncode == 3:
         _check_device(json.loads(proc.stdout.strip().splitlines()[-1])[
@@ -133,19 +141,58 @@ def open_retriever(cfg: dict, index_dir):
     return MultiStageRetriever(sidx, searcher, ms), longest
 
 
-def warm(retr, cfg: dict, k: int):
+def go_live(retr, cfg: dict):
+    """Attach the live index where the configuration has
+    ``serving.live``, as ``launch/serve.py --live --live-compact-every
+    N`` does → its ``AutoCompactor``, not yet started; None for a
+    frozen index."""
+    live = cfg["serving"].get("live")
+    if live is None:
+        return None
+    from repro.index.live import AutoCompactor
+    retr.enable_live()
+    return AutoCompactor(retr, live["compact_every"])
+
+
+def stop_compactor(compactor):
+    """Stop the program's ``AutoCompactor`` and wait until its thread
+    has ended; a compaction under way runs to its end. Its ``stop()``
+    joins through ``threading.Thread``, and on Python 3.12 that join
+    raises TypeError as soon as it finds the thread ended: the
+    compactor's stop event is named ``_stop``, which shadows the method
+    ``Thread`` calls there (PERF.md §7). That TypeError is the sign
+    that the thread has ended."""
+    while True:
+        try:
+            compactor.stop()
+            if not compactor.is_alive():
+                return
+        except TypeError:
+            return
+
+
+def warm(retr, cfg: dict, k: int, upserted: dict | None = None):
     """Compile (or load from the cache) every program the window can
     dispatch: the cell's method at each micro-batch size 1..max_batch,
-    on queries of the served shape."""
+    on queries of the served shape. Queries take the terms of the
+    ``upserted`` passages (``term_ids``, ``term_weights``) where given,
+    so that the live overlay path scores their delta rows too."""
     c, s = cfg["corpus"], cfg["serving"]
     rng = np.random.default_rng(0)
     b_max = s["max_batch"]
     q = rng.standard_normal((b_max, c["query_maxlen"], c["dim"]),
                             dtype=np.float32)
     q /= np.linalg.norm(q, axis=-1, keepdims=True)
-    terms = rng.integers(0, c["vocab"], (b_max, c["query_nnz"]),
-                         dtype=np.int32)
-    weights = np.ones((b_max, c["query_nnz"]), np.float32)
+    if upserted is None:
+        terms = rng.integers(0, c["vocab"], (b_max, c["query_nnz"]),
+                             dtype=np.int32)
+        weights = np.ones((b_max, c["query_nnz"]), np.float32)
+    else:
+        rows = np.arange(b_max) % len(upserted["term_ids"])
+        terms = np.asarray(upserted["term_ids"], np.int32)[
+            rows, :c["query_nnz"]]
+        weights = np.asarray(upserted["term_weights"], np.float32)[
+            rows, :c["query_nnz"]]
     for b in range(1, b_max + 1):
         retr.search_batch(s["method"], q_embs=list(q[:b]),
                           term_ids=list(terms[:b]),
@@ -216,6 +263,17 @@ def _at(t, fn):
     return th
 
 
+def preload(client, port: int) -> dict:
+    """Have the load generator send the traffic's ``preload`` updates
+    → the terms of the new versions."""
+    client.stdin.write(f"PRELOAD {port}\n")
+    client.stdin.flush()
+    line = client.stdout.readline().split(maxsplit=1)
+    if not line or line[0] != "PRELOADED":
+        raise RuntimeError("the load generator did not preload")
+    return json.loads(line[1])
+
+
 def serve_window(c: dict, seed: int, seconds: float, trace: bool,
                  index_dir, work, client) -> dict:
     """Set-up step 3, the window and the shutdown → the run's record."""
@@ -228,10 +286,12 @@ def serve_window(c: dict, seed: int, seconds: float, trace: bool,
     s = cfg["serving"]
     phases = {"jax_ready": time.monotonic() - T_START}
     retr, longest = open_retriever(cfg, index_dir)
+    compactor = go_live(retr, cfg)
     phases["index_open"] = time.monotonic() - T_START
-    warm(retr, cfg, traffic["k"])
-    phases["warm"] = time.monotonic() - T_START
-    retr.reset_stage_stats()
+    if compactor is None:
+        warm(retr, cfg, traffic["k"])
+        phases["warm"] = time.monotonic() - T_START
+        retr.reset_stage_stats()
     engine = ServeEngine(retr, pipeline_depth=s["pipeline_depth"])
     server = RetrievalServer(engine, max_batch=s["max_batch"],
                              batch_timeout_ms=s["batch_timeout_ms"])
@@ -244,6 +304,11 @@ def serve_window(c: dict, seed: int, seconds: float, trace: bool,
     if not ready or ready[0] != "READY":
         raise RuntimeError("the load generator did not start")
     phases["client_ready"] = time.monotonic() - T_START
+    if compactor is not None:
+        warm(retr, cfg, traffic["k"], preload(client, server.tcp_port))
+        phases["preload_and_warm"] = time.monotonic() - T_START
+        retr.reset_stage_stats()
+        compactor.start()
     rec = {"trace": None, "tail_calls": [], "interval": (0.0, seconds),
            "phases": phases, "ivf_longest": longest}
     undo = None
@@ -267,8 +332,15 @@ def serve_window(c: dict, seed: int, seconds: float, trace: bool,
             recorder.snapshot("end")
             jax.profiler.stop_trace()
         threads = [_at(t0 + a, begin), _at(t0 + b, end)]
-    mem = {}
-    at_close = _at(t0 + seconds, lambda: mem.update(rss()))
+    mem, live = {}, {}
+
+    def close():
+        mem.update(rss())
+        recorder.snapshot("close")
+        live["close"] = retr.live_stats()
+    at_close = _at(t0 + seconds, close)
+    recorder.snapshot("start")
+    live["start"] = retr.live_stats()
     client.stdin.write(f"GO {server.tcp_port} {t0!r}\n")
     client.stdin.flush()
     client.wait(timeout=seconds + CLIENT_GRACE_S)
@@ -280,7 +352,8 @@ def serve_window(c: dict, seed: int, seconds: float, trace: bool,
         for th in threads:
             th.join()
         rec["interval"] = (marks["a"], marks["b"])
-    recorder.snapshot("close")
+    if compactor is not None:
+        stop_compactor(compactor)
     server.shutdown_gracefully()
     tcp.server_close()
     loop.join(timeout=10)
@@ -299,6 +372,7 @@ def serve_window(c: dict, seed: int, seconds: float, trace: bool,
     rec["rss"] = mem
     rec["results"] = np.array(recorder.results).reshape(-1, 3) - clock - t0
     rec["stages"] = recorder.snapshots
+    rec["live"] = live
     rec["first_stage"] = retr.compile_plan(s["method"]).stages[0].name
     if trace:
         import trace as trace_mod
@@ -341,11 +415,13 @@ def per_layer(bench: dict, cell: str, rec: dict) -> dict:
 
 def run(workload: str, seed: int, seconds: float, trace: bool,
         require_tpu: bool = True, c: dict | None = None,
-        bench: dict | None = None) -> dict:
+        bench: dict | None = None, forget_writes: bool = False) -> dict:
     """One run of a cell → the result object (without printing it).
     ``c`` and ``bench`` stand in for the cell and ``BENCHMARK.json``
     that the workload's name finds; ``require_tpu=False`` lets a CPU
-    rehearsal drive everything but the look for a chip."""
+    rehearsal drive everything but the look for a chip.
+    ``forget_writes`` judges the answers against the corpus as it was
+    before any write: the control of the check under writes."""
     c = c or harness.cell(workload)
     bench = bench or harness.benchmark()
     harness.use_compile_cache()
@@ -383,8 +459,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     chk = c["config"]["check"]
     sample = stats.sample(rec["client"], chk["sample"], seed)
     t0 = time.monotonic()
-    numbers = reference.check(c["config"], index_dir, seed, rec["client"],
-                              sample, c["traffic"]["k"])
+    numbers = reference.check(c["config"], c["traffic"], index_dir, seed,
+                              rec["client"], sample, forget_writes)
     ref_s = time.monotonic() - t0
     limits = chk["limits"]
     correct = all(numbers[n] <= lim for n, lim in limits.items())
@@ -394,17 +470,32 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     if trace and rec["trace"] is not None:
         device.update(busy_s=rec["trace"]["busy_s"],
                       window_s=rec["trace"]["window_s"])
+    cl = rec["client"]
     result = {"correct": bool(correct),
-              "attempted": int(len(rec["client"]["status"])),
-              "failed": stats.failed(rec["client"]),
+              "attempted": int(len(cl["status"])
+                               + len(cl.get("w_status", ()))),
+              "failed": stats.failed(cl),
               "metrics": metrics, "device": device}
     if trace and rec["trace"] is not None:
         result["breakdown"] = rec["trace"]["breakdown"]
+    counters = [rec["stages"][x]["counters"] for x in ("start", "close")]
     result["info"] = {"build_s": build["times"], "reference_s": ref_s,
                       "setup_phases_s": rec["phases"],
                       "ivf_longest_list": rec["ivf_longest"],
                       "rss_at_close": rec["rss"], "sampled": len(sample),
-                      "ambiguous": numbers["ambiguous"]}
+                      "ambiguous": numbers["ambiguous"],
+                      "in_flight_at_close": stats.in_flight(cl, seconds),
+                      "jax_compiles_in_window": (
+                          counters[1].get("jax_compiles", 0)
+                          - counters[0].get("jax_compiles", 0))}
+    if "w_status" in cl:
+        lv = rec["live"]
+        result["info"]["writes"] = {
+            "sent": int(len(cl["w_status"])),
+            "acked": stats.writes_acked(cl),
+            "compactions_in_window": (lv["close"].get("compactions", 0)
+                                      - lv["start"].get("compactions", 0)),
+            "states_judged": numbers["states"]}
     result["checks"] = checks
     shutil.rmtree(index_dir, ignore_errors=True)
     return result

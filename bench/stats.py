@@ -40,7 +40,20 @@ def lateness_ms(client: dict) -> np.ndarray:
 
 
 def failed(client: dict) -> int:
-    return int(np.sum(client["status"] != OK))
+    """Requests that failed or got no reply, writes among them."""
+    return int(np.sum(client["status"] != OK)
+               + np.sum(client.get("w_status", np.zeros(0)) != OK))
+
+
+def writes_acked(client: dict) -> int:
+    return int(np.sum(client.get("w_status", np.zeros(0)) == OK))
+
+
+def in_flight(client: dict, seconds: float) -> int:
+    """Queries sent by the window's close whose reply came after it, or
+    never."""
+    sent = client["sent"] <= seconds
+    return int(np.sum(sent & ~(client["done"] <= seconds)))
 
 
 def sample(client: dict, n: int, seed: int) -> np.ndarray:

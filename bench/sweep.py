@@ -18,6 +18,13 @@ first fifth's (no growing backlog), and the p50 is at most ``SLOWDOWN``
 times the low load's. The sweep stops at the first rate that fails; the
 knee is the rate before it, and the last line names it. The benchmark's
 runs never run this; its rates go into the traffic files as numbers.
+
+A live configuration (``serving.live``) is served as a run serves it:
+the first window's load generator sends the traffic's ``preload``
+updates before the overlay path is warmed and the compactor starts, and
+each later window continues from the writes of the one before (each
+key's current pid is handed on in ``keys.npy``). Failed writes fail a
+rate.
 """
 
 from __future__ import annotations
@@ -68,7 +75,11 @@ def main(argv=None):
 
     cfg, s = c["config"], c["config"]["serving"]
     retr, _ = run.open_retriever(cfg, index_dir)
-    run.warm(retr, cfg, c["traffic"]["k"])
+    compactor = run.go_live(retr, cfg)
+    if compactor is None:
+        run.warm(retr, cfg, c["traffic"]["k"])
+    keys = work / "keys.npy"
+    keys.unlink(missing_ok=True)
     engine = ServeEngine(retr, pipeline_depth=s["pipeline_depth"])
     server = RetrievalServer(engine, max_batch=s["max_batch"],
                              batch_timeout_ms=s["batch_timeout_ms"])
@@ -85,9 +96,15 @@ def main(argv=None):
                 [sys.executable, str(harness.HERE / "client.py"),
                  "--config", str(c["config_file"]), "--traffic", str(tr),
                  "--seed", str(args.seed), "--seconds",
-                 repr(args.seconds), "--out", str(out), "--drain", "20"],
+                 repr(args.seconds), "--out", str(out), "--drain", "20",
+                 "--keys", str(keys)],
                 stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
             client.stdout.readline()
+            if compactor is not None and i == 0:
+                run.warm(retr, cfg, c["traffic"]["k"],
+                         run.preload(client, server.tcp_port))
+                compactor.start()
+            live0 = retr.live_stats()
             retr.reset_stage_stats()
             t0 = time.monotonic() + 0.5
             client.stdin.write(f"GO {server.tcp_port} {t0!r}\n")
@@ -97,13 +114,18 @@ def main(argv=None):
                 rec = {k: z[k] for k in z.files}
             lat = stats.latencies_ms(rec)
             fifth = len(lat) // 5
-            snap = retr.pipeline_stats.snapshot()["stages"]
+            full = retr.pipeline_stats.snapshot()
+            snap = full["stages"]
             first = snap.get(retr.compile_plan(s["method"]).stages[0].name,
                              {})
             row = {
                 "rate": rate, "offered": len(lat) / args.seconds,
                 "completed_qps": stats.qps(rec, args.seconds),
                 "failed": stats.failed(rec),
+                "writes_acked": stats.writes_acked(rec),
+                "jax_compiles": full["counters"].get("jax_compiles", 0),
+                "compactions": (retr.live_stats().get("compactions", 0)
+                                - live0.get("compactions", 0)),
                 "p50_ms": stats.percentile_ms(rec, 50),
                 "p95_ms": stats.percentile_ms(rec, 95),
                 "p50_first_fifth_ms": float(np.median(lat[:fifth])),
@@ -123,6 +145,8 @@ def main(argv=None):
             # the next rate starts on an empty queue
             server.drain()
     finally:
+        if compactor is not None and compactor.ident is not None:
+            run.stop_compactor(compactor)
         server.shutdown_gracefully()
         tcp.server_close()
         engine.close()

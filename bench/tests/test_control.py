@@ -28,7 +28,7 @@ def test_one_precision_step_down_fails(kind, tmp_path):
     for precision in ("highest", "high"):
         client["pids"], client["scores"] = control.answers(
             cfg, tmp_path, tiny.SEED, n, sample, k, precision)
-        numbers = reference.check(cfg, tmp_path, tiny.SEED, client, sample,
-                                  k)
+        numbers = reference.check(cfg, c["traffic"], tmp_path, tiny.SEED,
+                                  client, sample)
         verdict[precision] = all(numbers[m] <= v for m, v in limits.items())
     assert verdict == {"highest": True, "high": False}

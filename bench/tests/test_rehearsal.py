@@ -30,9 +30,10 @@ def test_run_is_correct_and_reports_its_metrics(kind):
 
 HOST_LAYERS = {
     "hybrid": ("p95_ms.steady", "client_late_ms", "queue_wait_ms",
-               "gather_ms", "stage1_ms"),
+               "gather_ms", "stage1_ms", "jax_compiles.steady"),
     "plaid": ("p50_ms.plaid", "p95_ms.plaid", "client_late_ms.plaid",
-              "queue_wait_ms.plaid", "gather_ms.plaid")}
+              "queue_wait_ms.plaid", "gather_ms.plaid",
+              "jax_compiles.plaid")}
 DEVICE_LAYERS = {
     "hybrid": ("tail_device_ms", "tail_roofline", "device_idle.steady"),
     "plaid": ("tail_device_ms.plaid", "tail_roofline.plaid",
@@ -49,6 +50,8 @@ def test_traced_run_reads_the_host_layers(kind):
     assert set(m) == set(HOST_LAYERS[kind])
     for name in HOST_LAYERS[kind]:
         assert np.isfinite(m[name]["value"]), name
+    # warm-up compiled every shape the window dispatches
+    assert m[HOST_LAYERS[kind][-1]]["value"] == 0
     # no TPU plane in a CPU trace: the device readers find nothing
     for name in DEVICE_LAYERS[kind]:
         assert name not in m

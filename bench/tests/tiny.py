@@ -12,10 +12,12 @@ SECONDS = 2.0
 
 
 def cell(kind: str, traffic: str = "tiny-traffic"):
-    """→ (cell, bench) for ``run.run`` with ``kind`` hybrid or plaid."""
+    """→ (cell, bench) for ``run.run`` with ``kind`` hybrid, plaid or
+    live (hybrid serving a live index)."""
     name = f"tiny-{kind}"
     like = {"hybrid": "msmarco-hybrid.steady",
-            "plaid": "msmarco-plaid.steady"}[kind]
+            "plaid": "msmarco-plaid.steady",
+            "live": "msmarco-hybrid.steady"}[kind]
     bench = harness.benchmark()
     for group in ("end_to_end", "per_layer"):
         for m in bench[group]:
