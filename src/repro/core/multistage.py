@@ -10,6 +10,7 @@ Four systems, exactly as the paper's evaluation defines them:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -18,6 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import hybrid as hybrid_mod
 from repro.core.plaid import (
@@ -36,6 +38,7 @@ from repro.serving.pipeline import (
     PipelineStats,
     Stage,
     StagePlan,
+    span_ids,
 )
 
 SPLADE_BACKENDS = ("host", "jax", "pallas")
@@ -756,8 +759,10 @@ class MultiStageRetriever:
         alphas = self._alpha_array(alpha, n)
         live = self.live
         if live is not None and live.dirty and self._live_inline:
+            qids = (None if ctxs is None or any(c is None for c in ctxs)
+                    else [c.qid for c in ctxs])
             return self._search_batch_live(live, method, q_embs, term_ids,
-                                           term_weights, alphas, k)
+                                           term_weights, alphas, k, qids)
         cb = self.build_batch(method, q_embs, term_ids, term_weights,
                               alphas, k, n, ctxs=ctxs)
         cb = self.compile_plan(method).run(cb, stats=self.pipeline_stats)
@@ -783,8 +788,14 @@ class MultiStageRetriever:
             raise ValueError("live index requires the host (mmap) tier; "
                              "device_resident pools are frozen")
         from repro.index.live import LiveIndexState
-        self.live = LiveIndexState(self.searcher.index, self.splade)
-        return self.live
+        live = self.live = LiveIndexState(self.searcher.index, self.splade)
+
+        def gauges():
+            st = live.stats()
+            return {"delta_docs": st["delta_docs"],
+                    "tombstones": st["tombstones"]}
+        self.pipeline_stats.gauge(gauges)
+        return live
 
     def _require_live(self):
         if self.live is None:
@@ -792,19 +803,40 @@ class MultiStageRetriever:
                                "--live)")
         return self.live
 
+    @contextlib.contextmanager
+    def _live_span(self, name: str, queries: int = 0, **stats):
+        """Profiler span ``name`` (``stage:live_*`` or ``live:*``, with
+        ``stats``) whose interval, once the body has returned, is also
+        recorded in :attr:`pipeline_stats` as a plan stage's is, under
+        the name less its ``stage:`` prefix with ``:`` made ``_``
+        (``stage:live_delta`` → ``live_delta``, ``live:upsert`` →
+        ``live_upsert``)."""
+        with TraceAnnotation(name, **stats):
+            t0 = time.perf_counter()
+            yield
+            self.pipeline_stats.record(
+                name.removeprefix("stage:").replace(":", "_"),
+                time.perf_counter() - t0, queries=queries)
+
     def live_upsert(self, doc_emb, term_ids, term_weights,
                     doc_len=None) -> int:
         """Append a document to the delta segment → its global pid.
         Bumps the index generation so result/stage-1 caches invalidate."""
-        pid = self._require_live().upsert(doc_emb, term_ids, term_weights,
-                                          doc_len)
+        live = self._require_live()
+        n = len(doc_emb) if doc_len is None else int(doc_len)
+        with self._live_span("live:upsert", doc_len=n):
+            pid = live.upsert(doc_emb, term_ids, term_weights, doc_len)
+        self.pipeline_stats.counter("live_upserts")
         self.bump_index_generation()
         return pid
 
     def live_delete(self, gpid: int) -> bool:
         """Tombstone a global pid; True if it was live before."""
-        ok = self._require_live().delete(gpid)
+        live = self._require_live()
+        with self._live_span("live:delete"):
+            ok = live.delete(gpid)
         if ok:
+            self.pipeline_stats.counter("live_deletes")
             self.bump_index_generation()
         return ok
 
@@ -831,58 +863,84 @@ class MultiStageRetriever:
         if n_take == 0:
             return None
         from repro.index import live as live_mod
+        from repro.index.builder import ColBERTIndex
         idx = self.searcher.index
         gen = self.index_generation + 1
         col_dir = idx.path.with_name(f"{idx.path.name}.g{gen}")
         spl_dir = idx.path.with_name(f"splade.g{gen}")
-        live_mod.compact_colbert_dir(idx, live, n_take, col_dir)
-        live_mod.compact_splade_dir(self.splade, live, n_take, spl_dir)
-        from repro.index.builder import ColBERTIndex
-        new_index = ColBERTIndex(col_dir, mode=idx.store.mode)
-        new_searcher = PLAIDSearcher(new_index, self.searcher.params,
-                                     device_resident=False,
-                                     device=self.searcher.device)
-        new_splade = SpladeIndex.load(spl_dir)
-        with live.gate.write():
-            self.splade = new_splade
-            self.searcher = new_searcher
-            with self._lock:
-                self._plans.clear()
-                self._splade_device = None
-            live.rebase(n_take)
-            self.bump_index_generation()
+        with self._live_span("live:compact", n_take=n_take):
+            live_mod.compact_colbert_dir(idx, live, n_take, col_dir)
+            live_mod.compact_splade_dir(self.splade, live, n_take, spl_dir)
+            new_index = ColBERTIndex(col_dir, mode=idx.store.mode)
+            new_searcher = PLAIDSearcher(new_index, self.searcher.params,
+                                         device_resident=False,
+                                         device=self.searcher.device)
+            new_splade = SpladeIndex.load(spl_dir)
+            with live.gate.write():
+                self.splade = new_splade
+                self.searcher = new_searcher
+                with self._lock:
+                    self._plans.clear()
+                    self._splade_device = None
+                live.rebase(n_take)
+                self.bump_index_generation()
+        self.pipeline_stats.counter("live_compactions")
         return {"compacted": n_take, "colbert_dir": str(col_dir),
                 "splade_dir": str(spl_dir)}
 
-    def _live_exact(self, live, q, q_valid, pids_p: np.ndarray):
+    def _live_exact(self, live, q, q_valid, pids_p: np.ndarray, b: int,
+                    qids=None):
         """Exact scores (host (Bp, C) f32) for a pid matrix that may mix
-        base and delta pids. Each origin is scored by its own gather +
-        decompress-MaxSim dispatch and scattered positionally — per-
-        candidate scores are independent, so the stitched matrix is
-        bitwise what one dispatch over a unified index would produce."""
+        base and delta pids; its first ``b`` rows are the real queries.
+        Each origin is scored by its own gather + decompress-MaxSim
+        dispatch and scattered positionally — per-candidate scores are
+        independent, so the stitched matrix is bitwise what one dispatch
+        over a unified index would produce. Each origin's span carries
+        its dispatch's shapes (``B``, ``C``, ``Ld``, ``pd``, ``Lq``,
+        ``dim``, ``K``, ``nbits``); ``delta_rows_scored`` counts the
+        real queries' delta candidates."""
         pids_p = np.asarray(pids_p)
         delta_mask = pids_p >= live.base_n
-        base_pids = np.where(delta_mask, -1, pids_p)
-        codes, packed, valid = self.searcher._dedup_gather(
-            base_pids, codes_only=False)
-        base_scores = np.asarray(self.searcher.score_gathered_lazy(
-            jnp.asarray(q), jnp.asarray(q_valid), jnp.asarray(codes),
-            jnp.asarray(packed), jnp.asarray(valid), base_pids))
+        shapes = {"B": pids_p.shape[0], "C": pids_p.shape[1],
+                  "Ld": live.doc_maxlen, "pd": live.packed_dim,
+                  "Lq": q.shape[1], "dim": q.shape[2],
+                  "K": live.n_centroids, "nbits": live.nbits,
+                  **span_ids(qids)}
+        with self._live_span("stage:live_base_exact", b, **shapes):
+            base_pids = np.where(delta_mask, -1, pids_p)
+            codes, packed, valid = self.searcher._dedup_gather(
+                base_pids, codes_only=False)
+            base_scores = np.asarray(self.searcher.score_gathered_lazy(
+                jnp.asarray(q), jnp.asarray(q_valid), jnp.asarray(codes),
+                jnp.asarray(packed), jnp.asarray(valid), base_pids))
         if delta_mask.any():
-            delta_pids = np.where(delta_mask, pids_p, -1)
-            d_scores = live.exact_scores(q, q_valid, delta_pids)
+            self.pipeline_stats.counter("delta_rows_scored",
+                                        int(delta_mask[:b].sum()))
+            with self._live_span("stage:live_delta", b, **shapes):
+                d_scores = live.exact_scores(
+                    q, q_valid, np.where(delta_mask, pids_p, -1))
             return np.where(delta_mask, d_scores,
                             base_scores).astype(np.float32)
         return base_scores.astype(np.float32)
 
     def _search_batch_live(self, live, method, q_embs, term_ids,
-                           term_weights, alphas, k: int):
-        """Overlay serving for a dirty live state: compose the same
-        stage primitives the frozen plans run — base index scoring plus
-        the delta segment, tombstones filtered at every merge — without
-        touching the compiled plans (which stay bitwise-frozen for the
-        inert case). Always the split stage-4 tail (bitwise-identical to
-        the fused one per the PR 8 parity contract)."""
+                           term_weights, alphas, k: int, qids=None):
+        """Overlay serving for a dirty live state (one
+        ``stage:live_overlay`` span, naming the batch's ``qids``):
+        compose the same stage primitives the frozen plans run — base
+        index scoring plus the delta segment, tombstones filtered at
+        every merge — without touching the compiled plans (which stay
+        bitwise-frozen for the inert case). Always the split stage-4
+        tail, bitwise-identical to the fused one (the fused/split
+        parity tests hold them to it)."""
+        n = len(q_embs) if q_embs is not None else len(term_ids)
+        with self._live_span("stage:live_overlay", n, **span_ids(qids)):
+            return self._search_batch_live_impl(
+                live, method, q_embs, term_ids, term_weights, alphas, k,
+                qids)
+
+    def _search_batch_live_impl(self, live, method, q_embs, term_ids,
+                                term_weights, alphas, k: int, qids):
         from repro.core import plaid as plaid_mod
         from repro.core.sharded import merge_topk
         p = self.params
@@ -890,14 +948,17 @@ class MultiStageRetriever:
         outcome = BatchOutcome()
 
         if method in ("splade", "rerank", "hybrid"):
-            pids_b, s_scores = self.run_splade_batch(
-                list(term_ids), list(term_weights), p.first_k)
+            with self._live_span("stage:live_stage1", len(term_ids),
+                                 **span_ids(qids)):
+                pids_b, s_scores = self.run_splade_batch(
+                    list(term_ids), list(term_weights), p.first_k)
             if method == "splade":
                 return pids_b[:, :k], s_scores[:, :k], outcome
             q, q_valid = pad_query_batch_host(q_embs)
             B, q, q_valid, pids_p = _pad_batch_rows(
                 q, q_valid, np.asarray(pids_b))
-            c_scores = self._live_exact(live, q, q_valid, pids_p)[:B]
+            c_scores = self._live_exact(live, q, q_valid, pids_p, B,
+                                        qids)[:B]
             if method == "rerank":
                 final = np.where(pids_b >= 0, c_scores, -np.inf)
             else:
@@ -952,7 +1013,7 @@ class MultiStageRetriever:
             np.concatenate([base_cand, d_mat], axis=1),
             np.concatenate([approx_np, d_approx], axis=1), ndocs)
 
-        exact = self._live_exact(live, q, q_valid, final_np)
+        exact = self._live_exact(live, q, q_valid, final_np, B, qids)
         out_pids, out_scores = searcher.finalize_topk(
             jnp.asarray(exact), jnp.asarray(final_np), B, k)
         return out_pids, out_scores, outcome

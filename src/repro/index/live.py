@@ -29,11 +29,13 @@ ascending) ↔ (0..n_survivors-1).
 
 from __future__ import annotations
 
+import functools
 import json
 import pathlib
 import threading
 from contextlib import contextmanager
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -41,6 +43,20 @@ from repro.core.store import PagedStore
 from repro.index import ivf as ivf_mod
 from repro.index import kmeans, residual
 from repro.kernels.decompress_maxsim.ops import decompress_maxsim_scores_batch
+
+
+@functools.partial(jax.jit, static_argnames=("nbits",))
+def encode_padded(emb, valid, centroids, cutoffs, nbits: int):
+    """Residual-encode one passage padded to the index's ``doc_maxlen``:
+    emb (doc_maxlen, dim) with its token validity (doc_maxlen,) →
+    (cids (doc_maxlen,) int32, packed (doc_maxlen, pd) uint8), zero on
+    the padding. One program for every passage length; each row is
+    encoded as the per-length path encodes it (``kmeans.assign`` scores
+    fixed chunks of rows, and the residual codec works row by row)."""
+    cids, _ = kmeans.assign(emb, centroids)
+    packed = residual.encode_residuals(emb, cids, centroids, cutoffs, nbits)
+    return (jnp.where(valid, cids, 0),
+            jnp.where(valid[:, None], packed, jnp.uint8(0)))
 
 
 class RWGate:
@@ -190,13 +206,13 @@ class LiveIndexState:
         L = int(emb.shape[0] if doc_len is None else doc_len)
         if not (0 < L <= self.doc_maxlen):
             raise ValueError(f"doc_len {L} outside (0, {self.doc_maxlen}]")
-        emb = emb[:L]
-        cids, _ = kmeans.assign(jnp.asarray(emb), self._centroids_j)
-        cids = np.asarray(cids, np.int32)
-        packed = np.asarray(residual.encode_residuals(
-            jnp.asarray(emb), jnp.asarray(cids), self._centroids_j,
-            self._cutoffs_j, self.nbits), np.uint8)
-        return cids, packed, L
+        padded = np.zeros((self.doc_maxlen, self.dim), np.float32)
+        padded[:L] = emb[:L]
+        cids, packed = encode_padded(
+            jnp.asarray(padded), jnp.asarray(np.arange(self.doc_maxlen) < L),
+            self._centroids_j, self._cutoffs_j, self.nbits)
+        return (np.asarray(cids, np.int32)[:L],
+                np.asarray(packed, np.uint8)[:L], L)
 
     def upsert(self, doc_emb, term_ids, term_weights, doc_len=None) -> int:
         """Append one document to the delta segment → its global pid."""
@@ -552,10 +568,12 @@ class AutoCompactor(threading.Thread):
         self.retriever = retriever
         self.every = int(every)
         self.interval_s = interval_s
-        self._stop = threading.Event()
+        # not ``_stop``: that name is a ``threading.Thread`` method,
+        # which ``join`` calls once the thread has ended
+        self._stop_event = threading.Event()
 
     def run(self):
-        while not self._stop.wait(self.interval_s):
+        while not self._stop_event.wait(self.interval_s):
             live = getattr(self.retriever, "live", None)
             if live is not None and live.n_delta >= self.every:
                 try:
@@ -565,5 +583,5 @@ class AutoCompactor(threading.Thread):
                     traceback.print_exc()
 
     def stop(self):
-        self._stop.set()
+        self._stop_event.set()
         self.join(timeout=5)

@@ -335,6 +335,9 @@ class PipelineStats:
     process (``jax_compiles``, and their ``jax_compile_ms``) among its
     counters, from one ``jax.monitoring`` listener per process. A
     served window after warm-up should count none.
+
+    Gauges (:meth:`gauge`) add values of the moment to the counters of
+    every snapshot, such as a live index's delta size.
     """
 
     def __init__(self, ewma_alpha: float = 0.25):
@@ -347,6 +350,7 @@ class PipelineStats:
         self._busy_any_s = 0.0
         self._overlap_s = 0.0
         self._counters: dict[str, float] = {}
+        self._gauges: list[Callable[[], Mapping[str, float]]] = []
         _watch_compiles(self)
 
     def reset(self):
@@ -417,13 +421,24 @@ class PipelineStats:
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + delta
 
+    def gauge(self, read: Callable[[], Mapping[str, float]]):
+        """Add ``read()``'s values to the counters of every later
+        snapshot, as they stand when it is taken; kept across
+        :meth:`reset`."""
+        with self._lock:
+            self._gauges.append(read)
+
     def snapshot(self) -> dict:
         """Atomic copy: {"stages": {name: record-dict},
-        "overlap_fraction": ..., "counters": ...}."""
+        "overlap_fraction": ..., "counters": ...}; the gauges are read
+        after the copy."""
         with self._lock:
             stages = {n: r.as_dict() for n, r in self._stages.items()}
             busy, over = self._busy_any_s, self._overlap_s
             counters = dict(self._counters)
+            gauges = list(self._gauges)
+        for read in gauges:
+            counters.update(read())
         return {"stages": stages,
                 "overlap_fraction": over / busy if busy > 0 else 0.0,
                 "counters": counters}

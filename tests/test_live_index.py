@@ -149,6 +149,53 @@ def test_delta_encode_matches_builder(corpus, base_dir):
         live.encode_doc(corpus["doc_embs"][0], 0)        # empty doc
 
 
+def test_padded_encode_matches_per_length_in_one_program():
+    """The upsert encoder pads a passage to ``doc_maxlen`` and encodes it
+    in one program for every length: bitwise the codes of encoding the
+    passage at its own length, and no compile after the first length."""
+    import types
+
+    import jax.numpy as jnp
+
+    from repro.index import kmeans, residual
+    from repro.index.live import LiveIndexState, encode_padded
+    from repro.serving.pipeline import PipelineStats
+
+    rng = np.random.default_rng(11)
+
+    def unit(shape):
+        x = rng.standard_normal(shape).astype(np.float32)
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+    # a geometry no other test compiles the encoder for
+    dim, maxlen, nbits = 24, 37, 2
+    index = types.SimpleNamespace(
+        n_docs=5, doc_maxlen=maxlen, dim=dim, nbits=nbits,
+        store=types.SimpleNamespace(packed_dim=dim * nbits // 8),
+        n_centroids=19, centroids=unit((19, dim)),
+        bucket_cutoffs=np.array([-0.1, 0.0, 0.1], np.float32),
+        bucket_weights=np.array([-0.15, -0.05, 0.05, 0.15], np.float32))
+    live = LiveIndexState(index, types.SimpleNamespace(quantum=0.01,
+                                                       vocab=64))
+    embs = [unit((L, dim)) for L in (1, 13, maxlen)]
+    before = encode_padded._cache_size()
+    encoded = [live.encode_doc(embs[0])]
+    compiles = PipelineStats()        # counts from the second length on
+    encoded += [live.encode_doc(emb) for emb in embs[1:]]
+    assert encode_padded._cache_size() == before + 1
+    assert compiles.snapshot()["counters"].get("jax_compiles", 0) == 0
+    for emb, (cids, packed, n) in zip(embs, encoded):
+        L = len(emb)
+        ref_cids = np.asarray(kmeans.assign(jnp.asarray(emb),
+                                            jnp.asarray(index.centroids))[0])
+        ref_packed = np.asarray(residual.encode_residuals(
+            jnp.asarray(emb), jnp.asarray(ref_cids),
+            jnp.asarray(index.centroids),
+            jnp.asarray(index.bucket_cutoffs), nbits))
+        assert n == L and cids.dtype == np.int32 and packed.dtype == np.uint8
+        np.testing.assert_array_equal(cids, ref_cids)
+        np.testing.assert_array_equal(packed, ref_packed)
+
+
 def test_clean_live_serves_frozen_results(corpus, base_dir):
     retr = _make_unsharded(base_dir)
     q = _queries(corpus)
@@ -314,6 +361,20 @@ def test_query_during_compaction(corpus, base_dir, oracle):
             t.join(timeout=30)
     assert not errors, errors[0]
     _assert_parity(g, corpus, oracle, "compacted under readers")
+
+
+def test_auto_compactor_stop_returns_and_the_thread_ends(base_dir):
+    """``stop()`` returns once the compactor's thread has ended, whether
+    it is called once or again after the end."""
+    from repro.index.live import AutoCompactor
+    retr = _make_unsharded(base_dir)
+    retr.enable_live()
+    c = AutoCompactor(retr, every=10**6, interval_s=0.01)
+    c.start()
+    c.stop()
+    assert not c.is_alive()
+    c.stop()
+    assert not c.is_alive()
 
 
 # ---------------------------------------------------------------------------
