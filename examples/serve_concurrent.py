@@ -63,7 +63,8 @@ def main():
     ap.add_argument("--max-batch", type=int, default=1,
                     help="micro-batch size (1 = request-at-a-time)")
     ap.add_argument("--batch-timeout-ms", type=float, default=2.0,
-                    help="max wait to coalesce a micro-batch")
+                    help="max wait to coalesce a micro-batch while "
+                         "the engine is full")
     ap.add_argument("--latency-slo-ms", type=float, default=None,
                     help="adaptive micro-batching: shrink/grow the "
                          "effective batch cap to keep batch service "
@@ -113,8 +114,8 @@ def main():
         server.submit(r).result(timeout=120)
     if args.max_batch > 1:
         # warm the coalesced batch shapes, then measure capacity as burst
-        # throughput — a lone probe request would pay the full
-        # batch_timeout_ms coalescing window and understate capacity
+        # throughput — a lone probe request runs as a batch of one and
+        # would understate capacity
         for f in [server.submit(r) for r in reqs(2 * args.max_batch)]:
             f.result(timeout=120)
         n_cap = 4 * args.max_batch

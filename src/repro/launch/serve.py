@@ -194,7 +194,10 @@ def main():
                     help="minimum hedge budget, so cold EWMAs don't "
                          "hedge every op")
     ap.add_argument("--max-batch", type=int, default=1)
-    ap.add_argument("--batch-timeout-ms", type=float, default=2.0)
+    ap.add_argument("--batch-timeout-ms", type=float, default=2.0,
+                    help="how long a micro-batch stays open for arrivals "
+                         "while the engine is full; a batch the engine "
+                         "can start goes out at once")
     ap.add_argument("--latency-slo-ms", type=float, default=None,
                     help="enable adaptive micro-batch sizing against "
                          "this service-time SLO")

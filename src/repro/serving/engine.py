@@ -177,6 +177,18 @@ class ServeEngine:
             if stale is not None and stale.running:
                 stale.stop()
 
+    def can_admit(self, reqs: list[Request]) -> bool:
+        """Whether a micro-batch of ``reqs`` would start at once: always
+        on the synchronous path (the worker that formed the batch serves
+        it); pipelined, while every executor the batch feeds has fewer
+        than ``pipeline_depth`` batches admitted."""
+        if not self.pipelined:
+            return True
+        with self._plock:
+            pipes = [self._pipelines.get(m) for m in
+                     {self._effective_method(r) for r in reqs}]
+        return all(px is None or px.has_room() for px in pipes)
+
     def drain_pipelines(self, timeout: Optional[float] = None):
         for px in list(self._pipelines.values()):
             px.drain(timeout)

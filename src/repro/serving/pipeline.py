@@ -709,6 +709,12 @@ class PipelineExecutor:
             self._cond.notify_all()
 
     # -- lifecycle / introspection --------------------------------------
+    def has_room(self) -> bool:
+        """Fewer than ``depth`` batches admitted: a ``submit`` now would
+        not block on backpressure."""
+        with self._cond:
+            return self._inflight < self.depth
+
     def drain(self, timeout: Optional[float] = None) -> bool:
         """Wait until no batches are in flight."""
         with self._cond:

@@ -8,13 +8,17 @@ finding in benchmarks/bench_latency.py). Latency is measured from
 arrival (enqueue) to completion, so queueing delay is included.
 
 Micro-batching: with ``max_batch > 1`` a worker that pops a request
-keeps collecting queued requests for up to ``batch_timeout_ms`` (or
-until ``max_batch``) and serves the group through
-``ServeEngine.process_batch`` — one batched device dispatch per stage
-and deduplicated mmap gathers across co-batched queries. ``max_batch=1``
-preserves strict request-at-a-time behaviour. With ``latency_slo_ms``
-set, the effective batch cap adapts: an EWMA of batch service time
-shrinks it under SLO pressure and grows it back when there is headroom.
+also takes every request already queued behind it (up to the batch
+cap) and serves the group through ``ServeEngine.process_batch`` — one
+batched device dispatch per stage and deduplicated mmap gathers across
+co-batched queries. The batch goes out at once when the engine can
+start it; only while the engine is full (a pipelined engine with
+``pipeline_depth`` batches in flight) does it stay open for arrivals,
+for at most ``batch_timeout_ms``, and each such hold counts as
+``batch_holds``. ``max_batch=1`` preserves strict request-at-a-time
+behaviour. With ``latency_slo_ms`` set, the effective batch cap adapts:
+an EWMA of batch service time shrinks it under SLO pressure and grows
+it back when there is headroom.
 
 Pipelining: when the engine has ``pipeline_depth >= 2`` the worker no
 longer owns a batch end-to-end — it feeds the stage-graph pipeline
@@ -49,6 +53,10 @@ from repro.serving.admission import AdmissionController, RequestShed
 from repro.serving.context import ADMIT_DEGRADED, ADMIT_SHED
 from repro.serving.engine import Request, Result, ServeEngine
 from repro.serving.pipeline import PipelineStopped, span_ids
+
+# how often a batch held open while the engine is full looks again for
+# room between arrivals
+ROOM_POLL_S = 2e-4
 
 
 class RetrievalServer:
@@ -108,8 +116,12 @@ class RetrievalServer:
             self.workers.append(t)
 
     def _collect_batch(self, first):
-        """Coalesce queued requests behind ``first`` until the current
-        (possibly adapted) batch cap or ``batch_timeout_ms`` elapses."""
+        """Coalesce the requests already queued behind ``first``, up to
+        the current (possibly adapted) batch cap. While the engine
+        cannot start the batch, keep it open for arrivals until the
+        engine has room, the cap is reached or ``batch_timeout_ms``
+        has passed; the ``tcp:collect`` span carries that wait as
+        ``held_ms``."""
         with TraceAnnotation("tcp:collect") as span:
             batch = [first]
             # one locked read: _observe_latency resizes batch_cap under
@@ -118,18 +130,42 @@ class RetrievalServer:
             # that no longer exists
             with self._lock:
                 cap = self.batch_cap
-            deadline = time.perf_counter() + self.batch_timeout_ms / 1e3
             while len(batch) < cap:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
                 try:
-                    batch.append(self.queue.get(timeout=remaining))
+                    batch.append(self.queue.get_nowait())
                 except queue.Empty:
                     break
-            span.set_metadata(n=len(batch),
+            held_ms = 0.0
+            if len(batch) < cap and not self._engine_can_admit(batch):
+                stats = self._pipeline_stats()
+                if stats is not None:
+                    stats.counter("batch_holds")
+                t0 = time.perf_counter()
+                deadline = t0 + self.batch_timeout_ms / 1e3
+                while (len(batch) < cap
+                       and not self._engine_can_admit(batch)):
+                    remaining = deadline - time.perf_counter()
+                    if remaining <= 0:
+                        break
+                    try:
+                        batch.append(self.queue.get(
+                            timeout=min(remaining, ROOM_POLL_S)))
+                    except queue.Empty:
+                        pass
+                held_ms = (time.perf_counter() - t0) * 1e3
+            span.set_metadata(n=len(batch), held_ms=held_ms,
                               **span_ids([req.qid for req, _ in batch]))
         return batch
+
+    def _engine_can_admit(self, batch) -> bool:
+        """The engine's own admission check; an engine without one
+        counts as able to start the batch."""
+        can_admit = getattr(self.engine, "can_admit", None)
+        return can_admit is None or can_admit([req for req, _ in batch])
+
+    def _pipeline_stats(self):
+        return getattr(getattr(self.engine, "retriever", None),
+                       "pipeline_stats", None)
 
     def _observe_latency(self, results):
         """Adaptive micro-batch sizing: feed the served group's service
@@ -397,8 +433,7 @@ class RetrievalServer:
             fut.set_result(hit)
             return fut
         if self.admission is not None:
-            retr = getattr(engine, "retriever", None)
-            stats = getattr(retr, "pipeline_stats", None)
+            stats = self._pipeline_stats()
             snap = stats.snapshot()["stages"] if stats is not None else {}
             degradable = (req.method in ("hybrid", "rerank")
                           and req.term_ids is not None
@@ -447,8 +482,7 @@ class RetrievalServer:
         if hasattr(retr, "degraded_shards"):
             h["degraded_shards"] = retr.degraded_shards()
             h["allow_degraded"] = getattr(retr, "allow_degraded", False)
-        stats = getattr(getattr(self.engine, "retriever", None),
-                        "pipeline_stats", None)
+        stats = self._pipeline_stats()
         if stats is not None:
             snap = stats.snapshot()
             h["stages"] = {
@@ -460,7 +494,7 @@ class RetrievalServer:
                 for name, r in snap["stages"].items()}
             h["overlap_fraction"] = snap["overlap_fraction"]
             h["counters"] = {"jax_compiles": 0, "jax_compile_ms": 0.0,
-                             **snap.get("counters", {})}
+                             "batch_holds": 0, **snap.get("counters", {})}
         live = getattr(retr, "live", None)
         if live is not None:
             h["live"] = (retr.live_stats() if hasattr(retr, "live_stats")

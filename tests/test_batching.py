@@ -284,8 +284,9 @@ def test_server_microbatch_equals_sequential(stack, small_corpus):
 
     bat_srv = RetrievalServer(ServeEngine(retr), n_threads=1, max_batch=8,
                               batch_timeout_ms=25)
-    bat_srv.start()
+    # queued before start(): the worker takes them as two full batches
     futs = [bat_srv.submit(r) for r in _requests(small_corpus, n)]
+    bat_srv.start()
     bat = [f.result(timeout=60) for f in futs]
     assert bat_srv.health()["served"] == n
     bat_srv.stop()
@@ -301,11 +302,11 @@ def test_microbatch_respects_per_request_k(stack, small_corpus):
     _, _, retr = stack
     srv = RetrievalServer(ServeEngine(retr), n_threads=1, max_batch=4,
                           batch_timeout_ms=25)
-    srv.start()
     reqs = _requests(small_corpus, 4, k=10)
     for r, want in zip(reqs, (3, 10, 7, 1)):
         r.k = want
-    futs = [srv.submit(r) for r in reqs]
+    futs = [srv.submit(r) for r in reqs]     # one batch: queued first
+    srv.start()
     for r, fut in zip(reqs, futs):
         assert len(fut.result(timeout=60).pids) == r.k
     srv.stop()
@@ -349,10 +350,10 @@ def test_microbatch_isolates_poisoned_request(stack, small_corpus):
     _, _, retr = stack
     srv = RetrievalServer(ServeEngine(retr), n_threads=1, max_batch=4,
                           batch_timeout_ms=25)
-    srv.start()
     reqs = _requests(small_corpus, 4)
     reqs[2].method = "no-such-method"
-    futs = [srv.submit(r) for r in reqs]
+    futs = [srv.submit(r) for r in reqs]     # one batch: queued first
+    srv.start()
     with pytest.raises(ValueError):
         futs[2].result(timeout=60)
     for i in (0, 1, 3):

@@ -158,8 +158,9 @@ def test_server_pipelined_equals_sequential_mixed(stack, small_corpus):
 
     srv = RetrievalServer(ServeEngine(retr, pipeline_depth=2),
                           n_threads=1, max_batch=4, batch_timeout_ms=25)
-    srv.start()
+    # queued before start(): four mixed batches of four
     futs = [srv.submit(r) for r in _requests(small_corpus, n)]
+    srv.start()
     piped = [f.result(timeout=60) for f in futs]
     assert srv.health()["served"] == n
     srv.stop()
@@ -175,12 +176,12 @@ def test_pipelined_respects_per_request_k_and_alpha(stack, small_corpus):
     _, _, retr = stack
     srv = RetrievalServer(ServeEngine(retr, pipeline_depth=2),
                           n_threads=1, max_batch=4, batch_timeout_ms=25)
-    srv.start()
     reqs = _requests(small_corpus, 4, methods=("hybrid",))
     for r, want in zip(reqs, (3, 10, 7, 1)):
         r.k = want
     reqs[1].alpha = 0.9
-    futs = [srv.submit(r) for r in reqs]
+    futs = [srv.submit(r) for r in reqs]     # one batch: queued first
+    srv.start()
     for r, fut in zip(reqs, futs):
         assert len(fut.result(timeout=60).pids) == r.k
     expect = retr.search("hybrid", q_emb=reqs[1].q_emb,
@@ -197,10 +198,10 @@ def test_pipelined_isolates_poisoned_request(stack, small_corpus):
     _, _, retr = stack
     srv = RetrievalServer(ServeEngine(retr, pipeline_depth=2),
                           n_threads=1, max_batch=4, batch_timeout_ms=25)
-    srv.start()
     reqs = _requests(small_corpus, 4)
     reqs[2].method = "no-such-method"
-    futs = [srv.submit(r) for r in reqs]
+    futs = [srv.submit(r) for r in reqs]     # one batch: queued first
+    srv.start()
     with pytest.raises(ValueError):
         futs[2].result(timeout=60)
     for i in (0, 1, 3):
@@ -249,6 +250,42 @@ def test_bounded_pipeline_backpressures_producer():
             assert f.result(timeout=30).state["b_done"]
     finally:
         px.stop()
+
+
+def test_executor_room_decides_the_engines_admission(stack, small_corpus):
+    """``has_room``: fewer than ``depth`` batches admitted. A pipelined
+    engine can admit a micro-batch while every executor it feeds has
+    room; a synchronous engine always can."""
+    _, _, retr = stack
+    gate = threading.Event()
+
+    def wait(cb):
+        assert gate.wait(timeout=30)
+        return cb
+
+    px = PipelineExecutor(StagePlan(method="gated", stages=(
+        Stage("host_gather", HOST, wait),)), depth=2)
+    eng = ServeEngine(retr, pipeline_depth=2)
+    eng._pipelines["hybrid"] = px
+    hybrid, colbert = _requests(small_corpus, 2,
+                                methods=("hybrid", "colbert"))
+    try:
+        futs = [px.submit(_cb(0))]
+        assert px.has_room() and eng.can_admit([hybrid])
+        futs.append(px.submit(_cb(1)))
+        assert not px.has_room()
+        assert not eng.can_admit([hybrid])
+        assert not eng.can_admit([colbert, hybrid])
+        assert eng.can_admit([colbert])     # its executor is not built
+        assert ServeEngine(retr).can_admit([hybrid])
+        gate.set()
+        for f in futs:
+            f.result(timeout=30)
+        assert px.drain(timeout=30)
+        assert px.has_room() and eng.can_admit([hybrid])
+    finally:
+        gate.set()
+        eng.stop_pipelines()
 
 
 def test_depth2_overlaps_two_stage_plan_threaded():

@@ -3,6 +3,7 @@ failure replacement, drain, Poisson load generation, TCP front."""
 
 import threading
 import time
+import types
 
 import numpy as np
 import pytest
@@ -10,6 +11,7 @@ import pytest
 from repro.serving.engine import Request, ServeEngine
 from repro.serving.loadgen import (run_closed_loop, run_open_loop,
                                    run_poisson_load)
+from repro.serving.pipeline import PipelineStats
 from repro.serving.server import RetrievalServer, TCPRetrievalServer, tcp_query
 
 
@@ -271,6 +273,182 @@ def test_worker_reevaluates_pipelined_flag_mid_serve():
         assert eng.sync_calls == 7
     finally:
         srv.stop()
+
+
+class _RoomEngine(_FlippableEngine):
+    """Synchronous stub that says whether it can start a batch: it has
+    no room while ``room`` is clear. Records the qids of every batch it
+    serves, and the largest batch it was asked about while full."""
+
+    def __init__(self):
+        super().__init__()
+        self.room = threading.Event()
+        self.room.set()
+        self.batches = []
+        self.held_at = 0
+        self._cond = threading.Condition()
+        self.retriever = types.SimpleNamespace(
+            pipeline_stats=PipelineStats())
+
+    def block(self):
+        with self._cond:
+            self.room.clear()
+            self.held_at = 0
+
+    def can_admit(self, reqs):
+        with self._cond:
+            if self.room.is_set():
+                return True
+            self.held_at = max(self.held_at, len(reqs))
+            self._cond.notify_all()
+            return False
+
+    def wait_held(self, n, timeout=10.0):
+        """Until a batch of ``n`` requests was refused for want of room."""
+        with self._cond:
+            return self._cond.wait_for(lambda: self.held_at >= n, timeout)
+
+    def process(self, req):
+        self.batches.append([req.qid])
+        return super().process(req)
+
+    def process_batch(self, reqs):
+        self.batches.append([r.qid for r in reqs])
+        return super().process_batch(reqs)
+
+
+def _req(qid):
+    return Request(qid=qid, method="hybrid", q_emb=np.zeros(2), k=5)
+
+
+def _holds(srv):
+    return srv.health()["counters"]["batch_holds"]
+
+
+def test_lone_request_starts_without_waiting_out_the_batch_timeout():
+    """An engine with room takes a lone request at once: the timeout
+    bounds a hold while the engine is full, not every batch."""
+    eng = _RoomEngine()
+    srv = RetrievalServer(eng, n_threads=1, max_batch=8,
+                          batch_timeout_ms=500.0)
+    srv.start()
+    try:
+        res = srv.submit(_req(0)).result(timeout=10)
+        assert res.t_start - res.t_arrival < 0.25
+        assert eng.batches == [[0]]
+        assert _holds(srv) == 0
+    finally:
+        srv.stop()
+
+
+def test_requests_queued_before_start_share_a_batch_up_to_the_cap():
+    eng = _RoomEngine()
+    srv = RetrievalServer(eng, n_threads=1, max_batch=4,
+                          batch_timeout_ms=500.0)
+    futs = [srv.submit(_req(i)) for i in range(6)]
+    srv.start()
+    try:
+        for f in futs:
+            f.result(timeout=10)
+        assert eng.batches == [[0, 1, 2, 3], [4, 5]]
+        assert _holds(srv) == 0
+    finally:
+        srv.stop()
+
+
+@pytest.mark.parametrize("ends", ["room", "cap", "timeout"])
+def test_full_engine_holds_the_batch_open_for_arrivals(ends):
+    """While the engine has no room, arrivals join the open batch. It
+    goes out when room appears, when it reaches the cap, or once
+    ``batch_timeout_ms`` has passed."""
+    eng = _RoomEngine()
+    eng.block()
+    timeout_ms = 1000.0 if ends == "timeout" else 60_000.0
+    srv = RetrievalServer(eng, n_threads=1,
+                          max_batch=2 if ends == "cap" else 8,
+                          batch_timeout_ms=timeout_ms)
+    srv.start()
+    try:
+        first = srv.submit(_req(0))
+        assert eng.wait_held(1)
+        second = srv.submit(_req(1))
+        if ends != "cap":
+            assert eng.wait_held(2)          # joined the open batch
+        if ends == "room":
+            eng.room.set()
+        res = [first.result(timeout=10), second.result(timeout=10)]
+        assert eng.batches == [[0, 1]]
+        if ends == "timeout":
+            assert res[0].t_start - res[0].t_arrival >= timeout_ms / 1e3
+        assert _holds(srv) == 1
+    finally:
+        srv.stop()
+
+
+def test_batch_holds_counts_exactly_the_held_batches():
+    eng = _RoomEngine()
+    eng.block()
+    srv = RetrievalServer(eng, n_threads=1, max_batch=2,
+                          batch_timeout_ms=60_000.0)
+    # full from the queue: goes out unheld, room or not
+    futs = [srv.submit(_req(i)) for i in range(2)]
+    srv.start()
+    try:
+        for f in futs:
+            f.result(timeout=10)
+        held = srv.submit(_req(2))           # held until room appears
+        assert eng.wait_held(1)
+        eng.room.set()
+        held.result(timeout=10)
+        srv.submit(_req(3)).result(timeout=10)   # room: not held
+        eng.block()
+        futs = [srv.submit(_req(4))]         # held until the cap
+        assert eng.wait_held(1)
+        futs.append(srv.submit(_req(5)))
+        for f in futs:
+            f.result(timeout=10)
+        assert eng.batches == [[0, 1], [2], [3], [4, 5]]
+        assert _holds(srv) == 2
+    finally:
+        srv.stop()
+
+
+def test_workers_collecting_while_room_flips_lose_no_request():
+    """Eight workers form batches while another thread flips the
+    engine's room: every request is served once, in a batch within the
+    cap, and no more batches are counted held than were formed."""
+    import sys
+    eng = _RoomEngine()
+    srv = RetrievalServer(eng, n_threads=8, max_batch=4,
+                          batch_timeout_ms=1.0)
+    stop = threading.Event()
+
+    def flip():
+        while not stop.is_set():
+            if eng.room.is_set():
+                eng.block()
+            else:
+                eng.room.set()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    t = threading.Thread(target=flip, daemon=True)
+    try:
+        t.start()
+        srv.start()
+        futs = [srv.submit(_req(i)) for i in range(400)]
+        for f in futs:
+            f.result(timeout=60)
+    finally:
+        stop.set()
+        t.join(timeout=10)
+        srv.stop()
+        sys.setswitchinterval(interval)
+    assert not t.is_alive()
+    served = sorted(q for b in eng.batches for q in b)
+    assert served == list(range(400))
+    assert all(1 <= len(b) <= 4 for b in eng.batches)
+    assert _holds(srv) <= len(eng.batches)
 
 
 def test_saturation_raises_latency():

@@ -299,7 +299,11 @@ class _PacedEngine:
 
 
 def _drain(srv, n):
+    """Serve ``n`` requests queued while the worker is stopped, so the
+    batches it forms fill the cap as it stands."""
+    srv.stop()
     futs = [srv.submit(Request(qid=i, method="splade")) for i in range(n)]
+    srv.start()
     for f in futs:
         f.result(timeout=30)
 

@@ -3,8 +3,11 @@ plan stage of its micro-batch show up in a profile of the serving
 process, joined by request id; gather stages count the bytes they hand
 to the device; compiles are counted; and tracing leaves answers alone."""
 
+import json
 import pathlib
+import socket
 import threading
+import time
 
 import jax
 import numpy as np
@@ -17,7 +20,7 @@ from repro.index.builder import ColBERTIndex
 from repro.index.splade_index import build_splade_index
 from repro.serving.engine import ServeEngine
 from repro.serving.pipeline import PipelineStats
-from repro.serving.server import RetrievalServer, tcp_query
+from repro.serving.server import RetrievalServer
 
 SPAN_PREFIXES = ("tcp:", "stage:", "tail:")
 
@@ -42,6 +45,19 @@ def _payload(small_corpus, qid, method):
             "term_weights": small_corpus["q_term_weights"][qid].tolist()}
 
 
+def _query(port, payload) -> dict:
+    """One request on its own connection, read until the server closes
+    it: a client has its reply before the server's handler leaves the
+    request's spans, and closes the connection only after."""
+    with socket.create_connection(("127.0.0.1", port), timeout=60) as s:
+        s.sendall((json.dumps(payload) + "\n").encode())
+        s.shutdown(socket.SHUT_WR)
+        buf = b""
+        while chunk := s.recv(65536):
+            buf += chunk
+    return json.loads(buf)
+
+
 def _warm(retr, small_corpus, method, max_batch):
     """Compile every micro-batch size the server can form."""
     for b in range(1, max_batch + 1):
@@ -53,13 +69,16 @@ def _warm(retr, small_corpus, method, max_batch):
 
 class _Served:
     """A pipelined server (depth 2, batches of up to 4) on an ephemeral
-    TCP port."""
+    TCP port. With ``start=False`` its worker starts only once the
+    first :meth:`ask` has queued every request, so they form full
+    batches."""
 
-    def __init__(self, retr):
+    def __init__(self, retr, start: bool = True):
         self.engine = ServeEngine(retr, pipeline_depth=2)
         self.server = RetrievalServer(self.engine, max_batch=4,
                                       batch_timeout_ms=50.0)
-        self.server.start()
+        if start:
+            self.server.start()
         self.tcp = self.server.serve_tcp("127.0.0.1", 0)
         self.loop = threading.Thread(target=self.tcp.serve_forever,
                                      daemon=True)
@@ -71,7 +90,7 @@ class _Served:
         out = {}
 
         def one(p):
-            out[p["qid"]] = tcp_query("127.0.0.1", port, p)
+            out[p["qid"]] = _query(port, p)
         if not concurrent:
             for p in payloads:
                 one(p)
@@ -80,6 +99,12 @@ class _Served:
                    for p in payloads]
         for t in threads:
             t.start()
+        if not self.server.running:
+            deadline = time.monotonic() + 60
+            while self.server.queue.qsize() < len(payloads):
+                assert time.monotonic() < deadline, "requests not queued"
+                time.sleep(0.001)
+            self.server.start()
         for t in threads:
             t.join(timeout=60)
             assert not t.is_alive()
@@ -125,7 +150,7 @@ def _qids(stats) -> list:
 def test_spans_name_each_request_and_its_batch_stages(retr, small_corpus,
                                                       tmp_path, method):
     _warm(retr, small_corpus, method, 4)
-    served = _Served(retr)
+    served = _Served(retr, start=False)
     qids = list(range(8))
     try:
         replies, events = _traced(tmp_path, lambda: served.ask(
