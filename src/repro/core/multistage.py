@@ -310,48 +310,13 @@ class MultiStageRetriever:
     def search(self, method: str, q_emb=None, term_ids=None,
                term_weights=None, alpha: Optional[float] = None,
                k: Optional[int] = None):
-        """Returns (pids (k,), scores (k,)), -1 padded, descending."""
-        p = self.params
-        k = p.k if k is None else k
-        alpha = p.alpha if alpha is None else alpha
-
-        live = self.live
-        if live is not None and live.dirty:
-            # single queries route through the (gated, overlay-aware)
-            # batch path while the live state is dirty
-            pids, scores, _ = self.search_batch_ctx(
-                method,
-                q_embs=None if q_emb is None else [q_emb],
-                term_ids=None if term_ids is None else [term_ids],
-                term_weights=None if term_weights is None else [term_weights],
-                alpha=alpha, k=k)
-            return pids[0], scores[0]
-
-        if method == "colbert":
-            pids, scores, _ = self.searcher.search(q_emb, k=k)
-            return pids, scores
-
-        pids, s_scores = self.run_splade(term_ids, term_weights, p.first_k)
-        if method == "splade":
-            return pids[:k], s_scores[:k]
-
-        t0 = time.perf_counter()
-        c_scores = self.searcher.rerank(q_emb, pids)
-        mask = pids >= 0
-        if method == "rerank":
-            final = np.where(mask, c_scores, -np.inf)
-        elif method == "hybrid":
-            final = np.asarray(hybrid_mod.hybrid_scores(
-                jnp.asarray(s_scores), jnp.asarray(c_scores),
-                jnp.asarray(mask), alpha=alpha, normalizer=p.normalizer))
-        else:
-            raise ValueError(method)
-
-        order = np.argsort(-final, kind="stable")[:k]
-        out_pids = np.where(final[order] > -np.inf, pids[order], -1)
-        self.pipeline_stats.record("rest", time.perf_counter() - t0,
-                                   queries=1)
-        return out_pids, final[order]
+        """One query as a batch of one through :meth:`search_batch_ctx`.
+        Returns (pids (k,), scores (k,)), -1 padded, descending."""
+        wrap = (lambda x: None if x is None else [x])
+        pids, scores, _ = self.search_batch_ctx(
+            method, q_embs=wrap(q_emb), term_ids=wrap(term_ids),
+            term_weights=wrap(term_weights), alpha=alpha, k=k)
+        return pids[0], scores[0]
 
     # ------------------------------------------------------------------
     # stage-graph compilation (the serving pipeline's unit of execution)
@@ -710,7 +675,7 @@ class MultiStageRetriever:
         pids, scores, outcome = self.search_batch_ctx(
             method, q_embs=q_embs, term_ids=term_ids,
             term_weights=term_weights, alpha=alpha, k=k, ctxs=ctxs)
-        self._note_degraded(outcome.missing_shards)
+        self._degraded_tls.missing = outcome.missing_shards
         return pids, scores
 
     def search_batch_ctx(self, method, q_embs=None, term_ids=None,
@@ -727,20 +692,22 @@ class MultiStageRetriever:
         ``stage1_key`` for the candidate-gather cache.
 
         Runs the method's compiled :class:`StagePlan` synchronously —
-        the ``pipeline_depth=1`` path of the stage-graph executor.
+        the ``pipeline_depth=1`` path of the stage-graph executor. A
+        call whose outcome names missing shards counts once in the
+        ``degraded_batches`` counter, however many method groups it
+        held.
 
         With a live index attached the whole batch holds the compaction
-        gate's read side: queries proceed concurrently (and re-entrantly
-        — the mixed-batch path recurses) and only the atomic generation
-        swap excludes them.
+        gate's read side: queries proceed concurrently and only the
+        atomic generation swap excludes them.
         """
         gate = getattr(self.live, "gate", None)
-        if gate is None:
-            return self._search_batch_ctx_impl(method, q_embs, term_ids,
-                                               term_weights, alpha, k, ctxs)
-        with gate.read():
-            return self._search_batch_ctx_impl(method, q_embs, term_ids,
-                                               term_weights, alpha, k, ctxs)
+        with gate.read() if gate is not None else contextlib.nullcontext():
+            out = self._search_batch_ctx_impl(method, q_embs, term_ids,
+                                              term_weights, alpha, k, ctxs)
+        if out[2].missing_shards:
+            self.pipeline_stats.counter("degraded_batches")
+        return out
 
     def _search_batch_ctx_impl(self, method, q_embs, term_ids,
                                term_weights, alpha, k, ctxs):
@@ -1019,8 +986,8 @@ class MultiStageRetriever:
         return out_pids, out_scores, outcome
 
     # ------------------------------------------------------------------
-    # degraded-answer bookkeeping (sharded process groups only; the
-    # in-process backends never produce a ``missing_shards`` state)
+    # degraded-answer note of :meth:`search_batch` (sharded process
+    # groups only; the in-process backends never miss a shard)
     # ------------------------------------------------------------------
     @property
     def _degraded_tls(self):
@@ -1028,18 +995,6 @@ class MultiStageRetriever:
         # this __init__
         return self.__dict__.setdefault("_degraded_tls_obj",
                                         threading.local())
-
-    def _note_degraded(self, missing):
-        """Record (per serving thread) that the batch just searched was
-        answered without these shards; mixed-method batches union their
-        groups' notes."""
-        if not missing:
-            return
-        tls = self._degraded_tls
-        prior = getattr(tls, "missing", ())
-        if not prior:
-            self.pipeline_stats.counter("degraded_batches")
-        tls.missing = tuple(sorted(set(prior) | set(missing)))
 
     def last_missing_shards(self) -> tuple:
         """Missing-shard ids of this thread's last ``search_batch``
@@ -1081,10 +1036,9 @@ class MultiStageRetriever:
             idx = [i for i, mi in enumerate(methods) if mi == m]
             pick = (lambda seq: None if seq is None
                     else [seq[i] for i in idx])
-            pids, scores, out = self.search_batch_ctx(
-                m, q_embs=pick(q_embs), term_ids=pick(term_ids),
-                term_weights=pick(term_weights), alpha=alphas[idx], k=k,
-                ctxs=pick(ctxs))
+            pids, scores, out = self._search_batch_ctx_impl(
+                m, pick(q_embs), pick(term_ids), pick(term_weights),
+                alphas[idx], k, pick(ctxs))
             outcome = outcome.merge(out)
             self.scatter_group(out_pids, out_scores, idx, pids, scores)
         return out_pids, out_scores, outcome

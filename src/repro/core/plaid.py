@@ -7,11 +7,11 @@ Stages (Santhanam et al., CIKM'22):
      *no residual access*)
   4. residual decompression + exact MaxSim for the surviving ``ndocs``
 
-The class orchestrates jitted device stages with host gathers through
-the PagedStore (mmap tier), mirroring the paper's Python↔C++ split.
-``device_resident=True`` instead keeps the whole pool in device memory
-and exposes a single jitted ``serve_step`` — that path is what the
-multi-pod dry-run lowers, with the pool sharded over the 'model' axis.
+The class provides the batched stages — jitted device steps and host
+gathers through the PagedStore (mmap tier), mirroring the paper's
+Python↔C++ split — that ``MultiStageRetriever.compile_plan`` composes
+into each method's stage plan; a query runs as a batch of one.
+``device_resident=True`` instead keeps the whole pool in device memory.
 """
 
 from __future__ import annotations
@@ -28,10 +28,8 @@ from jax.profiler import TraceAnnotation
 from repro.common.utils import next_pow2 as _next_pow2
 from repro.core import hybrid as hybrid_mod
 from repro.index.builder import ColBERTIndex
-from repro.index.residual import unpack_codes
 from repro.kernels.decompress_maxsim.ops import decompress_maxsim_scores_batch
 from repro.kernels.fused_rerank.ops import fused_rerank_topk_batch
-from repro.models.colbert import maxsim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,16 +43,6 @@ class PlaidParams:
 # --------------------------------------------------------------------------
 # jitted stage kernels (shapes static per index)
 # --------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("nprobe",))
-def stage1_centroid_probe(q_emb, centroids, nprobe: int):
-    """q_emb (Lq, d), centroids (K, d) → (scores_c (Lq, K), top cids)."""
-    s = jnp.einsum("qd,kd->qk", q_emb, centroids,
-                   precision=jax.lax.Precision.HIGHEST,
-                   preferred_element_type=jnp.float32)
-    _, cids = jax.lax.top_k(s, nprobe)
-    return s, cids.astype(jnp.int32)
-
 
 @functools.partial(jax.jit, static_argnames=("cap",))
 def stage2_candidates(ivf_padded, cids, cap: int):
@@ -109,16 +97,6 @@ def fused_hybrid_tail(q, packed, cids, valid, cand_mask, centroids,
     final = hybrid_mod.hybrid_scores(s_scores, c, cand_mask[:b],
                                      alpha=alphas, normalizer=normalizer)
     return jax.lax.top_k(final, k)
-
-
-@functools.partial(jax.jit, static_argnames=("nbits",))
-def stage4_exact_score(q_emb, packed, cids, valid, centroids,
-                       bucket_weights, nbits: int):
-    """Decompress-and-MaxSim: packed (C, Ld, pd) uint8, cids (C, Ld)."""
-    codes = unpack_codes(packed, nbits)
-    emb = centroids[cids] + bucket_weights[codes.astype(jnp.int32)]
-    emb = emb * valid[..., None]
-    return maxsim(q_emb, emb, valid)
 
 
 # --------------------------------------------------------------------------
@@ -231,60 +209,11 @@ class PLAIDSearcher:
             self.dev_offsets = put(index.doc_offsets)
             self.dev_doclens = put(index.doclens)
 
-    # -- full PLAID (stages 1-4) ------------------------------------------
-    def search(self, q_emb: np.ndarray, k: Optional[int] = None):
-        """q_emb: (Lq, dim). Returns (pids (k,), scores (k,)) desc."""
-        p = self.params
-        k = p.k if k is None else k
-        q = jnp.asarray(q_emb)
-        scores_c, cids = stage1_centroid_probe(q, self.centroids, p.nprobe)
-        cand = stage2_candidates(self.ivf_padded, cids, p.candidate_cap)
-
-        cand_np = np.asarray(cand)
-        n_real = int((cand_np >= 0).sum())
-        if self.device_resident:
-            codes, _, valid = self._gather_device(cand)
-        else:
-            # codes-only gather: the approximate stage must not fault
-            # residual mmap pages (the paper's access-minimisation claim)
-            codes_np, valid_np = self.index.gather_doc_codes(cand_np)
-            codes, valid = jnp.asarray(codes_np), jnp.asarray(valid_np)
-
-        approx = stage3_approx_score(scores_c, codes, valid)
-        approx = jnp.where(cand >= 0, approx, -jnp.inf)
-        ndocs = min(p.ndocs, p.candidate_cap)
-        _, keep = jax.lax.top_k(approx, ndocs)
-        final_pids = cand[keep]
-
-        if self.device_resident:
-            f_codes, f_packed, f_valid = self._gather_device(final_pids)
-        else:
-            # Stage 4 is the only residual access — this is where the
-            # mmap pages get touched.
-            c_np, r_np, v_np = self.index.gather_doc_tokens(
-                np.asarray(final_pids))
-            f_codes, f_packed, f_valid = (jnp.asarray(c_np),
-                                          jnp.asarray(r_np),
-                                          jnp.asarray(v_np))
-
-        exact = stage4_exact_score(q, f_packed, f_codes, f_valid,
-                                   self.centroids, self.bucket_weights,
-                                   self.index.nbits)
-        exact = jnp.where(final_pids >= 0, exact, -jnp.inf)
-        k_eff = min(k, ndocs)
-        top_s, idx = jax.lax.top_k(exact, k_eff)
-        out_pids = np.full(k, -1, np.int64)
-        out_scores = np.full(k, -np.inf, np.float32)
-        out_pids[:k_eff] = np.asarray(final_pids[idx])
-        out_scores[:k_eff] = np.asarray(top_s)
-        return out_pids, out_scores, {"candidates": n_real}
-
-    # -- batched stage pieces (shared by search_batch and the pipeline) ----
+    # -- batched stage pieces ----------------------------------------------
     #
     # ``MultiStageRetriever.compile_plan`` wraps these into typed stages
-    # (plaid_probe / host_gather / device_score / fuse_topk) and
-    # ``search_batch`` composes the exact same functions in the exact
-    # same order, so the synchronous and pipelined paths cannot drift.
+    # (plaid_probe / host_gather / device_score / fuse_topk); the
+    # synchronous and pipelined paths both run that plan.
 
     def probe_batch(self, q_embs) -> dict:
         """Stages 1-2 (device): pad/stack ragged queries, probe
@@ -364,13 +293,6 @@ class PLAIDSearcher:
             self.bucket_weights, nbits=self.index.nbits, q_valid=q_valid)
         return jnp.where(jnp.asarray(pids_p) >= 0, scores, -jnp.inf)
 
-    def score_gathered_batch(self, q, q_valid, codes, packed, valid,
-                             pids_p, B: int):
-        """Rerank scoring over already-gathered tokens → host (B, C)
-        scores aligned with ``pids_p`` (rows beyond ``B`` dropped)."""
-        return np.asarray(self.score_gathered_lazy(
-            q, q_valid, codes, packed, valid, pids_p))[:B]
-
     # -- fused stage-4 tail (rerank_backend="fused") -----------------------
     def fused_topk_gathered(self, q, q_valid, codes, packed, valid,
                             cand_mask, k: int):
@@ -419,58 +341,6 @@ class PLAIDSearcher:
         out_pids[:, :kk][i_np < 0] = -1
         out_scores[:, :kk] = s_np
         return out_pids, out_scores
-
-    # -- batched full PLAID (stages 1-4 over a query micro-batch) ----------
-    def search_batch(self, q_embs, k: Optional[int] = None):
-        """Cross-query batched PLAID. q_embs: sequence of (Lq_i, dim)
-        arrays (ragged lengths fine) or a stacked (B, Lq, dim) array.
-        Returns (pids (B, k), scores (B, k), aux list) — per-query
-        results identical to :meth:`search` within fp tolerance.
-
-        Host candidate gathers are deduplicated across the batch, so
-        co-batched queries share mmap page touches; device stages run on
-        stacked (B, ...) inputs in a single dispatch each."""
-        k = self.params.k if k is None else k
-        st = self.probe_batch(q_embs)
-        cand_np = np.asarray(st["cand"])
-        n_real = (cand_np[:st["B"]] >= 0).sum(axis=1)
-        codes, valid = self.gather_codes_batch(st["cand"])
-        final_pids = self.approx_select_batch(st["scores_c"], codes, valid,
-                                              st["q_valid"], st["cand"])
-        f_codes, f_packed, f_valid = self.gather_tokens_batch(final_pids)
-        exact = self.exact_score_gathered(st["q"], st["q_valid"], f_codes,
-                                          f_packed, f_valid, final_pids)
-        out_pids, out_scores = self.finalize_topk(exact, final_pids,
-                                                  st["B"], k)
-        return out_pids, out_scores, [{"candidates": int(n)} for n in n_real]
-
-    # -- rerank-only (stage 4 on external candidates) ----------------------
-    def rerank(self, q_emb: np.ndarray, pids: np.ndarray):
-        """Exact MaxSim for given candidates (the paper's Rerank path).
-        pids: (C,) (−1 pad). Returns scores (C,) aligned with pids."""
-        q = jnp.asarray(q_emb)
-        if self.device_resident:
-            codes, packed, valid = self._gather_device(jnp.asarray(pids))
-        else:
-            c_np, r_np, v_np = self.index.gather_doc_tokens(np.asarray(pids))
-            codes, packed, valid = (jnp.asarray(c_np), jnp.asarray(r_np),
-                                    jnp.asarray(v_np))
-        scores = stage4_exact_score(q, packed, codes, valid, self.centroids,
-                                    self.bucket_weights, self.index.nbits)
-        return np.asarray(jnp.where(jnp.asarray(pids) >= 0, scores, -jnp.inf))
-
-    # -- batched rerank (stage 4 over a query micro-batch) -----------------
-    def rerank_batch(self, q_embs, pids: np.ndarray):
-        """Exact MaxSim for per-query candidate lists. q_embs: sequence of
-        (Lq_i, dim) arrays or stacked (B, Lq, dim); pids: (B, C) (−1 pad).
-        Returns scores (B, C) aligned with pids — one residual gather
-        (deduplicated across the batch) and one scoring dispatch."""
-        q, q_valid = pad_query_batch(q_embs)
-        pids = np.asarray(pids)
-        B, q, q_valid, pids_p = _pad_batch_rows(q, q_valid, pids)
-        codes, packed, valid = self.gather_tokens_batch(pids_p)
-        return self.score_gathered_batch(q, q_valid, codes, packed, valid,
-                                         pids_p, B)
 
     # -- deduplicated host gather (shared mmap pages per batch) ------------
     def _dedup_gather(self, pids_b: np.ndarray, *, codes_only: bool):

@@ -481,22 +481,11 @@ class ShardedRetriever(MultiStageRetriever):
     # ------------------------------------------------------------------
     # search entry points (n_shards == 1 delegates: bitwise-unsharded)
     # ------------------------------------------------------------------
-    def search(self, method, q_emb=None, term_ids=None, term_weights=None,
-               alpha=None, k=None):
-        if self.n_shards == 1:
-            return self.shards[0].search(
-                method, q_emb=q_emb, term_ids=term_ids,
-                term_weights=term_weights, alpha=alpha, k=k)
-        wrap = (lambda x: None if x is None else [x])
-        pids, scores = self.search_batch(
-            method, q_embs=wrap(q_emb), term_ids=wrap(term_ids),
-            term_weights=wrap(term_weights), alpha=alpha, k=k)
-        return pids[0], scores[0]
-
     def search_batch_ctx(self, method, q_embs=None, term_ids=None,
                          term_weights=None, alpha=None, k=None, ctxs=None):
-        # search_batch is inherited: it routes through here, so the
-        # one-shard delegation (and its ctx threading) lands once
+        # search and search_batch are inherited: they route through
+        # here, so the one-shard delegation (and its ctx threading)
+        # lands once
         if self.n_shards == 1:
             return self.shards[0].search_batch_ctx(
                 method, q_embs=q_embs, term_ids=term_ids,
@@ -1593,14 +1582,6 @@ class ProcessShardGroup(MultiStageRetriever):
     # ------------------------------------------------------------------
     # retriever protocol
     # ------------------------------------------------------------------
-    def search(self, method, q_emb=None, term_ids=None, term_weights=None,
-               alpha=None, k=None):
-        wrap = (lambda x: None if x is None else [x])
-        pids, scores = self.search_batch(
-            method, q_embs=wrap(q_emb), term_ids=wrap(term_ids),
-            term_weights=wrap(term_weights), alpha=alpha, k=k)
-        return pids[0], scores[0]
-
     def run_splade_batch(self, term_ids, term_weights, k=None,
                          backend=None, _record=True):
         """Group-wide stage 1 over the worker processes (benchmarks

@@ -91,18 +91,12 @@ class Result:
 
 class ServeEngine:
     def __init__(self, retriever: MultiStageRetriever,
-                 splade_backend: Optional[str] = None,
                  pipeline_depth: int = 1,
                  pipeline_workers: str = "single",
                  own_retriever: bool = False,
                  caches: Optional[CacheHierarchy] = None):
-        """``splade_backend`` (host | jax | pallas) switches the
-        retriever's stage-1 scorer at construction time — a convenience
-        for retrievers built elsewhere, NOT a per-engine scope: the
-        retriever owns the setting, so a later ``set_splade_backend``
-        (or another engine constructed over the same retriever) wins.
-        jax/pallas also pre-materialise the padded-postings device cache
-        so the first request doesn't pay the transfer.
+        """The retriever owns the stage-1 scorer
+        (``set_splade_backend``); the engine serves whatever it is set to.
 
         ``pipeline_depth``: 1 = synchronous batches (classic path);
         >= 2 = stage-graph pipelining with that many batches in flight
@@ -127,10 +121,6 @@ class ServeEngine:
         self.caches = caches
         if caches is not None and hasattr(retriever, "attach_caches"):
             retriever.attach_caches(caches)
-        if splade_backend is not None:
-            retriever.set_splade_backend(splade_backend)
-            if splade_backend != "host":
-                retriever.splade_device_cache()
         self.pipeline_depth = max(1, pipeline_depth)
         self.pipeline_workers = pipeline_workers
         self._pipelines: dict = {}
@@ -344,44 +334,18 @@ class ServeEngine:
         return degraded, reason
 
     # -- request execution -----------------------------------------------
-    def _missing_shards(self) -> tuple:
-        """Missing-shard note of the search this thread just ran
-        (degraded shard groups only; () everywhere else)."""
-        last = getattr(self.retriever, "last_missing_shards", None)
-        return tuple(last()) if last is not None else ()
-
     def process(self, req: Request) -> Result:
-        hit = self.cache_lookup(req)
-        if hit is not None:
-            return hit
-        t_start = time.perf_counter()
-        method = self._effective_method(req)
-        pids, scores = self.retriever.search(
-            method, q_emb=req.q_emb, term_ids=req.term_ids,
-            term_weights=req.term_weights, alpha=req.alpha, k=req.k)
-        missing = self._missing_shards()
-        t_done = time.perf_counter()
-        with self._lock:
-            self.served += 1
-        degraded, reason = self._degrade_info(req, missing)
-        res = Result(qid=req.qid, pids=pids, scores=scores,
-                     t_arrival=req.t_arrival, t_start=t_start,
-                     t_done=t_done, degraded=degraded,
-                     missing_shards=missing, degrade_reason=reason)
-        self._cache_store(req, res)
-        return res
+        """One request: :meth:`process_batch` of one."""
+        return self.process_batch([req])[0]
 
     def process_batch(self, reqs: list[Request]) -> list[Result]:
         """Score a micro-batch in one batched retriever call per method
-        group. Per-request results are identical (within fp tolerance) to
-        :meth:`process`; requests keep their own ``k``/``alpha``.
+        group, through the methods' compiled stage plans; a single
+        request is a batch of one. Requests keep their own
+        ``k``/``alpha``.
 
         Cache hits are peeled off first; only the misses run the
-        retriever. Falls back to sequential processing when the
-        retriever has no ``search_batch`` (e.g. test doubles)."""
-        if len(reqs) == 1 or not hasattr(self.retriever, "search_batch"):
-            return [self.process(r) for r in reqs]
-
+        retriever."""
         self._ensure_ctxs(reqs)
         results: list = [None] * len(reqs)
         miss_idx = []
@@ -394,27 +358,17 @@ class ServeEngine:
         if not miss_idx:
             return results
         miss = [reqs[i] for i in miss_idx]
-        if len(miss) == 1:
-            results[miss_idx[0]] = self.process(miss[0])
-            return results
 
         t_start = time.perf_counter()
-        methods = [self._effective_method(r) for r in miss]
-        k_max = max(r.k for r in miss)
         alphas = [r.alpha for r in miss]
-        kwargs = dict(
+        pids, scores, outcome = self.retriever.search_batch_ctx(
+            [self._effective_method(r) for r in miss],
             q_embs=[r.q_emb for r in miss],
             term_ids=[r.term_ids for r in miss],
             term_weights=[r.term_weights for r in miss],
             alpha=None if all(a is None for a in alphas) else alphas,
-            k=k_max)
-        if hasattr(self.retriever, "search_batch_ctx"):
-            pids, scores, outcome = self.retriever.search_batch_ctx(
-                methods, ctxs=[r.ctx for r in miss], **kwargs)
-            missing = outcome.missing_shards
-        else:
-            pids, scores = self.retriever.search_batch(methods, **kwargs)
-            missing = self._missing_shards()
+            k=max(r.k for r in miss), ctxs=[r.ctx for r in miss])
+        missing = outcome.missing_shards
         t_done = time.perf_counter()
         with self._lock:
             self.served += len(miss)

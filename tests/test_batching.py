@@ -1,6 +1,7 @@
-"""Cross-query batched execution: batched == sequential for every stage
-(kernels, PLAID, multi-stage methods, server micro-batcher), stage-3
-codes-only access, and shutdown semantics."""
+"""Cross-query batched execution: a batch row == the same query run
+alone (B = 1) for every stage (kernels, PLAID plan, multi-stage methods,
+server micro-batcher), stage-3 codes-only access, and shutdown
+semantics."""
 
 import jax
 import jax.numpy as jnp
@@ -99,28 +100,48 @@ def _ragged_queries(small_corpus, n):
             for i in range(n)]
 
 
+def _plaid_plan(retr, qs, k):
+    """PLAID (``colbert``) through the retriever's compiled stage plan
+    → (pids (B, k), scores (B, k), per-query aux)."""
+    cb = retr.compile_plan("colbert").run(
+        retr.build_batch("colbert", q_embs=qs, k=k))
+    return cb.pids, cb.scores, cb.state["aux"]
+
+
+def _score_candidates(searcher, qs, pids):
+    """Exact MaxSim of each query against its own candidate row (−1 pad
+    → -inf), through the residual gather and rerank scoring the plans
+    run → host (B, C)."""
+    q, q_valid = pad_query_batch(qs)
+    pids = np.asarray(pids)
+    codes, packed, valid = searcher.gather_tokens_batch(pids)
+    return np.asarray(searcher.score_gathered_lazy(q, q_valid, codes,
+                                                   packed, valid, pids))
+
+
 def test_search_batch_equals_sequential_ragged(stack, small_corpus):
-    _, searcher, _ = stack
+    _, _, retr = stack
     qs = _ragged_queries(small_corpus, 6)
-    bp, bs, aux = searcher.search_batch(qs, k=20)
+    bp, bs, aux = _plaid_plan(retr, qs, 20)
     for i, q in enumerate(qs):
-        sp, ss, a = searcher.search(q, k=20)
-        np.testing.assert_array_equal(bp[i], sp)
-        np.testing.assert_allclose(bs[i], ss, rtol=1e-4, atol=1e-4)
-        assert aux[i]["candidates"] == a["candidates"]
+        sp, ss, a = _plaid_plan(retr, [q], 20)
+        np.testing.assert_array_equal(bp[i], sp[0])
+        np.testing.assert_allclose(bs[i], ss[0], rtol=1e-4, atol=1e-4)
+        assert aux[i]["candidates"] == a[0]["candidates"]
 
 
-def test_search_batch_device_resident(built_index, small_corpus):
+def test_search_batch_device_resident(built_index, small_corpus, stack):
     index = ColBERTIndex(built_index, mode="ram")
     dev = PLAIDSearcher(index, PlaidParams(nprobe=8, candidate_cap=512,
                                            ndocs=128, k=50),
                         device_resident=True)
+    retr = MultiStageRetriever(stack[2].splade, dev)
     qs = _ragged_queries(small_corpus, 4)
-    bp, bs, _ = dev.search_batch(qs, k=15)
+    bp, bs, _ = _plaid_plan(retr, qs, 15)
     for i, q in enumerate(qs):
-        sp, ss, _ = dev.search(q, k=15)
-        np.testing.assert_array_equal(bp[i], sp)
-        np.testing.assert_allclose(bs[i], ss, rtol=1e-4, atol=1e-4)
+        sp, ss, _ = _plaid_plan(retr, [q], 15)
+        np.testing.assert_array_equal(bp[i], sp[0])
+        np.testing.assert_allclose(bs[i], ss[0], rtol=1e-4, atol=1e-4)
 
 
 def test_rerank_batch_equals_sequential(stack, small_corpus):
@@ -128,10 +149,11 @@ def test_rerank_batch_equals_sequential(stack, small_corpus):
     qs = _ragged_queries(small_corpus, 3)
     pids = np.stack([np.arange(30), np.arange(30) + 5,
                      np.concatenate([np.arange(20), np.full(10, -1)])])
-    batch = searcher.rerank_batch(qs, pids)
+    batch = _score_candidates(searcher, qs, pids)
     for i, q in enumerate(qs):
-        np.testing.assert_allclose(batch[i], searcher.rerank(q, pids[i]),
-                                   rtol=1e-3, atol=1e-3)
+        np.testing.assert_allclose(
+            batch[i], _score_candidates(searcher, [q], pids[i:i + 1])[0],
+            rtol=1e-3, atol=1e-3)
 
 
 @pytest.mark.parametrize("method", METHODS)
@@ -203,15 +225,15 @@ def test_mixed_batch_k_beyond_first_k(stack, small_corpus):
 def test_k_zero_and_explicit_k_honored(stack, small_corpus):
     """A k=0 request must not silently become k=params.k (regression for
     the falsy ``k or p.k`` default)."""
-    _, searcher, retr = stack
+    _, _, retr = stack
     q = small_corpus["q_embs"][0]
-    pids, scores, _ = searcher.search(q, k=0)
+    pids, scores = retr.search("colbert", q_emb=q, k=0)
     assert pids.shape == (0,) and scores.shape == (0,)
     sp, ss = retr.search("hybrid", q_emb=q,
                          term_ids=small_corpus["q_term_ids"][0],
                          term_weights=small_corpus["q_term_weights"][0], k=0)
     assert sp.shape == (0,)
-    bp, bs, _ = searcher.search_batch([q, q], k=0)
+    bp, bs = retr.search_batch("colbert", q_embs=[q, q], k=0)
     assert bp.shape == (2, 0)
 
 
@@ -233,30 +255,33 @@ def test_codes_only_gather_touches_zero_residual_pages(stack):
 
 def test_stage3_touches_zero_residual_pages(stack, small_corpus):
     """Full mmap search faults residual pages in stage 4 ONLY: exactly
-    one residual gather, covering the ``ndocs`` survivors — stages 1-3
-    stay codes-only."""
-    index, searcher, _ = stack
+    one residual gather, covering the survivors (at most ``ndocs``) —
+    stages 1-3 stay codes-only."""
+    index, searcher, retr = stack
     index.store.stats.reset()
-    searcher.search(small_corpus["q_embs"][0], k=10)
+    _, _, aux = _plaid_plan(retr, [small_corpus["q_embs"][0]], 10)
     st = index.store.stats
+    cand = aux[0]["candidates"]
     assert st.residual_gathers == 1
     assert st.residual_tokens_read == \
-        searcher.params.ndocs * index.doc_maxlen
-    # the codes-only stage-3 gather still happened (and was accounted)
+        min(searcher.params.ndocs, cand) * index.doc_maxlen
+    # the codes-only stage-3 gather still happened (and was accounted):
+    # one row of codes per candidate
     assert st.gathers == 2
-    assert st.tokens_read > st.residual_tokens_read
+    assert st.tokens_read - st.residual_tokens_read == \
+        cand * index.doc_maxlen
 
 
 def test_batched_gathers_share_pages(stack, small_corpus):
     """Duplicate queries co-batched touch the same residual pages once —
     the shared-page-touch benefit the micro-batcher exists for."""
-    index, searcher, _ = stack
+    index, _, retr = stack
     q = small_corpus["q_embs"][1]
     index.store.stats.reset()
-    searcher.search(q, k=10)
+    retr.search("colbert", q_emb=q, k=10)
     single = index.store.stats.pages_touched
     index.store.stats.reset()
-    searcher.search_batch([q, q, q], k=10)
+    retr.search_batch("colbert", q_embs=[q, q, q], k=10)
     batched = index.store.stats.pages_touched
     assert batched == single
 

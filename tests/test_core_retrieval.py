@@ -8,7 +8,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import hybrid as H
 from repro.core.multistage import MultiStageParams, MultiStageRetriever
-from repro.core.plaid import PLAIDSearcher, PlaidParams
+from repro.core.plaid import PLAIDSearcher, PlaidParams, pad_query_batch
 from repro.eval import metrics
 from repro.index.builder import ColBERTIndex
 from repro.index.residual import decode_embeddings
@@ -71,10 +71,25 @@ def test_hybrid_padding_is_neg_inf(alpha, seed):
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
-def searcher(built_index):
+def sidx(small_corpus):
+    return build_splade_index(small_corpus["doc_term_ids"],
+                              small_corpus["doc_term_weights"],
+                              small_corpus["cfg"].vocab,
+                              small_corpus["cfg"].n_docs)
+
+
+def _plaid(sidx, index, device_resident=False):
+    """A retriever whose ``colbert`` method is PLAID at the test
+    settings, served through its compiled stage plan."""
+    return MultiStageRetriever(sidx, PLAIDSearcher(
+        index, PlaidParams(nprobe=8, candidate_cap=512, ndocs=128, k=50),
+        device_resident=device_resident))
+
+
+@pytest.fixture(scope="module")
+def searcher(built_index, sidx):
     index = ColBERTIndex(built_index, mode="mmap")
-    return index, PLAIDSearcher(index, PlaidParams(
-        nprobe=8, candidate_cap=512, ndocs=128, k=50))
+    return index, _plaid(sidx, index)
 
 
 def brute_force(index: ColBERTIndex, q_emb):
@@ -89,13 +104,24 @@ def brute_force(index: ColBERTIndex, q_emb):
     return np.asarray(scores)
 
 
+def _rerank(searcher: PLAIDSearcher, q_emb, pids):
+    """Exact MaxSim for given candidates (the paper's Rerank path),
+    through the residual gather and scoring the rerank plans run.
+    pids: (C,) (−1 pad) → scores (C,) aligned with pids."""
+    q, q_valid = pad_query_batch([q_emb])
+    pids = np.asarray(pids)[None]
+    codes, packed, valid = searcher.gather_tokens_batch(pids)
+    return np.asarray(searcher.score_gathered_lazy(q, q_valid, codes,
+                                                   packed, valid, pids))[0]
+
+
 def test_plaid_agrees_with_brute_force(searcher, small_corpus):
-    index, s = searcher
+    index, retr = searcher
     hits = 0
     for qi in range(20):
         q = small_corpus["q_embs"][qi]
         exact = brute_force(index, q)
-        pids, scores, _ = s.search(q, k=10)
+        pids, scores = retr.search("colbert", q_emb=q, k=10)
         # top-1 of PLAID is within the true top-3 (approximation in
         # stages 1-3 can reorder near-ties)
         true_top3 = set(np.argsort(-exact)[:3].tolist())
@@ -104,45 +130,40 @@ def test_plaid_agrees_with_brute_force(searcher, small_corpus):
 
 
 def test_rerank_equals_exact_scoring(searcher, small_corpus):
-    index, s = searcher
+    index, retr = searcher
     q = small_corpus["q_embs"][0]
     pids = np.arange(40)
     exact = brute_force(index, q)[:40]
-    got = s.rerank(q, pids)
+    got = _rerank(retr.searcher, q, pids)
     np.testing.assert_allclose(got, exact, rtol=1e-3, atol=1e-3)
 
 
 def test_rerank_respects_padding(searcher, small_corpus):
-    _, s = searcher
+    _, retr = searcher
     q = small_corpus["q_embs"][1]
     pids = np.array([3, -1, 7, -1])
-    out = s.rerank(q, pids)
+    out = _rerank(retr.searcher, q, pids)
     assert np.isinf(out[1]) and np.isinf(out[3])
     assert np.isfinite(out[0]) and np.isfinite(out[2])
 
 
-def test_mmap_and_ram_modes_identical(built_index, small_corpus):
+def test_mmap_and_ram_modes_identical(built_index, small_corpus, sidx):
     res = {}
     for mode in ("ram", "mmap"):
-        index = ColBERTIndex(built_index, mode=mode)
-        s = PLAIDSearcher(index, PlaidParams(nprobe=8, candidate_cap=512,
-                                             ndocs=128, k=20))
-        pids, scores, _ = s.search(small_corpus["q_embs"][2], k=20)
-        res[mode] = (pids, scores)
+        retr = _plaid(sidx, ColBERTIndex(built_index, mode=mode))
+        res[mode] = retr.search("colbert", q_emb=small_corpus["q_embs"][2],
+                                k=20)
     np.testing.assert_array_equal(res["ram"][0], res["mmap"][0])
     np.testing.assert_allclose(res["ram"][1], res["mmap"][1], rtol=1e-6)
 
 
-def test_device_resident_matches_host_path(built_index, small_corpus):
+def test_device_resident_matches_host_path(built_index, small_corpus, sidx):
     index = ColBERTIndex(built_index, mode="ram")
-    host = PLAIDSearcher(index, PlaidParams(nprobe=8, candidate_cap=512,
-                                            ndocs=128, k=20))
-    dev = PLAIDSearcher(index, PlaidParams(nprobe=8, candidate_cap=512,
-                                           ndocs=128, k=20),
-                        device_resident=True)
+    host = _plaid(sidx, index)
+    dev = _plaid(sidx, index, device_resident=True)
     q = small_corpus["q_embs"][3]
-    p1, s1, _ = host.search(q, k=20)
-    p2, s2, _ = dev.search(q, k=20)
+    p1, s1 = host.search("colbert", q_emb=q, k=20)
+    p2, s2 = dev.search("colbert", q_emb=q, k=20)
     np.testing.assert_array_equal(p1, p2)
     np.testing.assert_allclose(s1, s2, rtol=1e-4, atol=1e-4)
 
@@ -153,8 +174,14 @@ def test_device_resident_matches_host_path(built_index, small_corpus):
 
 def test_rerank_touches_fewer_pages_than_full_plaid(built_index,
                                                     small_corpus):
+    """The paper's regime: PLAID's candidate union is larger than
+    SPLADE's ``first_k``, so PLAID reads codes for the whole union and
+    residuals for its survivors while rerank reads ``first_k`` rows. On
+    this corpus that takes ``nprobe`` 16 (a union of ~100 passages a
+    query; at 8 it is ~44, below ``first_k`` 50). Padded candidate
+    slots are never read, so only real rows count."""
     index = ColBERTIndex(built_index, mode="mmap")
-    s = PLAIDSearcher(index, PlaidParams(nprobe=8, candidate_cap=512,
+    s = PLAIDSearcher(index, PlaidParams(nprobe=16, candidate_cap=512,
                                          ndocs=256, k=20))
     sidx = build_splade_index(small_corpus["doc_term_ids"],
                               small_corpus["doc_term_weights"],
@@ -166,6 +193,8 @@ def test_rerank_touches_fewer_pages_than_full_plaid(built_index,
     for qi in range(10):
         retr.search("colbert", q_emb=small_corpus["q_embs"][qi])
     full_tokens = index.store.stats.tokens_read
+    union_tokens = full_tokens - index.store.stats.residual_tokens_read
+    assert union_tokens > 10 * retr.params.first_k * index.doc_maxlen
 
     index.store.stats.reset()
     for qi in range(10):

@@ -508,8 +508,10 @@ def test_all_replicas_down_degrades_then_heals(remote_fleet, split,
         ref0 = shard0.search_batch("splade", **kw, k=10)
         np.testing.assert_array_equal(ref0[0], out[0])
         np.testing.assert_array_equal(ref0[1], out[1])
+        # one degraded call counts once: search_batch adds no count of
+        # its own to search_batch_ctx's
         assert g.pipeline_stats.snapshot()["counters"][
-            "degraded_batches"] >= 1
+            "degraded_batches"] == 1
         # recovery: restart both workers → full bitwise parity again
         _restore(remote_fleet)
         deadline = time.monotonic() + 60
@@ -615,6 +617,10 @@ def test_degraded_flags_through_engine_server_and_tcp(split, thread_ref,
         part = srv.submit(req(0)).result(timeout=120)
         assert part.degraded and part.missing_shards == (1,)
         assert (part.pids[part.pids >= 0] < bounds[1]).all()
+        # a request served alone (a batch of one) counts as a degraded
+        # batch
+        assert g.pipeline_stats.snapshot()["counters"][
+            "degraded_batches"] == 1
 
         # the degraded request reaped the corpse and has not respawned
         # it yet (heal-on-next-use), so health names the missing shard
@@ -637,6 +643,8 @@ def test_degraded_flags_through_engine_server_and_tcp(split, thread_ref,
                 small_corpus["q_term_weights"][7].tolist(), "k": 10})
         assert "error" not in deg
         assert deg["degraded"] is True and deg["missing_shards"] == [1]
+        assert g.pipeline_stats.snapshot()["counters"][
+            "degraded_batches"] == 2
         ok = tcp_query("127.0.0.1", srv.tcp_port, {
             "qid": 8, "method": "splade",
             "term_ids": small_corpus["q_term_ids"][8].tolist(),

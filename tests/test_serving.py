@@ -8,6 +8,7 @@ import types
 import numpy as np
 import pytest
 
+from repro.serving.context import BatchOutcome
 from repro.serving.engine import Request, ServeEngine
 from repro.serving.loadgen import (run_closed_loop, run_open_loop,
                                    run_poisson_load)
@@ -23,14 +24,17 @@ class FakeRetriever:
         self.fail_qids = set(fail_qids)
         self.calls = 0
 
-    def search(self, method, q_emb=None, term_ids=None, term_weights=None,
-               alpha=None, k=10):
+    def search_batch_ctx(self, method, q_embs=None, term_ids=None,
+                         term_weights=None, alpha=None, k=10, ctxs=None):
         self.calls += 1
         if self.service_s:
             time.sleep(self.service_s)
-        if q_emb is not None and int(q_emb[0]) in self.fail_qids:
+        if any(q is not None and int(q[0]) in self.fail_qids
+               for q in q_embs):
             raise RuntimeError("injected failure")
-        return np.arange(k), np.linspace(1, 0, k)
+        n = len(q_embs)
+        return (np.tile(np.arange(k), (n, 1)),
+                np.tile(np.linspace(1, 0, k), (n, 1)), BatchOutcome())
 
 
 def make_server(n_threads=2, **kw):

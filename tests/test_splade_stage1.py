@@ -252,12 +252,19 @@ def test_search_batch_host_backend_is_single_pass(retr, small_corpus):
     assert retr.stage_stats["stage1_queries"] == B
 
 
-def test_engine_backend_override(retr):
+def test_engine_backend_override(retr, small_corpus):
+    """The retriever owns the stage-1 backend: switched to ``jax``, an
+    engine over it scores stage 1 from the padded-postings device
+    cache."""
     assert retr.splade_backend == "host"
-    ServeEngine(retr, splade_backend="jax")
+    retr.set_splade_backend("jax")
+    retr._splade_device = None
     try:
         assert retr.splade_backend == "jax"
-        assert retr._splade_device is not None    # cache pre-materialised
+        ServeEngine(retr).process(Request(
+            qid=0, method="splade", term_ids=small_corpus["q_term_ids"][0],
+            term_weights=small_corpus["q_term_weights"][0], k=5))
+        assert retr._splade_device is not None    # cache materialised
     finally:
         retr.set_splade_backend("host")
 
