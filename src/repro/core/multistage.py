@@ -864,8 +864,11 @@ class MultiStageRetriever:
         independent, so the stitched matrix is bitwise what one dispatch
         over a unified index would produce. Each origin's span carries
         its dispatch's shapes (``B``, ``C``, ``Ld``, ``pd``, ``Lq``,
-        ``dim``, ``K``, ``nbits``); ``delta_rows_scored`` counts the
-        real queries' delta candidates."""
+        ``dim``, ``K``, ``nbits``): the delta's ``C`` is its own width
+        (``LiveIndexState.delta_width``). ``delta_rows_scored`` counts
+        the real queries' delta candidates, ``live_delta_narrow`` and
+        ``live_delta_full`` the delta dispatches below and at the full
+        width."""
         pids_p = np.asarray(pids_p)
         delta_mask = pids_p >= live.base_n
         shapes = {"B": pids_p.shape[0], "C": pids_p.shape[1],
@@ -874,18 +877,26 @@ class MultiStageRetriever:
                   "K": live.n_centroids, "nbits": live.nbits,
                   **span_ids(qids)}
         with self._live_span("stage:live_base_exact", b, **shapes):
+            # the query and the gathered host arrays go to the jitted
+            # call as they are: it copies them in less time than a
+            # jnp.asarray apiece, and each dispatch's span holds its own
+            # copies
             base_pids = np.where(delta_mask, -1, pids_p)
             codes, packed, valid = self.searcher._dedup_gather(
                 base_pids, codes_only=False)
             base_scores = np.asarray(self.searcher.score_gathered_lazy(
-                jnp.asarray(q), jnp.asarray(q_valid), jnp.asarray(codes),
-                jnp.asarray(packed), jnp.asarray(valid), base_pids))
+                q, q_valid, codes, packed, valid, base_pids))
         if delta_mask.any():
+            d_pids = np.where(delta_mask, pids_p, -1)
+            width = live.delta_width(d_pids)
             self.pipeline_stats.counter("delta_rows_scored",
                                         int(delta_mask[:b].sum()))
-            with self._live_span("stage:live_delta", b, **shapes):
-                d_scores = live.exact_scores(
-                    q, q_valid, np.where(delta_mask, pids_p, -1))
+            self.pipeline_stats.counter(
+                "live_delta_narrow" if width < shapes["C"]
+                else "live_delta_full")
+            with self._live_span("stage:live_delta", b,
+                                 **dict(shapes, C=width)):
+                d_scores = live.exact_scores(q, q_valid, d_pids, width)
             return np.where(delta_mask, d_scores,
                             base_scores).astype(np.float32)
         return base_scores.astype(np.float32)

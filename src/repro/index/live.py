@@ -44,6 +44,14 @@ from repro.index import ivf as ivf_mod
 from repro.index import kmeans, residual
 from repro.kernels.decompress_maxsim.ops import decompress_maxsim_scores_batch
 
+# Candidate width of the exact delta dispatch when every row's delta
+# candidates fit in it: a served hybrid row holds a few delta passages
+# among its 200 candidates, so the dispatch scores one narrow kernel
+# tile rather than the batch's full width. Set from the largest per-row
+# delta count measured on the chip, rounded up to a power of two
+# (PERF.md §6).
+DELTA_WIDTH = 8
+
 
 @functools.partial(jax.jit, static_argnames=("nbits",))
 def encode_padded(emb, valid, centroids, cutoffs, nbits: int):
@@ -395,20 +403,40 @@ class LiveIndexState:
         return np.where(pids_mat >= 0, np.asarray(approx), -np.inf).astype(
             np.float32)
 
-    def exact_scores(self, q, q_valid, pids_mat) -> np.ndarray:
-        """Exact decompress+MaxSim for delta candidates, -inf at -1
-        slots — same kernel + argument shapes as
-        ``PLAIDSearcher.score_gathered_lazy`` so per-candidate scores
-        are bitwise what the frozen path computes."""
+    def delta_width(self, pids_mat) -> int:
+        """Candidate width of the exact delta dispatch for a (B, C)
+        matrix of delta pids (-1 elsewhere): ``DELTA_WIDTH`` where every
+        row's delta pids fit in it and it is narrower than C, else C."""
         pids_mat = np.asarray(pids_mat)
-        codes, packed, valid = self._gather_delta(pids_mat, with_packed=True)
+        C = pids_mat.shape[1]
+        most = int((pids_mat >= 0).sum(axis=1).max(initial=0))
+        return DELTA_WIDTH if most <= DELTA_WIDTH < C else C
+
+    def exact_scores(self, q, q_valid, pids_mat, width=None) -> np.ndarray:
+        """Exact decompress+MaxSim for the delta pids of a (B, C) matrix,
+        -inf at -1 slots. Each row's delta pids are packed to the left
+        of a (B, ``width``) matrix, gathered and scored there, and their
+        scores scattered back to their columns; ``width`` is the caller's
+        :meth:`delta_width` of ``pids_mat`` (computed here when None).
+        The kernel and argument layout are
+        ``PLAIDSearcher.score_gathered_lazy``'s and a candidate's score
+        depends on no other column, so per-candidate scores are bitwise
+        what the frozen path computes."""
+        pids_mat = np.asarray(pids_mat)
+        W = self.delta_width(pids_mat) if width is None else width
+        # a stable sort puts each row's delta columns first, in order
+        cols = np.argsort(pids_mat < 0, axis=1, kind="stable")[:, :W]
+        narrow = np.take_along_axis(pids_mat, cols, axis=1)
+        codes, packed, valid = self._gather_delta(narrow, with_packed=True)
+        # host arrays go to the jitted call as they are: it copies them
+        # to the device in less time than a jnp.asarray apiece
         scores = decompress_maxsim_scores_batch(
-            jnp.asarray(q), jnp.asarray(packed),
-            jnp.asarray(codes).astype(jnp.int32), jnp.asarray(valid),
-            self._centroids_j, self._bweights_j, nbits=self.nbits,
-            q_valid=jnp.asarray(q_valid))
-        return np.where(pids_mat >= 0, np.asarray(scores), -np.inf).astype(
-            np.float32)
+            q, packed, codes, valid, self._centroids_j, self._bweights_j,
+            nbits=self.nbits, q_valid=q_valid)
+        out = np.full(pids_mat.shape, -np.inf, np.float32)
+        np.put_along_axis(out, cols, np.where(narrow >= 0, np.asarray(
+            scores), -np.inf), axis=1)
+        return out
 
     # -- compaction --------------------------------------------------------
     def snapshot_delta(self) -> int:

@@ -196,6 +196,74 @@ def test_padded_encode_matches_per_length_in_one_program():
         np.testing.assert_array_equal(packed, ref_packed)
 
 
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+def test_delta_exact_scores_narrow_match_full_width(corpus, base_dir, impl,
+                                                    monkeypatch):
+    """``exact_scores`` packs each row's delta pids into a narrow
+    dispatch and scatters the scores back: bitwise the full-width
+    dispatch's score in every delta column, -inf elsewhere — for rows
+    with no delta pid, a pid shared by several rows, padded batch rows,
+    and (at full width) a row with more delta pids than the narrow
+    width holds."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from repro.core.plaid import _pad_batch_rows, pad_query_batch_host
+    from repro.index import live as live_mod
+    from repro.kernels.decompress_maxsim.ops import (
+        decompress_maxsim_scores_batch,
+    )
+
+    W = live_mod.DELTA_WIDTH
+    C = 2 * W + 8
+    live = _make_unsharded(base_dir).enable_live()
+    gp = [live.upsert(corpus["doc_embs"][j], corpus["doc_term_ids"][j],
+                      corpus["doc_term_weights"][j], corpus["doc_lens"][j])
+          for j in range(W + 4)]
+    kernel = functools.partial(decompress_maxsim_scores_batch, impl=impl)
+    widths = []
+
+    def spy(q, packed, *args, **kw):
+        widths.append(packed.shape[1])
+        return kernel(q, packed, *args, **kw)
+    monkeypatch.setattr(live_mod, "decompress_maxsim_scores_batch", spy)
+
+    def full_width(q, q_valid, pids):
+        codes, packed, valid = live._gather_delta(pids, with_packed=True)
+        s = kernel(jnp.asarray(q), jnp.asarray(packed),
+                   jnp.asarray(codes).astype(jnp.int32), jnp.asarray(valid),
+                   live._centroids_j, live._bweights_j, nbits=live.nbits,
+                   q_valid=jnp.asarray(q_valid))
+        return np.where(pids >= 0, np.asarray(s), -np.inf).astype(np.float32)
+
+    def rows(*cols_pids):
+        m = np.full((len(cols_pids), C), -1, np.int64)
+        for r, pairs in enumerate(cols_pids):
+            for c, p in pairs:
+                m[r, c] = p
+        return m
+
+    shared = gp[3]
+    narrow = rows([],                                      # no delta pid
+                  [(0, gp[0]), (7, shared), (C - 1, gp[5])],
+                  [(2, shared), (W, gp[1])])               # padded: B 3 → 4
+    over = rows([(2 * c + 1, p) for c, p in enumerate(gp[:W + 1])],
+                [(5, shared)])
+    for pids, width in ((narrow, W), (over, C)):
+        q, q_valid = pad_query_batch_host(
+            list(corpus["q_embs"][:pids.shape[0]]))
+        B, q, q_valid, pids_p = _pad_batch_rows(q, q_valid, pids)
+        assert B == pids.shape[0] and pids_p.shape[0] in (2, 4)
+        widths.clear()
+        got = live.exact_scores(q, q_valid, pids_p)
+        assert widths == [width]
+        assert live.delta_width(pids_p) == width
+        np.testing.assert_array_equal(got, full_width(q, q_valid, pids_p))
+        assert np.isneginf(got[pids_p < 0]).all()
+        assert np.isfinite(got[pids_p >= 0]).all()
+
+
 def test_clean_live_serves_frozen_results(corpus, base_dir):
     retr = _make_unsharded(base_dir)
     q = _queries(corpus)

@@ -13,6 +13,7 @@ from jax.profiler import ProfileData
 from repro.core.multistage import MultiStageParams, MultiStageRetriever
 from repro.core.plaid import PLAIDSearcher, PlaidParams
 from repro.index.builder import ColBERTIndex, build_colbert_index
+from repro.index.live import DELTA_WIDTH
 from repro.index.splade_index import SpladeIndex, build_splade_index
 from repro.serving.context import RequestContext
 from repro.serving.engine import ServeEngine
@@ -100,10 +101,12 @@ def test_overlay_and_write_spans_records_and_counters(retr, small_corpus,
     overlay = [s for n, s in events if n == "stage:live_overlay"]
     assert [int(x) for x in str(overlay[0]["qids"]).split()] == [
         100, 101, 102, 103]
-    for name in ("stage:live_base_exact", "stage:live_delta"):
+    # the delta dispatch scores its few candidates at the narrow width
+    for name, width in (("stage:live_base_exact", 32),
+                        ("stage:live_delta", DELTA_WIDTH)):
         s = dict(events)[name]
         assert {k: s[k] for k in SHAPES} == {
-            "B": 4, "C": 32, "Ld": retr.live.doc_maxlen,
+            "B": 4, "C": width, "Ld": retr.live.doc_maxlen,
             "pd": retr.live.packed_dim, "Lq": 8,     # 6 tokens padded
             "dim": c["cfg"].dim, "K": 64, "nbits": 2}
 
@@ -117,6 +120,7 @@ def test_overlay_and_write_spans_records_and_counters(retr, small_corpus,
         == (HOLD, 1, 1)
     # the batch's queries carry the upserted passages' own terms
     assert HOLD <= n["delta_rows_scored"] <= HOLD * 32
+    assert n["live_delta_narrow"] == 1 and "live_delta_full" not in n
     # gauges: the delta went into the base; the tombstone stays
     assert (n["delta_docs"], n["tombstones"]) == (0, 1)
 
@@ -164,3 +168,73 @@ def test_frozen_path_runs_no_live_span_or_counter(retr, small_corpus,
     assert snap["counters"].get("delta_docs", 0) == 0
     assert not [k for k in snap["counters"]
                 if k.startswith("live_") or k == "delta_rows_scored"]
+    assert not {"live_delta_narrow", "live_delta_full"} & set(
+        snap["counters"])
+
+
+def _serve(retr, rows_per_batch, q):
+    for rows in rows_per_batch:
+        retr.search_batch("hybrid", k=10, **{
+            key: [v[r] for r in rows] for key, v in q.items()})
+
+
+def test_delta_span_width_and_counters_narrow_then_full(retr, small_corpus,
+                                                        base_n, tmp_path):
+    """``stage:live_delta`` carries the width of the dispatch it makes:
+    the narrow width while every row's delta candidates fit in it, the
+    full candidate width once a row holds more; ``live_delta_narrow``
+    and ``live_delta_full`` count each, in snapshots and in
+    ``health()``."""
+    c = small_corpus
+    retr.enable_live()
+    retr.live_upsert(c["doc_embs"][base_n], c["doc_term_ids"][base_n],
+                     c["doc_term_weights"][base_n], c["doc_lens"][base_n])
+    q = _queries(c, [base_n])
+    events = _traced(tmp_path / "narrow", lambda: _serve(retr, [[0]], q))
+    # more copies of the passage than the narrow width holds: its query
+    # now has them all among its 32 candidates
+    for _ in range(DELTA_WIDTH):
+        retr.live_upsert(c["doc_embs"][base_n], c["doc_term_ids"][base_n],
+                         c["doc_term_weights"][base_n],
+                         c["doc_lens"][base_n])
+    events += _traced(tmp_path / "full", lambda: _serve(retr, [[0]], q))
+    widths = [s["C"] for n, s in events if n == "stage:live_delta"]
+    assert widths == [DELTA_WIDTH, 32]
+    n = retr.pipeline_stats.snapshot()["counters"]
+    assert (n["live_delta_narrow"], n["live_delta_full"]) == (1, 1)
+    assert n["delta_rows_scored"] == 1 + DELTA_WIDTH + 1
+    engine = ServeEngine(retr)
+    try:
+        h = RetrievalServer(engine).health()["counters"]
+    finally:
+        engine.close()
+    assert (h["live_delta_narrow"], h["live_delta_full"]) == (1, 1)
+
+
+def test_warm_overlay_serves_every_batch_size_without_compiling(
+        retr, small_corpus, base_n):
+    """Warm-up as the benchmark's (the hybrid overlay at B = 1..8 on the
+    upserted passages' terms) compiles every program the dirty overlay
+    dispatches: narrow delta batches at every B, and full-width ones
+    (the base dispatch's program), compile nothing more."""
+    c = small_corpus
+    retr.enable_live()
+    rows = list(range(base_n, base_n + HOLD))
+    for j in rows:
+        retr.live_upsert(c["doc_embs"][j], c["doc_term_ids"][j],
+                         c["doc_term_weights"][j], c["doc_lens"][j])
+    q = _queries(c, [rows[i % HOLD] for i in range(8)])
+    _serve(retr, [range(b) for b in range(1, 9)], q)
+    before = retr.pipeline_stats.snapshot()["counters"]
+    # other passages' terms, then a batch whose first row holds more
+    # delta candidates than the narrow width (copies of one passage)
+    _serve(retr, [[(i + 1) % 8 for i in range(b)] for b in range(1, 9)], q)
+    for _ in range(DELTA_WIDTH):
+        retr.live_upsert(c["doc_embs"][rows[0]], c["doc_term_ids"][rows[0]],
+                         c["doc_term_weights"][rows[0]],
+                         c["doc_lens"][rows[0]])
+    _serve(retr, [range(b) for b in range(1, 9)], q)
+    after = retr.pipeline_stats.snapshot()["counters"]
+    assert after.get("jax_compiles", 0) == before.get("jax_compiles", 0)
+    assert after["live_delta_narrow"] - before["live_delta_narrow"] >= 8
+    assert after["live_delta_full"] - before.get("live_delta_full", 0) == 8

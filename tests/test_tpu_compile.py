@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.plaid import fused_hybrid_tail
+from repro.index.live import DELTA_WIDTH
 from repro.index.splade_device import _score_topk
 from repro.kernels.decompress_maxsim.ops import decompress_maxsim_scores_batch
 from repro.kernels.fused_rerank.ops import fused_rerank_topk_batch
@@ -98,6 +99,22 @@ def test_decompress_maxsim_compiles_for_v5e(one_chip, nbits, K):
         a["q"], a["packed"], a["cids"], a["valid"], a["centroids"],
         a["bucket_weights"], q_valid=a["q_valid"], nbits=nbits,
         impl="pallas").compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("b", [1, B])
+def test_narrow_delta_dispatch_compiles_for_v5e(one_chip, b):
+    """The live overlay's delta dispatch at its narrow candidate width
+    (``DELTA_WIDTH``, under one 128-lane tile) for the smallest and the
+    largest padded micro-batch."""
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    nbits, w = 2, DELTA_WIDTH
+    compiled = decompress_maxsim_scores_batch.lower(
+        s((b, LQ, D), jnp.float32), s((b, w, LD, D * nbits // 8), jnp.uint8),
+        s((b, w, LD), jnp.int32), s((b, w, LD), jnp.bool_),
+        s((1 << 15, D), jnp.float32), s((1 << nbits,), jnp.float32),
+        q_valid=s((b, LQ), jnp.bool_), nbits=nbits, impl="pallas").compile()
     _assert_kernel(compiled)
 
 
